@@ -125,11 +125,14 @@ def build_amoebanet(args, cfg, spatial_cells=0):
 
 
 def make_trainer(args, cfg, cells, plain_cells, gems: bool = False, n_spatial=None):
+    """Build the trainer the config asks for. The single-program
+    ``Trainer`` runs under the fixed remat rule (``train.default_remat``);
+    the pipeline trainers checkpoint per stage on their own."""
     import jax
 
     from mpi4dl_tpu.parallel import multihost
     from mpi4dl_tpu.parallel.pipeline import GemsMasterTrainer, PipelineTrainer
-    from mpi4dl_tpu.train import Trainer
+    from mpi4dl_tpu.train import Trainer, default_remat
 
     n_dev = cfg.num_devices
     if len(jax.devices()) < n_dev:
@@ -168,6 +171,8 @@ def make_trainer(args, cfg, cells, plain_cells, gems: bool = False, n_spatial=No
             n_spatial,
         )
     if cfg.split_size == 1 or cfg.spatial_size == cfg.split_size:
+        remat = default_remat(cfg.image_size)
+        print(f"remat policy: {remat} (@{cfg.image_size}px)")
         return (
             Trainer(
                 cells,
@@ -175,6 +180,7 @@ def make_trainer(args, cfg, cells, plain_cells, gems: bool = False, n_spatial=No
                 config=cfg,
                 plain_cells=plain_cells,
                 mesh=mesh,
+                remat=remat,
             ),
             n_spatial,
         )
@@ -301,15 +307,12 @@ def run_training(args, trainer, tag: str):
         # MFU against the model's analytic FLOPs (BASELINE.json north star
         # is stated in MFU; the reference never reports it). Counted on the
         # plain twin — same math, no spatial collectives to trace.
-        try:
-            from mpi4dl_tpu.flops import mfu, train_flops_per_image
+        from mpi4dl_tpu.flops import mfu, train_flops_per_image
 
-            fpi = train_flops_per_image(trainer.plain_cells, cfg.image_size)
-            util = mfu(mean_ips, fpi, n_devices=jax.device_count())
-            if util is not None:
-                line += f" MFU {100 * util:.1f}%"
-        except Exception as e:  # never let accounting kill a benchmark
-            line += f" (MFU unavailable: {e})"
+        fpi = train_flops_per_image(trainer.plain_cells, cfg.image_size)
+        util = mfu(mean_ips, fpi, n_devices=jax.device_count())
+        if util is not None:  # None on CPU only
+            line += f" MFU {100 * util:.1f}%"
         print(line)
     if getattr(args, "eval_batches", 0):
         # skip: the (epoch, step) slots training consumed, reduced modulo

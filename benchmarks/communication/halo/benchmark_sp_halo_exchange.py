@@ -50,13 +50,10 @@ def get_args():
 def main():
     args = get_args()
 
-    from mpi4dl_tpu.utils import apply_platform_env
-
-    apply_platform_env()
 
     import jax
     import jax.numpy as jnp
-    from mpi4dl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from mpi4dl_tpu.config import tile_grid

@@ -67,10 +67,6 @@ def main():
         jax.config.update(
             "jax_num_cpu_devices", max(args.num_spatial_parts, 1)
         )
-    else:
-        from mpi4dl_tpu.utils import apply_platform_env
-
-        apply_platform_env()
 
     import jax
     import jax.numpy as jnp

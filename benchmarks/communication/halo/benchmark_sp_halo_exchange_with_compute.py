@@ -55,10 +55,6 @@ def get_args():
 def main():
     args = get_args()
 
-    from mpi4dl_tpu.utils import apply_platform_env
-
-    apply_platform_env()
-
     import jax
     import jax.numpy as jnp
     from jax import lax, shard_map

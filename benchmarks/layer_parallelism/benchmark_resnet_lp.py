@@ -25,9 +25,6 @@ from mpi4dl_tpu.parser import get_parser
 
 
 def main():
-    from mpi4dl_tpu.utils import apply_platform_env
-
-    apply_platform_env()
     args = get_parser().parse_args()
     cfg = build_config(args, spatial=False)
     n_cells = len(build_resnet(args, cfg)[1])

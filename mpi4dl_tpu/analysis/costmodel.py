@@ -372,15 +372,12 @@ def main(argv: "list[str] | None" = None) -> int:
                    choices=("error", "warn", "never"))
     args = p.parse_args(argv)
 
-    from mpi4dl_tpu.utils import apply_platform_env
-
-    apply_platform_env()
     import os
 
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-        from mpi4dl_tpu.compat import set_cpu_devices
+        import jax
 
-        set_cpu_devices(8)
+        jax.config.update("jax_num_cpu_devices", 8)
 
     import shutil
     import tempfile

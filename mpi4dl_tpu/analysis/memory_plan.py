@@ -222,14 +222,13 @@ def _render(plan: dict, args) -> None:
 
 
 def _setup_backend() -> None:
-    from mpi4dl_tpu.utils import apply_platform_env, enable_compilation_cache
+    from mpi4dl_tpu.utils import enable_compilation_cache
     import os
 
-    apply_platform_env()
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-        from mpi4dl_tpu.compat import set_cpu_devices
+        import jax
 
-        set_cpu_devices(8)
+        jax.config.update("jax_num_cpu_devices", 8)
     enable_compilation_cache()
 
 

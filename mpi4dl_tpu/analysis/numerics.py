@@ -308,16 +308,15 @@ def main(argv=None) -> int:
         return 0 if ok else 1
 
     from mpi4dl_tpu.serve.sharded import parse_mesh
-    from mpi4dl_tpu.utils import apply_platform_env, enable_compilation_cache
+    from mpi4dl_tpu.utils import enable_compilation_cache
 
     mesh = parse_mesh(args.mesh)
-    apply_platform_env()
     import os
 
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-        from mpi4dl_tpu.compat import set_cpu_devices
+        import jax
 
-        set_cpu_devices(max(8, mesh[0] * mesh[1]))
+        jax.config.update("jax_num_cpu_devices", max(8, mesh[0] * mesh[1]))
     enable_compilation_cache()
 
     report = run_live_audit(

@@ -237,15 +237,14 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
 
-    from mpi4dl_tpu.utils import apply_platform_env, enable_compilation_cache
+    from mpi4dl_tpu.utils import enable_compilation_cache
 
-    apply_platform_env()
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
         # The 2x2 tile mesh needs virtual devices before backend init —
         # the same 8-device simulation the test suite runs on.
-        from mpi4dl_tpu.compat import set_cpu_devices
+        import jax
 
-        set_cpu_devices(8)
+        jax.config.update("jax_num_cpu_devices", 8)
     enable_compilation_cache()
 
     out = run_overlap_ab(

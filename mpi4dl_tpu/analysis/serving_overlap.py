@@ -233,16 +233,15 @@ def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
 
     from mpi4dl_tpu.serve.sharded import parse_mesh
-    from mpi4dl_tpu.utils import apply_platform_env, enable_compilation_cache
+    from mpi4dl_tpu.utils import enable_compilation_cache
 
-    apply_platform_env()
     mesh = parse_mesh(args.mesh)
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
         # The tile mesh needs virtual devices before backend init — the
         # same 8-device simulation the test suite runs on.
-        from mpi4dl_tpu.compat import set_cpu_devices
+        import jax
 
-        set_cpu_devices(max(8, mesh[0] * mesh[1]))
+        jax.config.update("jax_num_cpu_devices", max(8, mesh[0] * mesh[1]))
     enable_compilation_cache()
     # Each arm pins its own impl at compile; an inherited process-wide
     # override would collapse the A/B into one arm measured twice.

@@ -9,7 +9,7 @@ parser reads with stdlib ``gzip`` + ``json`` only — no TF/protobuf/xprof
 dependency — and turns into:
 
 - a typed event inventory (:class:`TraceEvent`) split into host and
-  device timelines by thread identity (CPU: the ``XLATfrtCpuClient``
+  device timelines by thread identity (CPU: the ``XLAPjRtCpuClient``
   executor threads carry per-HLO-op slices; TPU/GPU: ``/device:*``
   process timelines, preferring the ``XLA Ops`` line to avoid counting
   the module/step summary lines twice);
@@ -62,6 +62,24 @@ COLLECTIVE_MARKERS = (
     "ragged-all-to-all",
 )
 
+#: The installed jax names an HLO instruction after the primitive that
+#: made it (``ppermute.3``, ``psum.14``), and where no backend pass renames
+#: it (the CPU backend) that is the slice's name in the trace — not the
+#: opcode. Matched against the WHOLE name less its trailing ``.N``, so a
+#: ``psum_fusion`` compute kernel does not false-positive.
+COLLECTIVE_PRIMITIVE_STEMS = frozenset({
+    "ppermute",
+    "psum",
+    "pmax",
+    "pmin",
+    "all_gather",
+    "all_to_all",
+    "reduce_scatter",
+    "pbroadcast",
+})
+#: The primitive behind ``collective-permute``.
+_PERMUTE_STEMS = ("collective-permute", "ppermute")
+
 #: Case-insensitive substrings marking host<->device / device<->device
 #: data movement (the "h2d" bucket; includes d2h and d2d).
 TRANSFER_MARKERS = (
@@ -80,12 +98,11 @@ TRANSFER_MARKERS = (
 )
 
 #: Thread-name substrings that mark a CPU-backend device timeline: the
-#: per-device TfrtCpuClient executor threads AND the shared XLAEigen
+#: per-device PjRtCpuClient executor threads AND the shared XLAEigen
 #: intra-op pool — XLA's thunk executor schedules op thunks onto either,
 #: and which one a given op lands on varies run to run.
 _CPU_DEVICE_THREAD_MARKERS = (
-    "XLATfrtCpuClient",
-    "TfrtCpuDevice",
+    "XLAPjRtCpuClient",
     "XLAEigen",
 )
 
@@ -95,10 +112,17 @@ _CPU_DEVICE_THREAD_MARKERS = (
 _INFRA_PREFIXES = (
     "ThreadpoolListener",
     "ThunkExecutor",
-    "TfrtCpu",
+    "SlinkyThreadPool",
+    "PjRtCpu",
+    "CommonPjRt",
+    "Handle inputs",
+    "Rendezvous",
+    "InvokeRendezvous",
+    "Wait",  # "Wait: pending_threads=3/8", "Wait for rendezvous callback"
     "ParseArguments",
     "PjitFunction",
     "ExecuteThunks",
+    "end: ",  # the thunk executor's end-of-op markers
     "$",  # python-source host slices
 )
 
@@ -135,6 +159,8 @@ def categorize(name: str) -> "str | None":
     """Device-slice category for an event name, or None for runtime
     bookkeeping that must not count as device busy time."""
     if any(m in name for m in COLLECTIVE_MARKERS):
+        return "collective"
+    if _TRAILING_ID.sub("", name) in COLLECTIVE_PRIMITIVE_STEMS:
         return "collective"
     low = name.lower()
     if any(m in low for m in TRANSFER_MARKERS):
@@ -195,7 +221,7 @@ def device_slices(events) -> "list[TraceEvent]":
     """Device-timeline op slices, categorized; host threads and runtime
     bookkeeping excluded.
 
-    CPU: XLA runs op thunks on the per-device ``XLATfrtCpuClient``
+    CPU: XLA runs op thunks on the per-device ``XLAPjRtCpuClient``
     executor threads and the shared ``XLAEigen`` intra-op pool — both are
     device timelines here. TPU/GPU: each device is a ``/device:*``
     process whose ``XLA Ops`` thread carries the op timeline — when that
@@ -592,7 +618,9 @@ def pipeline_attribution(
             continue
         counts[ev.name] = counts.get(ev.name, 0) + 1
         durs[ev.name] = durs.get(ev.name, 0.0) + ev.duration_s
-        if ev.category == "collective" and "collective-permute" in ev.name:
+        if ev.category == "collective" and any(
+            m in ev.name for m in _PERMUTE_STEMS
+        ):
             permute_s += ev.duration_s
 
     def branch_count(unique_names) -> int:

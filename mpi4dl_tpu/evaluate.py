@@ -37,7 +37,7 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
-from mpi4dl_tpu.compat import axis_size
+from jax.lax import axis_size
 from mpi4dl_tpu.ops.layers import bn_stats_mode
 from mpi4dl_tpu.train import correct_count, cross_entropy_sum
 
@@ -379,7 +379,7 @@ def make_spatial_eval_step(trainer):
     cached = getattr(trainer, "_spatial_eval_step", None)
     if cached is not None:
         return cached
-    from mpi4dl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(params, batch_stats, x, y):
@@ -433,7 +433,7 @@ def aot_compile_spatial_predict(
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from mpi4dl_tpu.compat import shard_map
+    from jax import shard_map
     from mpi4dl_tpu.config import AXIS_DATA
 
     mesh = trainer.mesh
@@ -492,7 +492,7 @@ def spatial_collect_batch_stats(trainer, params, batches) -> list:
     :func:`collect_batch_stats` for models whose full-image forward does
     not fit one device. ``batches``: iterable of host input arrays (global
     batch shape, like the training inputs)."""
-    from mpi4dl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local_first(params, x):

@@ -481,10 +481,6 @@ def main(argv=None) -> int:
     t_start = time.monotonic()
     args = build_parser().parse_args(argv)
 
-    from mpi4dl_tpu.utils import apply_platform_env
-
-    apply_platform_env()
-
     mesh_shape = None
     if args.mesh:
         from mpi4dl_tpu.serve.sharded import parse_mesh
@@ -492,9 +488,11 @@ def main(argv=None) -> int:
         mesh_shape = parse_mesh(args.mesh)
         if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
             # The tile mesh needs virtual devices before backend init.
-            from mpi4dl_tpu.compat import set_cpu_devices
+            import jax
 
-            set_cpu_devices(max(8, mesh_shape[0] * mesh_shape[1]))
+            jax.config.update(
+                "jax_num_cpu_devices", max(8, mesh_shape[0] * mesh_shape[1])
+            )
 
     import jax
     import jax.numpy as jnp

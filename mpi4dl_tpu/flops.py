@@ -43,15 +43,22 @@ _PEAK_FLOPS = {
 
 
 def peak_flops(device=None) -> float | None:
-    """Peak bf16 FLOP/s for ``device`` (default: first visible device), or
-    None when unknown (CPU, unlisted TPU generations)."""
+    """Peak bf16 FLOP/s for ``device`` (default: first visible device).
+    None on CPU only; a TPU whose ``device_kind`` is not in the table is
+    an error, not a default."""
     if device is None:
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "")
+    if device.platform == "cpu":
+        return None
+    kind = device.device_kind
     for name in sorted(_PEAK_FLOPS, key=len, reverse=True):
         if kind.startswith(name):
             return _PEAK_FLOPS[name]
-    return None
+    raise ValueError(
+        f"no peak FLOP/s on record for device_kind {kind!r} "
+        f"(platform {device.platform!r}); add it to flops._PEAK_FLOPS "
+        "with its source"
+    )
 
 
 def _eqn_flops(eqn) -> float:
@@ -177,8 +184,9 @@ def train_flops_per_image(cells: Sequence[Any], image_size: int, dtype=None) -> 
 
 def mfu(images_per_sec: float, flops_per_image: float, n_devices: int = 1,
         device=None) -> float | None:
-    """Model FLOP utilization in [0, 1], or None off-TPU/unknown device."""
+    """Model FLOP utilization in [0, 1]; None on CPU (no peak), an error
+    on an accelerator :func:`peak_flops` does not know."""
     peak = peak_flops(device)
-    if not peak:
+    if peak is None:
         return None
     return images_per_sec * flops_per_image / (peak * n_devices)

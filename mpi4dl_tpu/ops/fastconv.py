@@ -100,7 +100,7 @@ def conv_impl() -> str:
 def _wgrad_impl_allows(c: int) -> bool:
     """Pallas-wgrad dispatch policy. ``MPI4DL_TPU_WGRAD_IMPL`` = ``xla``
     (default; never dispatch the kernel) | ``pallas`` (dispatch wherever
-    the kernel's shape gate + compile probe admit, bounded by
+    the kernel's shape gate admits, bounded by
     ``MPI4DL_TPU_WGRAD_CMAX`` input channels). Read at trace time so
     benchmark processes can A/B the dispatch without code edits.
 
@@ -122,10 +122,7 @@ def _wgrad_impl_allows(c: int) -> bool:
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover - device probing never fatal
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @functools.lru_cache(maxsize=None)
@@ -247,7 +244,7 @@ def _conv2d_s1_bwd(padding, res, dy):
         # step, docs/PERF.md round 5).
         from mpi4dl_tpu.ops import dot1x1_pallas
 
-        if _on_tpu() and dot1x1_pallas.dispatchable(x, dy, w):
+        if dot1x1_pallas.dispatchable(x, dy):
             c, o = x.shape[-1], dy.shape[-1]
             dx, dw = dot1x1_pallas.bwd_1x1(
                 x, dy, w.reshape(c, o)
@@ -303,7 +300,9 @@ def _conv2d_s1_bwd(padding, res, dy):
     if (
         _on_tpu()
         and _wgrad_impl_allows(x.shape[-1])
-        and wgrad_pallas.usable(xt, dy, kh, kw)
+        and wgrad_pallas.supported(
+            xt.shape, dy.shape, kh, kw, xt.dtype.itemsize, dy.dtype.itemsize
+        )
     ):
         dw = wgrad_pallas.wgrad(xt, dy, kh, kw)
     else:

@@ -72,31 +72,20 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.lax import axis_size
 
-from mpi4dl_tpu.compat import axis_size
 
-
-def interpret_available() -> bool:
-    """Whether this jax can interpret TPU-distributed Pallas kernels on
-    CPU (``InterpretParams``; ``TPUInterpretParams`` on 2024-era lines;
-    absent entirely on 0.4.x — tests skip the pallas halo there)."""
-    return any(
-        hasattr(pltpu, n) for n in ("InterpretParams", "TPUInterpretParams")
-    )
+# The pallas_call's name: how the kernel is found in a compiled step's
+# text and in a profiler trace.
+KERNEL_NAME = "mpi4dl_halo_swap"
 
 
 def _interpret():
-    # Pallas TPU kernels run interpreted on CPU test meshes.
-    if jax.default_backend() == "tpu":
-        return False
-    for name in ("InterpretParams", "TPUInterpretParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls()
-    raise NotImplementedError(
-        "this jax has no TPU-Pallas CPU interpreter; the pallas halo "
-        "impl needs a real TPU here (use MPI4DL_TPU_HALO_IMPL=xla)"
-    )
+    """Interpret mode only where the backend is CPU (test meshes); every
+    other backend compiles the kernel."""
+    if jax.default_backend() == "cpu":
+        return pltpu.InterpretParams()
+    return False
 
 
 def _swap_kernel(axis_name: str):
@@ -190,6 +179,7 @@ def _swap_call(a, b, axis_name: str):
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=_interpret(),
+        name=KERNEL_NAME,
         compiler_params=pltpu.CompilerParams(
             collective_id=_next_collective_id(), has_side_effects=True
         ),

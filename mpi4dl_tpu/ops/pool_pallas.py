@@ -38,10 +38,12 @@ interior-padded full-resolution scatter terms (the failure mode that
 made the XLA-level decomposition 32% SLOWER end-to-end,
 ``pool_bwd_impl``/docs/PERF.md round 4).
 
-Dispatch: ``usable()`` = shape gate + cached on-device compile probe
-(Mosaic failures only surface on real hardware); fallbacks are the
-existing tree / reduce_window paths, so the step cannot be broken by a
-kernel regression. ``MPI4DL_TPU_POOL_PALLAS=off`` disables for A/B;
+Dispatch: ``usable()`` = TPU backend + shape gate (``supported``);
+shapes the gate declines take the existing tree / reduce_window paths. A
+shape the gate admits and the chip's compiler refuses is a bug in the
+gate and surfaces as the compiler's error (``tests/test_tpu_compile.py``
+compiles the admitted shapes of the full-width models for a described
+v5e chip). ``MPI4DL_TPU_POOL_PALLAS=off`` disables for A/B;
 ``=on`` additionally neutralizes trainer-armed ``disable()`` heuristics
 (the >=2048px gate) for A/B re-validation.
 """
@@ -56,17 +58,19 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+# The pallas_call's name: how the kernel is found in a compiled step's
+# text and in a profiler trace.
+KERNEL_NAME = "mpi4dl_pool_bwd"
 _NEG = float("-inf")
 _VMEM_BUDGET = 10 * 1024 * 1024
 
 
 def pool_pallas_mode() -> str:
-    """auto: shape/probe gates decide, and trainers may arm ``disable()``
+    """auto: shape gates decide, and trainers may arm ``disable()``
     heuristics (e.g. the >=2048px gate). off: never dispatch. on: like
     auto but ``disable()`` becomes a no-op, so the >=2048px heuristic can
     be A/B-revalidated if the compiler/runtime VMEM behavior improves —
-    correctness gates (shape plan, compile probe, batched traces) still
-    apply."""
+    correctness gates (shape plan, batched traces) still apply."""
     mode = os.environ.get("MPI4DL_TPU_POOL_PALLAS", "auto")
     if mode not in ("auto", "off", "on"):
         raise ValueError(
@@ -85,8 +89,8 @@ class disable:
     ``Trainer.train_step`` arms this for images >= 2048px: per-shape the
     kernels pass their gates there, but injecting VMEM-stack-allocated
     custom-call results into a program already compiled against the HBM
-    ceiling kills the compile helper (measured: AmoebaNet@2048 bs1
-    compiles with the kernels off, dies with them on — round 4). The
+    ceiling fails the compile (measured: AmoebaNet@2048 bs1 compiles
+    with the kernels off, fails with them on — round 4). The
     @1024 headline regime, where the kernel is measured bit-exact at
     end-to-end parity, keeps the dispatch. ``MPI4DL_TPU_POOL_PALLAS=off``
     disables everywhere regardless; ``=on`` makes THIS switch a no-op so
@@ -255,43 +259,27 @@ def supported(x_shape, kh, kw, sh, sw, ph, pw, itemsize=2) -> bool:
     b, h, w, c = x_shape
     if kh <= sh and kw <= sw:
         return False  # non-overlapping: XLA's backward is already a reshape
-    # This runtime's AOT compiler stack-allocates Pallas custom-call
-    # results in VMEM (docs/PERF.md round 4), so the kernel's output set
-    # (~dx-sized) must fit well under the 128 MB VMEM alongside the
-    # working set. Gate cheaply here instead of paying a doomed 10-30 s
-    # compile probe per >=2048px pool shape during bench runs.
-    if b * (h + 2 * ph) * (w + 2 * pw) * c * itemsize > 100 * 1024 * 1024:
+    # The TPU compiler may stack-allocate a Pallas custom call's results in
+    # VMEM (docs/PERF.md round 4) and then fails the compile when they
+    # overflow ("Ran out of memory in memory space vmem while allocating
+    # on stack"). Measured by compiling for a described v5e chip (jax
+    # 0.9.0, libtpu 0.0.34; tests/test_tpu_compile.py holds the gate to
+    # it). Stride-1 shapes compile alone up to 104.8 MiB of padded input
+    # (fail at 211 MiB) and inside the whole AmoebaNet-D 18/416 @1024
+    # step. Strided shapes — whose result set is the parity classes plus
+    # their tails — are refused whatever their size: [2,130,130,832] fails
+    # alone, and [2,130,130,416], which compiles alone, fails inside the
+    # SP 2x2 step, as [2,130,130,832] does under "scan_save". They take
+    # XLA's select_and_scatter.
+    if (sh, sw) != (1, 1):
+        return False
+    if b * (h + 2 * ph) * (w + 2 * pw) * c * itemsize > 100 * 2**20:
         return False
     hp, wp = h + 2 * ph, w + 2 * pw
     if hp < kh or wp < kw:
         return False
     ho, wo, _, _ = _out_geom(hp, wp, kh, kw, sh, sw)
     return _plan(c, ho, wo, kh, kw, sh, sw, itemsize) is not None
-
-
-@functools.lru_cache(maxsize=None)
-def _compiles(x_shape, dtype, kh, kw, sh, sw, ph, pw) -> bool:
-    """Cached on-device compile probe (pattern: wgrad_pallas._compiles)."""
-    import warnings
-
-    try:
-        b, h, w, c = x_shape
-        hp, wp = h + 2 * ph, w + 2 * pw
-        ho, wo, _, _ = _out_geom(hp, wp, kh, kw, sh, sw)
-        jax.jit(
-            functools.partial(_bwd_padded, kh=kh, kw=kw, sh=sh, sw=sw)
-        ).lower(
-            jax.ShapeDtypeStruct((b, hp, wp, c), dtype),
-            jax.ShapeDtypeStruct((b, ho, wo, c), dtype),
-        ).compile()
-        return True
-    except Exception as e:
-        warnings.warn(
-            "Pallas max-pool backward failed to compile for "
-            f"x={x_shape} k=({kh},{kw}) s=({sh},{sw}) p=({ph},{pw}); "
-            f"using the XLA backward instead. Error: {str(e)[:400]}"
-        )
-        return False
 
 
 def usable(x, kh, kw, sh, sw, ph, pw) -> bool:
@@ -301,18 +289,14 @@ def usable(x, kh, kw, sh, sw, ph, pw) -> bool:
         return False
     if x.ndim != 4:
         return False
-    if not supported(tuple(x.shape), kh, kw, sh, sw, ph, pw, x.dtype.itemsize):
-        return False
-    return _compiles(
-        tuple(x.shape), jnp.dtype(x.dtype).name, kh, kw, sh, sw, ph, pw
-    )
+    return supported(tuple(x.shape), kh, kw, sh, sw, ph, pw, x.dtype.itemsize)
 
 
 def dispatchable(x, kh, kw, sh, sw, ph, pw) -> bool:
     """``usable`` + not under a batched (vmapped) trace. The pipeline's
     micro-batched front vmaps the cell stack; a batched ``pallas_call``
     compiles through an added grid dimension only sometimes, and the
-    compile probe (which runs on the UN-batched shape) cannot vouch for
+    shape gate (which plans the UN-batched shape) cannot vouch for
     it — so batched contexts keep the XLA/tree backward, exactly like the
     halo kernel's policy (``parallel/halo.py:124-146``). The sniffs are
     shared with that policy: the pipeline front's ``xla_halo_only``
@@ -412,6 +396,7 @@ def _bwd_padded(xp, dy, *, kh, kw, sh, sw, interpret=False):
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
+        name=KERNEL_NAME,
     )(*args)
     outs = list(outs) if isinstance(outs, (list, tuple)) else [outs]
 

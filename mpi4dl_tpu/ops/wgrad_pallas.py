@@ -38,10 +38,11 @@ Contraction layout (the round-2 fix + speedup, measured on device):
 
 Exactness: same products as the stock wgrad, f32 accumulation, summation
 regrouped per (batch, row-chunk) — ``tests/test_wgrad_pallas.py`` checks
-math in interpreter mode. Dispatch is guarded by a cached on-device compile
-probe (:func:`usable`): Mosaic layout failures only surface at compile time
-on real hardware, so the probe falls back to XLA's backward-filter conv
-instead of crashing the step (round-1 VERDICT weak #1).
+math in interpreter mode. Dispatch is guarded by the shape gate
+(:func:`supported`) alone: a shape the gate admits and the chip's compiler
+refuses is a bug in the gate and surfaces as the compiler's error.
+``tests/test_tpu_compile.py`` compiles the admitted shapes of the
+full-width models for a described v5e chip.
 """
 
 from __future__ import annotations
@@ -54,6 +55,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# The pallas_call's name: how the kernel is found in a compiled step's
+# text and in a profiler trace.
+KERNEL_NAME = "mpi4dl_wgrad"
 # Row-chunk height. Must divide Ho and be a multiple of (kh - 1).
 _TH = 8
 
@@ -108,46 +112,6 @@ def supported(xp_shape, dy_shape, kh: int, kw: int,
     return x_bytes + dy_bytes + 2 * acc_bytes + pat_bytes < 12 * 1024 * 1024
 
 
-@functools.lru_cache(maxsize=None)
-def _compiles(xp_shape, dy_shape, x_dtype, dy_dtype, kh: int, kw: int) -> bool:
-    """One-time compile probe, cached per (shapes, dtypes, taps).
-
-    Mosaic layout failures surface only at compile time on the real TPU —
-    interpreter-mode tests cannot catch them (this is exactly how round 1's
-    bench broke: ADVICE.md high finding, `tpu.concatenate` offset mismatch).
-    Probing the actual lowering before dispatching makes the training step
-    un-breakable by kernel compile regressions: on any failure we fall back
-    to XLA's backward-filter conv.
-    """
-    import warnings
-
-    import jax
-
-    try:
-        jax.jit(functools.partial(wgrad, kh=kh, kw=kw)).lower(
-            jax.ShapeDtypeStruct(xp_shape, x_dtype),
-            jax.ShapeDtypeStruct(dy_shape, dy_dtype),
-        ).compile()
-        return True
-    except Exception as e:  # fall back to XLA's wgrad — but say so
-        warnings.warn(
-            "Pallas wgrad kernel failed to compile for "
-            f"xp={xp_shape} dy={dy_shape} k=({kh},{kw}); using the XLA "
-            f"backward-filter conv instead. Error: {str(e)[:400]}"
-        )
-        return False
-
-
-def usable(xp, dy, kh: int, kw: int) -> bool:
-    """supported() + the cached on-device compile probe."""
-    if not supported(xp.shape, dy.shape, kh, kw,
-                     xp.dtype.itemsize, dy.dtype.itemsize):
-        return False
-    return _compiles(tuple(xp.shape), tuple(dy.shape),
-                     jnp.dtype(xp.dtype).name, jnp.dtype(dy.dtype).name,
-                     kh, kw)
-
-
 @functools.partial(jax.jit, static_argnames=("kh", "kw", "interpret"))
 def wgrad(xp, dy, kh: int, kw: int, interpret: bool = False):
     """dw[kh, kw, C, O] (f32) for a stride-1 conv.
@@ -184,6 +148,7 @@ def wgrad(xp, dy, kh: int, kw: int, interpret: bool = False):
         out_shape=jax.ShapeDtypeStruct((kw, o, kh * c), jnp.float32),
         scratch_shapes=[pltpu.VMEM((kw, o, kh * c), jnp.float32)],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(xp, xp, dy)
     # out[v, o, u*C + c] -> dw[u, v, c, o]
     return out.reshape(kw, o, kh, c).transpose(2, 0, 3, 1)

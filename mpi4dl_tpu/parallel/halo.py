@@ -28,7 +28,7 @@ import contextlib
 import jax.numpy as jnp
 from jax import lax
 
-from mpi4dl_tpu.compat import axis_size
+from jax.lax import axis_size
 
 # -- Pallas-impl safety plumbing (see halo_exchange's impl dispatch) ---------
 

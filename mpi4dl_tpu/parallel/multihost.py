@@ -59,10 +59,8 @@ _COORDINATOR_ENV_VARS = (
 
 
 # Environment markers that unambiguously mean "more than one process was
-# launched" even when no coordinator address is spelled out (the launcher or
-# pod runtime provides it). Checked besides jax's own cluster auto-detection
-# so a jax-internal API move cannot silently disable the propagation of
-# multi-host init failures.
+# launched" even when no coordinator address is spelled out (the launcher
+# provides it, and jax's own cluster detection reads the rest).
 _MULTIPROC_ENV_MARKERS = (
     "OMPI_COMM_WORLD_SIZE",
     "SLURM_NTASKS",
@@ -70,11 +68,10 @@ _MULTIPROC_ENV_MARKERS = (
 )
 
 
-def _cluster_autodetected() -> bool:
-    """True when this environment is recognizably a multi-process launch
-    (GKE / GCE TPU pods, Slurm, OpenMPI, …) — there, no coordinator env var
-    is set by the operator, yet a multi-host world IS configured and init
-    failures must propagate."""
+def _launcher_present() -> bool:
+    """True when a multi-process launcher (Slurm, OpenMPI, a multi-slice
+    runtime) started this process: no coordinator env var is set by the
+    operator there, yet a multi-host world IS configured."""
     for k in _MULTIPROC_ENV_MARKERS:
         v = os.environ.get(k)
         try:
@@ -82,12 +79,7 @@ def _cluster_autodetected() -> bool:
                 return True
         except ValueError:
             pass
-    try:
-        from jax._src.clusters import ClusterEnv
-
-        return any(c.is_env_present() for c in ClusterEnv._cluster_types)
-    except Exception:
-        return False
+    return False
 
 
 def initialize_distributed(
@@ -98,38 +90,37 @@ def initialize_distributed(
     """Join the multi-host world (ref ``dist.init_process_group``,
     ``comm.py:154-159``; launcher contract ``README.md:121-125``).
 
-    On TPU pods all three arguments are discovered from the environment, so
-    a bare ``initialize_distributed()`` at the top of a training script is
-    the entire multi-host setup. Must run before anything that initializes
-    the XLA backend (``jax.devices()``, array creation, …) — like
-    ``jax.distributed.initialize`` itself. Calling it again once
-    initialized is a no-op, and so is a plain single-process run with no
-    coordinator configured anywhere; but if a coordinator IS configured
-    (argument or environment), failures propagate — silently degrading a
-    pod launch into N independent single-host jobs is the one outcome this
-    wrapper must never produce.
+    A world is configured by argument, by a coordinator variable
+    (``JAX_COORDINATOR_ADDRESS`` …) or by a launcher's marker (Slurm,
+    OpenMPI, multi-slice); what the arguments leave open jax's cluster
+    detection fills in. Then this must run before anything that
+    initializes the XLA backend (``jax.devices()``, array creation, …) and
+    its failures propagate — silently degrading a pod launch into N
+    independent single-host jobs is the one outcome this wrapper must
+    never produce. With none of these it is a single-process run: nothing
+    is joined and nothing is looked up. (Until PR 24 a bare
+    ``jax.distributed.initialize()`` was tried regardless and its failure
+    swallowed unless jax's Cloud-TPU sniff said "cluster" — which it says
+    on any machine with a TPU attached, so on a one-chip VM with no
+    metadata server every benchmark entry point died here.) Calling it
+    again once initialized is a no-op.
     """
-    from mpi4dl_tpu.compat import distributed_is_initialized
-
-    if distributed_is_initialized():
+    if jax.distributed.is_initialized():
         return
     configured = (
         coordinator_address is not None
         or num_processes is not None
         or process_id is not None
         or any(os.environ.get(k) for k in _COORDINATOR_ENV_VARS)
-        or _cluster_autodetected()
+        or _launcher_present()
     )
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coordinator_address,
-            num_processes=num_processes,
-            process_id=process_id,
-        )
-    except (ValueError, RuntimeError):
-        if configured:
-            raise
-        # No coordinator anywhere → single-process run, nothing to join.
+    if not configured:
+        return
+    jax.distributed.initialize(
+        coordinator_address=coordinator_address,
+        num_processes=num_processes,
+        process_id=process_id,
+    )
 
 
 def num_slices(devices: Sequence[jax.Device] | None = None) -> int:

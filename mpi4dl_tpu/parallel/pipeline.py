@@ -73,7 +73,8 @@ import optax
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from mpi4dl_tpu.compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 from mpi4dl_tpu.config import (
     AXIS_DATA,
     AXIS_PIPE,
@@ -289,11 +290,16 @@ class PipelineTrainer:
     def spatial_cell_count(num_cells: int, config: ParallelConfig) -> int:
         """How many leading cells are spatial: all cells of stages
         ``0..spatial_size-1`` (ref boundary logic ``resnet_spatial.py:545-633``:
-        spatial cells up to the SP stage's end layer)."""
+        spatial cells up to the SP stage's end layer) — but never the last
+        cell: the head (``Classify`` / ``HeadV2``) pools over the whole
+        image and is plain in every builder, so when every stage is spatial
+        (``spatial_size == split_size``) the tile merge comes before it.
+        Without the cap each tile classified its own quarter of the image
+        and the loss was the mean of four different models' losses."""
         if not config.spatial_size:
             return 0
         bounds = stage_bounds(num_cells, config.split_size, config.balance)
-        return bounds[config.spatial_size - 1][1]
+        return min(bounds[config.spatial_size - 1][1], num_cells - 1)
 
     def _build_static_plan(self):
         """Trace the front output and per-boundary wire shapes via

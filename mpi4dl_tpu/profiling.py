@@ -160,7 +160,14 @@ def trace(logdir: str | None = None):
         return
     import jax
 
-    with jax.profiler.trace(logdir):
+    # Python-call tracing off: at its default level every Python function
+    # call becomes a host slice, a multi-step capture overflows the trace
+    # converter's 1M-event cap, and the device-thread slices the
+    # attribution reads are what gets dropped. TraceAnnotation /
+    # StepTraceAnnotation spans are host-tracer events and stay.
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(logdir, profiler_options=options):
         yield logdir
 
 

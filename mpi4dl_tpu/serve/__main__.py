@@ -370,10 +370,6 @@ def main(argv=None) -> int:
 
     import os
 
-    from mpi4dl_tpu.utils import apply_platform_env
-
-    apply_platform_env()
-
     if args.tiled and args.mesh:
         raise SystemExit(
             "--tiled and --mesh are mutually exclusive: tiled streaming "
@@ -388,9 +384,11 @@ def main(argv=None) -> int:
         if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
             # The tile mesh needs virtual devices before backend init
             # (the same simulation the test suite / analyze CLI use).
-            from mpi4dl_tpu.compat import set_cpu_devices
+            import jax
 
-            set_cpu_devices(max(8, mesh_shape[0] * mesh_shape[1]))
+            jax.config.update(
+                "jax_num_cpu_devices", max(8, mesh_shape[0] * mesh_shape[1])
+            )
 
     from mpi4dl_tpu.serve import ServingEngine
     from mpi4dl_tpu.serve.loadgen import (

@@ -25,9 +25,12 @@ chip at bounded memory:
   cropped), and a window edge at the image boundary coincides with it, so
   the conv's zero padding there IS the monolithic padding. Every kept
   output element therefore sees exactly the bytes the monolithic forward
-  saw — the stitched result is bit-identical wherever the monolithic
-  forward fits (tier-1-asserted, the PR-9 ``overlap_decompose``
-  equivalence bar).
+  saw — the stitched result is bit-identical wherever the backend rounds
+  a convolution independently of the window's pixel count, and agrees at
+  the f32 reduction-order boundary (~2e-6) where it does not: the
+  installed XLA:CPU blocks a wide 3x3 conv's accumulation by its pixel
+  count (tier-1-asserted, ``tests/_tiled_equiv_check.py``; not measured
+  on a TPU).
 - **One AOT-warmed tile executable.** Interior, edge, corner, and ragged
   tiles all run the SAME fixed ``window × window`` program (clamping
   keeps the shape constant), batched into power-of-two TILE buckets and
@@ -331,8 +334,9 @@ class TiledPredictor:
         up to it (``/predict_tiled``'s own buckets, orthogonal to the
         engine's per-IMAGE buckets, which default to 1). Default 1 —
         the EXACT path: every window runs the one batch-1 section
-        executable, whose outputs are bit-identical to the monolithic
-        forward (tier-1-asserted). Raising it batches windows per
+        executable, whose outputs equal the monolithic forward's to
+        the bit or to f32 reduction order, as the backend's conv rounds
+        (module docstring; tier-1-asserted). Raising it batches windows per
         dispatch (a throughput lever for small tiles), at the repo's
         documented cross-executable boundary: rows computed by a
         batch-b program agree with the batch-1/monolithic program at

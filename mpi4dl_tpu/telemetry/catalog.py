@@ -237,10 +237,9 @@ CATALOG: "dict[str, MetricSpec]" = {
     ),
     "compile_cache_enabled": MetricSpec(
         "gauge", (),
-        "1 when the persistent compilation cache is on, 0 when off — "
-        "including the jax-0.4.x segfault gate in "
-        "utils.enable_compilation_cache, so fleet runs are honest "
-        "about whether compiles are ever amortized.",
+        "1 when the persistent compilation cache is on, 0 when off "
+        "(utils.enable_compilation_cache failed or never ran), so fleet "
+        "runs are honest about whether compiles are ever amortized.",
     ),
     # -- tail forensics (mpi4dl_tpu/telemetry/tail.py) -----------------------
     "tail_samples_total": MetricSpec(

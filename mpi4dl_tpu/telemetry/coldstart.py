@@ -19,9 +19,9 @@ turn it into an instrument:
   ``fleet_recovery_phase_seconds{phase=}`` — durations, not timestamps,
   so the arithmetic is clock-skew-safe across processes.
 - **Cache honesty** (:func:`publish_cache_status`): the
-  ``compile_cache_enabled`` gauge, 0 under the jax-0.4.x segfault gate
-  in :func:`mpi4dl_tpu.utils.enable_compilation_cache` — fleet runs
-  stop silently paying compiles they believe are cached.
+  ``compile_cache_enabled`` gauge, 0 when
+  :func:`mpi4dl_tpu.utils.enable_compilation_cache` failed or never ran —
+  fleet runs stop silently paying compiles they believe are cached.
 
 ``python -m mpi4dl_tpu.analyze coldstart``
 (:mod:`mpi4dl_tpu.analysis.coldstart`) joins the ledger dumps,
@@ -162,11 +162,10 @@ def recovery_phase_decomposition(
 
 def publish_cache_status(registry, attempt: bool = True) -> dict:
     """Publish the cataloged ``compile_cache_enabled`` gauge (1 = the
-    persistent compilation cache is on, 0 = off — including the jax-0.4.x
-    segfault gate) and return the status dict with the reason. With
-    ``attempt=True`` (default) this first calls
+    persistent compilation cache is on, 0 = off) and return the status
+    dict with the reason. With ``attempt=True`` (default) this first calls
     :func:`mpi4dl_tpu.utils.enable_compilation_cache`, which records its
-    own gate decision and logs the reason once per process — so a
+    own decision — so a
     serving engine's scrape is honest about cache state without every
     entry point having to remember the call."""
     from mpi4dl_tpu import telemetry

@@ -71,7 +71,7 @@ OOM_SIGNATURES = (
 def exception_chain_text(exc) -> str:
     """str(exc) plus every chained ``__cause__``/``__context__`` message
     — the HBM table can sit in a wrapped cause while the outer message
-    says only "compile helper died" (bench.py's lesson, ADVICE r4)."""
+    says only that the compile died (bench.py's lesson, ADVICE r4)."""
     if isinstance(exc, str):
         return exc
     parts, seen, todo = [], set(), [exc]

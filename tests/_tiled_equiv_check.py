@@ -1,23 +1,37 @@
 """Subprocess half of the tiled stitch-exactness suite: run on a
 SINGLE-device CPU backend — the tiled predictor's actual deployment
 topology (one chip serving huge images) — and compare the tile-streaming
-forward against the monolithic forward BIT FOR BIT across tile grids and
-model families. Prints one JSON verdict line.
+forward against the monolithic forward across tile grids and model
+families: bit for bit, and by largest absolute difference. Prints one
+JSON verdict line.
 
-Why a subprocess: the test harness simulates an 8-device mesh
-(``conftest.set_cpu_devices(8)``), under which XLA:CPU partitions each
-program's intra-op work differently per SHAPE — two programs computing
-the same window bytes (a 40×40 section window vs the 56×56 monolithic
-forward) can then round differently in the last bit, the repo's standard
-cross-executable f32 boundary. On one device the per-shape partitioning
-coincides and the stitched forward is bit-identical, which is the claim
-that matters for the single-chip gigapixel deployment.
+Every kept output element sees exactly the bytes the monolithic forward
+saw, so the two agree bitwise wherever the backend rounds a convolution
+independently of how many pixels it covers. The installed XLA:CPU
+(jaxlib 0.9.0) does not, for wide convs: a 3x3 conv is an Eigen
+contraction over K = 9*C_in whose accumulation is blocked by the
+contraction's M x N extent, so the v1 model's last section cell (3x3,
+32->64 stride 2 and 64->64, K = 288 / 576) rounds differently on a
+10x10 or 10x12 window (plans t16 and t(16, 24): windows 40x40 and
+40x48) than on the 14x14 whole-image feature map — ~2e-6 on O(1)
+activations, the repo's standard cross-executable f32 boundary. Narrower
+convs (C <= 32 at stride 1, every 1x1) are shape-stable there, which is
+why t48 (window == image) and the v2 bottleneck model stay bitwise. So
+``ok`` holds the plans to that f32 boundary, and the window == image plan
+— which pins that the section/head SPLIT itself is bitwise-safe — to
+bit-identity.
+
+Why a subprocess: the test harness simulates an 8-device mesh, under
+which XLA:CPU also partitions each program's intra-op work per SHAPE.
 """
 
 import json
 import sys
 
 import numpy as np
+
+#: The cross-executable f32 boundary (the in-harness test's own atol).
+ATOL = 5e-6
 
 
 def main() -> int:
@@ -31,6 +45,7 @@ def main() -> int:
 
     assert len(jax.devices()) == 1, "this check needs ONE device"
     results = {}
+    max_abs = {}
 
     def check(tag, cells, size, tile, seed):
         rng = np.random.default_rng(seed)
@@ -55,6 +70,7 @@ def main() -> int:
             got = pred.run(handle, x)
             want = np.asarray(mono(params, stats, x))
             results[f"{tag}_t{t}"] = bool(np.array_equal(got, want))
+            max_abs[f"{tag}_t{t}"] = float(np.max(np.abs(got - want)))
 
     # v1 at a ragged size: square/rect cores, ragged last tiles, the
     # single-window degenerate; v2 (pre-activation bottlenecks, 1x1
@@ -69,8 +85,11 @@ def main() -> int:
         get_resnet_v2(depth=11, num_classes=10, pool_kernel=8),
         32, [4], seed=1,
     )
-    ok = all(results.values())
-    print(json.dumps({"ok": ok, "bit_identical": results}), flush=True)
+    ok = results["v1_56_t48"] and all(d <= ATOL for d in max_abs.values())
+    print(
+        json.dumps({"ok": ok, "bit_identical": results, "max_abs": max_abs}),
+        flush=True,
+    )
     return 0 if ok else 1
 
 

@@ -619,8 +619,11 @@ def test_runs_on_the_committed_round_files(capsys):
     out = capsys.readouterr().out
     assert rc in (0, 1)
     assert "regression(s)" in out
-    # Round labels come from the files' own "n" fields.
-    assert "r01" in out or "#0" in out
+    # Round labels come from the files' own "n" fields (r01 and r02 left
+    # the tree in PR 24; the oldest record kept is whatever sorts first).
+    with open(files[0]) as f:
+        first = json.load(f)["n"]
+    assert f"r{first:02d}" in out or "#0" in out
 
 def test_overlap_series_trended_with_correct_signs(tmp_path):
     """ISSUE satellite: the sp2x2_overlap extra's per-arm measured

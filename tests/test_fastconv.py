@@ -226,7 +226,7 @@ def test_packed_spatial_conv_matches_golden(monkeypatch):
     """The production TPU shape: Conv2d(spatial=True) under shard_map with
     the packed impl, forward AND gradient vs the full-image plain golden."""
     monkeypatch.setenv("MPI4DL_TPU_CONV_IMPL", "packed")
-    from mpi4dl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from mpi4dl_tpu.config import ParallelConfig

@@ -38,7 +38,25 @@ def test_resnet_train_flops_sane():
 
 
 def test_mfu_none_off_tpu():
-    assert mfu(10.0, 1e12) is None  # CPU test process: unknown peak
+    assert mfu(10.0, 1e12) is None  # CPU test process: no peak
+
+
+def test_peak_flops_unknown_tpu_is_an_error():
+    """None is for CPU only: a TPU the table does not list must not
+    quietly drop the MFU figure."""
+    import types
+
+    import pytest
+
+    from mpi4dl_tpu.flops import peak_flops
+
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert peak_flops(v5e) == 197e12
+    unknown = types.SimpleNamespace(platform="tpu", device_kind="TPU v99")
+    with pytest.raises(ValueError, match="TPU v99"):
+        peak_flops(unknown)
+    with pytest.raises(ValueError):
+        mfu(10.0, 1e12, device=unknown)
 
 
 def test_flops_counted_inside_cond_branches():

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from mpi4dl_tpu.compat import shard_map
+from jax import shard_map
 
 from mpi4dl_tpu.parallel.halo import halo_exchange
 

@@ -11,16 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from mpi4dl_tpu.compat import shard_map
+from jax import shard_map
 
 from mpi4dl_tpu.ops import halo_pallas
 from mpi4dl_tpu.parallel.halo import halo_exchange
-
-pytestmark = pytest.mark.skipif(
-    jax.default_backend() != "tpu" and not halo_pallas.interpret_available(),
-    reason="this jax has no TPU-Pallas CPU interpreter "
-    "(InterpretParams/TPUInterpretParams)",
-)
 
 SPEC = P(None, "tile_h", "tile_w", None)
 

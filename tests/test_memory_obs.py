@@ -21,7 +21,7 @@ from mpi4dl_tpu.telemetry import memory as memobs
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # Canned real-shape XLA messages. The HBM table is the docs/PERF.md
-# round-4 incident: the compile helper dying at buffer assignment with
+# round-4 incident: the compile dying at buffer assignment with
 # the full breakdown — including the 16x-padded wgrad copy of
 # f32[1,3072,3072,16] that PERF.md's whack-a-mole ledger names.
 HBM_OOM = """\
@@ -125,7 +125,7 @@ def test_is_oom_error_walks_exception_chain():
         try:
             raise RuntimeError(HBM_OOM)
         except RuntimeError as inner:
-            raise ValueError("compile helper died") from inner
+            raise ValueError("compile died") from inner
     except ValueError as e:
         wrapped = e
     assert memobs.is_oom_error(wrapped)

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from mpi4dl_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mpi4dl_tpu.models.resnet import get_resnet_v1, get_resnet_v2

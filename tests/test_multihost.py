@@ -31,11 +31,12 @@ def test_make_multihost_mesh_falls_back_single_slice():
     assert (mesh.devices == cfg.make_mesh().devices).all()
 
 
-def test_initialize_distributed_swallows_only_unconfigured(monkeypatch):
-    """Single process with no coordinator: init failure is the expected
-    'nothing to join' case. With a coordinator configured (env or argument),
-    the same failure MUST propagate — swallowing it would silently degrade a
-    pod launch to N independent single-host jobs."""
+def test_initialize_distributed_joins_only_a_configured_world(monkeypatch):
+    """Single process with no coordinator: nothing is joined and jax is not
+    even asked (on a one-chip TPU VM without a metadata server the bare
+    call fails). With a coordinator configured (env, argument or launcher
+    marker), a failure MUST propagate — swallowing it would silently
+    degrade a pod launch to N independent single-host jobs."""
     calls = []
 
     def fake_init(coordinator_address=None, num_processes=None, process_id=None):
@@ -45,11 +46,13 @@ def test_initialize_distributed_swallows_only_unconfigured(monkeypatch):
     monkeypatch.setattr(jax.distributed, "initialize", fake_init)
     for var in multihost._COORDINATOR_ENV_VARS + multihost._MULTIPROC_ENV_MARKERS:
         monkeypatch.delenv(var, raising=False)
-    # CI may itself run under Slurm/MPI; pin auto-detection off so the
-    # "unconfigured" branch is what's actually exercised.
-    monkeypatch.setattr(multihost, "_cluster_autodetected", lambda: False)
-    multihost.initialize_distributed()  # unconfigured → swallowed
-    assert calls == [None]  # initialize was actually attempted
+    multihost.initialize_distributed()  # unconfigured → nothing to join
+    assert calls == []
+
+    monkeypatch.setenv("SLURM_NTASKS", "2")  # a launcher's marker alone
+    with pytest.raises(RuntimeError):
+        multihost.initialize_distributed()
+    monkeypatch.delenv("SLURM_NTASKS")
 
     monkeypatch.setenv("JAX_COORDINATOR_ADDRESS", "badhost:1234")
     with pytest.raises(RuntimeError):
@@ -60,12 +63,7 @@ def test_initialize_distributed_swallows_only_unconfigured(monkeypatch):
 
 
 def test_initialize_distributed_noop_when_initialized(monkeypatch):
-    # raising=False: jax 0.4.x has no is_initialized; the compat probe
-    # (mpi4dl_tpu.compat.distributed_is_initialized) prefers the
-    # attribute whenever it exists, so the monkeypatch works on any jax.
-    monkeypatch.setattr(
-        jax.distributed, "is_initialized", lambda: True, raising=False
-    )
+    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: True)
 
     def boom(*a, **k):  # must not be reached
         raise AssertionError("initialize called despite is_initialized()")

@@ -389,24 +389,6 @@ def test_1f1b_pipeline_matches_golden_and_gpipe():
     )
 
 
-# jax 0.4.x cannot differentiate the GEMS schedule's shard_map at all:
-# with check_vma/check_rep=False its transpose rule trips an internal
-# _SpecError on the scalar loss outputs, and the check_rep=True rewrite
-# path rejects the chunk scan's lax.cond ("branches produced mismatched
-# replication types" — the workaround it suggests IS check_rep=False).
-# Fixed upstream in later jax; nothing repo-side short of rewriting the
-# schedule can dodge both.
-_GEMS_GRAD_BROKEN = tuple(
-    int(p) for p in jax.__version__.split(".")[:2]
-) < (0, 5)
-
-
-@pytest.mark.skipif(
-    _GEMS_GRAD_BROKEN,
-    reason="jax 0.4.x shard_map transpose cannot differentiate the GEMS "
-    "schedule (_SpecError with check_rep=False, cond rep-type mismatch "
-    "with check_rep=True)",
-)
 @pytest.mark.parametrize(
     "times",
     [
@@ -431,11 +413,6 @@ def test_gems_master_matches_golden(times):
     _run_and_compare(trainer)
 
 
-@pytest.mark.skipif(
-    _GEMS_GRAD_BROKEN,
-    reason="tracing the GEMS train-step jaxpr differentiates the schedule "
-    "(same jax 0.4.x shard_map transpose limitation)",
-)
 def test_gems_times_constant_program_size():
     """The GEMS chunk loop is a ``lax.scan`` over normal/mirror pairs
     (``GemsMasterTrainer._local_loss``): the traced program must contain

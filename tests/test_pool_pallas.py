@@ -62,7 +62,9 @@ def test_bwd_matches_select_and_scatter(shape, k, s, p, tie_heavy):
     dy = jnp.asarray(
         rng.integers(-64, 64, size=(shape[0], ho, wo, shape[3])), jnp.float32
     )
-    assert pool_pallas.supported(shape, k, k, s, s, p, p, 4)
+    # The dispatch gate declines strided shapes (the chip's compiler refuses
+    # them, see supported()); the kernel's math is held to XLA's either way.
+    assert pool_pallas.supported(shape, k, k, s, s, p, p, 4) == (s == 1)
     got = _kernel_dx(x, dy, k, k, s, s, p, p)
     want = _xla_dx(x, dy, k, k, s, s, p, p)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -96,8 +98,8 @@ def test_gates(monkeypatch):
 def test_disable_context():
     """Trainer arms pool_pallas.disable() for >=2048px traces: injecting
     the kernel's VMEM-stack-allocated results into a program compiled
-    against the HBM ceiling kills the compile helper (round-4 incident:
-    AmoebaNet@2048 bs1 compiled with the kernels off, died with them on).
+    against the HBM ceiling fails the compile (round-4 incident:
+    AmoebaNet@2048 bs1 compiled with the kernels off, failed with them on).
     The context must gate dispatchable() regardless of backend."""
     x = jnp.zeros((2, 18, 18, 8), jnp.float32)
     with pool_pallas.disable():

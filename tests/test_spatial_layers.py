@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from mpi4dl_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mpi4dl_tpu.config import tile_grid
@@ -174,7 +174,7 @@ def test_pool_decomposed_backward_dispatch(spatial, monkeypatch):
 
         @jax.jit
         def loss(x):
-            from mpi4dl_tpu.compat import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec
 
             def local(xt):

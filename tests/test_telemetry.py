@@ -635,7 +635,7 @@ def full_stack(tmp_path_factory):
 
     from mpi4dl_tpu import profiling
     from mpi4dl_tpu.analysis.trace import publish_attribution
-    from mpi4dl_tpu.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()), ("x",))
     n = len(jax.devices())

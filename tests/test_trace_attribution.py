@@ -34,9 +34,9 @@ _META = [
     {"ph": "M", "pid": 1, "tid": 10, "name": "thread_name",
      "args": {"name": "python"}},
     {"ph": "M", "pid": 1, "tid": 20, "name": "thread_name",
-     "args": {"name": "tf_XLATfrtCpuClient/111"}},
+     "args": {"name": "tf_XLAPjRtCpuClient/111"}},
     {"ph": "M", "pid": 1, "tid": 21, "name": "thread_name",
-     "args": {"name": "tf_XLATfrtCpuClient/222"}},
+     "args": {"name": "tf_XLAPjRtCpuClient/222"}},
 ]
 
 _STEPS = [
@@ -61,7 +61,7 @@ _DEVICE = [
     # runtime bookkeeping that must NOT count as device busy time — the
     # ExecuteHelper wrapper spans the whole step and would double it.
     {"ph": "X", "pid": 1, "tid": 20, "ts": 0, "dur": 1000,
-     "name": "TfrtCpuExecutable::ExecuteHelper"},
+     "name": "PjRtCpuExecutable::ExecuteHelper"},
     {"ph": "X", "pid": 1, "tid": 20, "ts": 0, "dur": 50,
      "name": "ThreadpoolListener::StartRegion"},
     {"ph": "X", "pid": 1, "tid": 20, "ts": 600, "dur": 300,
@@ -163,7 +163,7 @@ def test_categorize_noise_filter():
     assert categorize("D2D Dispatch") == "transfer"
     assert categorize("TransferToDeviceStream") == "transfer"
     assert categorize("fusion.3") == "compute"
-    assert categorize("TfrtCpuExecutable::ExecuteHelper") is None
+    assert categorize("PjRtCpuExecutable::ExecuteHelper") is None
     assert categorize("ThunkExecutor::Execute (wait for completion)") is None
     assert categorize("$profiling.py:141 annotate_step") is None
 
@@ -244,7 +244,7 @@ def test_capture_live_attribution_sums_and_crosscheck(tmp_path):
     from jax.sharding import Mesh, PartitionSpec as P
 
     from mpi4dl_tpu.analysis import analyze_compiled
-    from mpi4dl_tpu.compat import shard_map
+    from jax import shard_map
 
     n = len(jax.devices())
     mesh = Mesh(np.array(jax.devices()), ("x",))

@@ -679,3 +679,91 @@ def test_compact_restore_mixed_tree_roundtrip():
     restored = Trainer._restore(compact, meta)
     for k in tree:
         np.testing.assert_array_equal(np.asarray(restored[k]), np.asarray(tree[k]))
+
+
+@pytest.mark.parametrize(
+    "image_size,policy",
+    [
+        (32, False), (1024, False), (2047, False), (2048, "scan"),
+        (3071, "scan"), (3072, "scanlog"), (4095, "scanlog"),
+        (4096, "scanq"), (8192, "scanq"),
+    ],
+)
+def test_default_remat_is_one_fixed_rule_on_image_size(image_size, policy):
+    """The entry points' remat policy (benchmarks/common.make_trainer,
+    bench.py) is a fixed choice, not a ladder of attempts: nothing below
+    2048 px — both 1024 px reference configurations fit a 16 GB v5e chip
+    with everything stored (PR 24's described-chip compiles) — then
+    scan / scanlog / scanq."""
+    from mpi4dl_tpu.train import default_remat
+
+    assert default_remat(image_size) == policy
+    Trainer(  # whatever the rule names, the Trainer accepts
+        get_resnet_v1(depth=8), num_spatial_cells=0, remat=policy,
+        config=ParallelConfig(batch_size=2, split_size=1, spatial_size=0),
+    )
+
+
+def test_init_places_state_on_mesh_so_step_two_does_not_recompile():
+    """A state left on the default device reaches step 2 with other input
+    shardings than step 1 had (train_step returns it mesh-replicated) and
+    the whole step compiles twice — minutes on the chip at full width."""
+    from jax.sharding import NamedSharding
+
+    cfg = ParallelConfig(
+        batch_size=4, split_size=1, spatial_size=0, image_size=32,
+        data_parallel=2,
+    )
+    trainer = Trainer(get_resnet_v1(depth=8), num_spatial_cells=0, config=cfg)
+    state = trainer.init(jax.random.PRNGKey(0), (4, 32, 32, 3))
+    for leaf in jax.tree.leaves(state):
+        assert isinstance(leaf.sharding, NamedSharding)
+        assert leaf.sharding.mesh == trainer.mesh
+        assert leaf.sharding.is_fully_replicated
+    xs, ys = trainer.shard_batch(*_batch(4, 32))
+    for _ in range(2):
+        state, metrics = trainer.train_step(state, xs, ys)
+        float(metrics["loss"])
+    assert trainer._jit_step._cache_size() == 1
+
+
+def test_entry_point_sp_with_every_stage_spatial_matches_one_device(monkeypatch):
+    """``--spatial-size == --split-size`` through benchmarks/common.py's
+    builders: every stage is spatial, yet the head cell never is, so the
+    tile merge must come before it (``spatial_cell_count`` stops one cell
+    short). Before PR 24 each tile classified its own quarter and the
+    first-step loss was off by 3.8% in f32; now it equals the one-device
+    program's. This is the comparison ``chip_smoke.py --chips 4`` makes on
+    the chips at full width."""
+    import os
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(repo)
+    monkeypatch.setenv("MPI4DL_TPU_RESNET_N", "1")
+    from benchmarks.common import build_resnet, make_trainer
+    from mpi4dl_tpu.parallel.pipeline import PipelineTrainer
+    from mpi4dl_tpu.parser import get_parser
+
+    base = ["--batch-size", "2", "--image-size", "64", "--split-size", "1",
+            "--precision", "fp32"]
+    sp = ["--num-spatial-parts", "4", "--slice-method", "square",
+          "--spatial-size", "1"]
+    x, y = _batch(2, 64)
+    losses = {}
+    for tag, argv, spatial in (("one", base, 0), ("sp", base + sp, 1)):
+        args = get_parser().parse_args(argv)
+        cfg = ParallelConfig(
+            batch_size=2, split_size=1, spatial_size=spatial,
+            num_spatial_parts=(4,), slice_method="square", image_size=64,
+        )
+        n_cells = len(build_resnet(args, cfg)[1])
+        n_sp = PipelineTrainer.spatial_cell_count(n_cells, cfg) if spatial else 0
+        assert n_sp == (n_cells - 1 if spatial else 0)
+        cells, plain = build_resnet(args, cfg, spatial_cells=n_sp)
+        trainer, n_out = make_trainer(args, cfg, cells, plain)
+        assert n_out == n_sp and trainer.remat is False
+        state = trainer.init(jax.random.PRNGKey(0), (2, 64, 64, 3))
+        _, metrics = trainer.train_step(state, *trainer.shard_batch(x, y))
+        losses[tag] = float(metrics["loss"])
+    np.testing.assert_allclose(losses["sp"], losses["one"], rtol=2e-4)
