@@ -23,6 +23,7 @@ prints); the last line is exactly
 from __future__ import annotations
 
 import argparse
+import concurrent.futures
 import contextlib
 import gc
 import importlib.metadata
@@ -32,6 +33,7 @@ import math
 import os
 import sys
 import time
+import types
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, ROOT)
@@ -45,20 +47,26 @@ SP_ARGV = [
     "--num-spatial-parts", "4", "--slice-method", "square",
     "--spatial-size", "1",
 ]
-# First-step loss, SP 2x2 against one chip, relative. Same weights, same
-# batch, same math: in f32 the two agree to 1.7e-5 on the CPU mesh (3e-6
-# with the packed conv forced), and tests/test_train.py holds that. What
-# differs on the chips is bf16 rounding (8 mantissa bits) under another
-# summation order — each conv sums over a 512x512 tile plus halo with pack
-# factors chosen from the tile's shape, every BatchNorm's statistics are
-# reduced per tile and then across the four chips — and at random init
-# with batch 2 the loss is that sensitive. Measured on v5e (PR 24): the
-# one-chip model's first loss is 2.3456 in f32 and 2.3236 / 2.2935 /
-# 2.2750 in bf16 (forward only with the packed conv, forward only with
-# XLA's conv, inside the train step): 3.0% spread from arithmetic alone.
-# SP 2x2 read 2.1576, 5.2% from the one-chip train step. So this is a
-# check against garbage (a lost tile, a NaN), not against a few percent.
-SP_LOSS_RTOL = 1e-1
+# --chips 4 compares the first-step loss of SP 2x2 with the one-chip
+# program's, relative, for the weights of PRNGKey(0) (the three steps) and
+# of each seed in EXTRA_SEEDS. Same weights, same batch, same math: in f32
+# the two agree to 6e-5 on the CPU mesh (16 seeds) and tests/test_train.py
+# holds them to 2e-4 — THAT is the check which catches a wrong SP program
+# (the head classified per tile, repaired in PR 24, is 3.6-6.2% off there).
+# In bf16 no limit can, because the freshly initialised net amplifies what
+# it is given: on the v5e chip a 1e-3 relative nudge of the input image
+# (an eighth of a bf16 rounding step) moves this model's first-batch loss
+# by up to 5.1% (mean 1.6%, 72 readings), and bf16 rounds by 2^-8 after
+# every op, at places that differ between two programs of the same math.
+# Readings of two sound bf16 programs against each other (PR 24, PERF.md
+# section 6): CPU mesh, a 6-layer model, 16 seeds, SP against one device
+# 0.3-4.8%, either sign (the planted head fault: 2.0-9.6%, overlapping);
+# v5e, this model, one chip, packed against XLA conv 0.2-1.9% (4 seeds)
+# and 2.1% across three programs of seed 0; SP 2x2 against the one-chip
+# step 5.16%. The limit is twice the largest reading on the chip, rounded
+# up: a check against a lost tile, a NaN or a wrong batch, nothing finer.
+SP_LOSS_RTOL = 0.11
+EXTRA_SEEDS = (1, 2)
 
 
 def emit(**fields):
@@ -66,24 +74,25 @@ def emit(**fields):
 
 
 def kernel_table():
-    """kernel name in the compiled text -> (module, the entry its dispatch
-    site calls once the gate has admitted a shape)."""
+    """kernel name in the compiled text -> (module, the function that holds
+    its one ``pallas_call``): a call of it while the step is traced is one
+    ``tpu_custom_call`` the compiled step must hold."""
     from mpi4dl_tpu.ops import (
         dot1x1_pallas, halo_pallas, pool_pallas, wgrad_pallas,
     )
 
     return {
-        pool_pallas.KERNEL_NAME: (pool_pallas, "max_pool"),
+        pool_pallas.KERNEL_NAME: (pool_pallas, "_bwd_padded"),
         wgrad_pallas.KERNEL_NAME: (wgrad_pallas, "wgrad"),
-        dot1x1_pallas.KERNEL_NAME: (dot1x1_pallas, "bwd_1x1"),
-        halo_pallas.KERNEL_NAME: (halo_pallas, "halo_exchange_pallas"),
+        dot1x1_pallas.KERNEL_NAME: (dot1x1_pallas, "_bwd_impl"),
+        halo_pallas.KERNEL_NAME: (halo_pallas, "_swap_call"),
     }
 
 
 @contextlib.contextmanager
-def count_dispatches(table):
-    """Each kernel entry wrapped with a call counter while a step is traced;
-    yields the counts."""
+def count_calls(table):
+    """Each kernel's ``pallas_call`` site wrapped with a call counter while
+    a step is traced; yields the counts."""
     calls = dict.fromkeys(table, 0)
     entries = {n: getattr(m, a) for n, (m, a) in table.items()}
     for name, (module, attr) in table.items():
@@ -110,17 +119,15 @@ def peak_bytes(device):
     return device.memory_stats()["peak_bytes_in_use"]
 
 
-def run_phase(tag, argv, spatial, cache_events):
-    """Build through the entry points' own functions, compile, take STEPS
-    steps; returns the losses, the compiled step's collective-permute count
-    and what was left on the devices."""
+def build(tag, argv, spatial):
+    """The program as the benchmark entry points build it, its first STEPS
+    batches on the mesh, and the step traced and lowered (the kernels'
+    ``pallas_call``s counted meanwhile)."""
     import jax
     import jax.numpy as jnp
 
     from benchmarks.common import build_amoebanet, build_config, make_trainer
-    from mpi4dl_tpu import native
     from mpi4dl_tpu.data import get_dataset
-    from mpi4dl_tpu.flops import mfu, train_flops_per_image
     from mpi4dl_tpu.parallel.pipeline import PipelineTrainer
     from mpi4dl_tpu.parser import get_parser
 
@@ -133,41 +140,82 @@ def run_phase(tag, argv, spatial, cache_events):
     )
     cells, plain = build_amoebanet(args, cfg, spatial_cells=n_spatial)
     trainer, _ = make_trainer(args, cfg, cells, plain)
-
     batches = [
         trainer.shard_batch(jnp.asarray(x), jnp.asarray(y))
         for x, y in itertools.islice(
             iter(get_dataset(args, cfg.batch_size, cfg.num_classes)), STEPS
         )
     ]
-    state = trainer.init(
-        jax.random.PRNGKey(0),
-        (cfg.batch_size, cfg.image_size, cfg.image_size, 3),
-    )
-
-    table = kernel_table()
-    before = dict(cache_events)
-    t0 = time.perf_counter()
+    shape = (cfg.batch_size, cfg.image_size, cfg.image_size, 3)
+    state = trainer.init(jax.random.PRNGKey(0), shape)
     # At 1024 px Trainer.train_step arms no trace-time context, so this is
     # the program the steps below run.
-    with count_dispatches(table) as admitted:
-        compiled = trainer._jit_step.lower(state, *batches[0]).compile()
-    compile_s = time.perf_counter() - t0
-    text = compiled.as_text()
-    found = kernels_in(text, table)
-    permutes = text.count(" collective-permute")
-    for name in table:
-        if bool(admitted[name]) != bool(found[name]):
-            raise SystemExit(
-                f"{tag}: kernel {name}: gate admitted {admitted[name]} "
-                f"shapes while tracing, compiled step holds {found[name]} "
-                "tpu_custom_calls"
-            )
+    with count_calls(kernel_table()) as traced:
+        lowered = trainer._jit_step.lower(state, *batches[0])
+    return types.SimpleNamespace(
+        tag=tag, cfg=cfg, trainer=trainer, batches=batches, shape=shape,
+        state=state, lowered=lowered, kernels_traced=traced,
+    )
 
-    losses, step_s = [], []
-    for xs, ys in batches:
+
+CACHE_EVENTS = {"cache_hits": 0, "cache_misses": 0}
+
+
+def count_cache_event(event, **_):
+    key = event.rsplit("/", 1)[-1]
+    if event.startswith("/jax/compilation_cache/") and key in CACHE_EVENTS:
+        CACHE_EVENTS[key] += 1
+
+
+def compile_steps(*phases):
+    """Compile the lowered steps, side by side where there are two: the
+    chip's compiler works one program on one core for minutes, and under
+    --chips 4 four chips are held meanwhile."""
+    import jax
+
+    def compile_one(phase):
         t0 = time.perf_counter()
-        state, metrics = trainer.train_step(state, xs, ys)
+        phase.compiled = phase.lowered.compile()
+        phase.compile_seconds = time.perf_counter() - t0
+
+    before = dict(CACHE_EVENTS)
+    t0 = time.perf_counter()
+    with concurrent.futures.ThreadPoolExecutor(len(phases)) as pool:
+        for done in [pool.submit(compile_one, p) for p in phases]:
+            done.result()
+    emit(
+        phase="compile",
+        seconds={p.tag: p.compile_seconds for p in phases},
+        wall_seconds=time.perf_counter() - t0,
+        compile_cache={k: CACHE_EVENTS[k] - before[k] for k in CACHE_EVENTS},
+        compile_cache_dir=jax.config.jax_compilation_cache_dir,
+    )
+
+
+def run_steps(phase):
+    """Check the compiled step's kernels and take STEPS steps from the
+    weights of PRNGKey(0); returns the losses."""
+    import jax
+
+    from mpi4dl_tpu import native
+    from mpi4dl_tpu.flops import mfu, train_flops_per_image
+
+    tag, trainer, cfg = phase.tag, phase.trainer, phase.cfg
+    text = phase.compiled.as_text()
+    found = kernels_in(text, kernel_table())
+    if found != phase.kernels_traced:
+        raise SystemExit(
+            f"{tag}: pallas_calls traced {phase.kernels_traced}, "
+            f"tpu_custom_calls in the compiled step {found}"
+        )
+    phase.collective_permutes = text.count(" collective-permute")
+
+    if phase.state is None:
+        phase.state = trainer.init(jax.random.PRNGKey(0), phase.shape)
+    losses, step_s = [], []
+    for xs, ys in phase.batches:
+        t0 = time.perf_counter()
+        phase.state, metrics = trainer.train_step(phase.state, xs, ys)
         losses.append(float(metrics["loss"]))  # host read ends the step
         step_s.append(time.perf_counter() - t0)
     if not all(math.isfinite(l) for l in losses):
@@ -188,39 +236,62 @@ def run_phase(tag, argv, spatial, cache_events):
         n_devices=len(devices),
         mesh=dict(trainer.mesh.shape),
         remat=trainer.remat,
-        compile_seconds=compile_s,
+        compile_seconds=phase.compile_seconds,
         step_seconds=step_s,
         images_per_second=ips,
         mfu=util,
         losses=losses,
-        kernels_admitted=admitted,
+        kernels_traced=phase.kernels_traced,
         kernels_found=found,
-        collective_permutes=permutes,
-        compile_cache={
-            k: cache_events[k] - before[k] for k in cache_events
-        },
-        compile_cache_dir=jax.config.jax_compilation_cache_dir,
+        collective_permutes=phase.collective_permutes,
         peak_bytes_in_use=[peak_bytes(d) for d in devices],
         native_loader_used=native.available(),
     )
-    return losses, permutes, state, xs
+    return losses
 
 
-def smoke(chips, base_argv, cache_events):
+def first_losses(phase, seeds):
+    """The first-step loss from the weights of each seed. The trained state
+    is dropped first: the one-chip step has no room for a second one."""
+    import jax
+
+    phase.state = None
+    first = {}
+    for seed in seeds:
+        state = phase.trainer.init(jax.random.PRNGKey(seed), phase.shape)
+        state, metrics = phase.trainer.train_step(state, *phase.batches[0])
+        first[seed] = float(metrics["loss"])
+        del state, metrics
+    return first
+
+
+def smoke(chips):
     """The default phase, or with ``chips == 4`` the SP 2x2 phase and its
     one-chip comparison and nothing else."""
     import jax
 
     if chips == 1:
-        run_phase("one_chip", base_argv, False, cache_events)
+        one = build("one_chip", BASE_ARGV, False)
+        compile_steps(one)
+        run_steps(one)
         return
-    sp_losses, permutes, state, xs = run_phase(
-        "sp_2x2", base_argv + SP_ARGV, True, cache_events
-    )
-    if not permutes:
+    sp = build("sp_2x2", BASE_ARGV + SP_ARGV, True)
+    sp.state = None  # chip 0 cannot hold it beside the one-chip step
+    one = build("one_chip_reference", BASE_ARGV, False)
+    compile_steps(sp, one)
+
+    # The one-chip step first: it needs all but 1.5 GiB of chip 0, which
+    # the SP state and a loaded SP executable would take from it.
+    one_first = {0: run_steps(one)[0]} | first_losses(one, EXTRA_SEEDS)
+    del one
+    gc.collect()
+
+    sp_first = {0: run_steps(sp)[0]}
+    if not sp.collective_permutes:
         raise SystemExit("sp_2x2: no collective-permute in the compiled step")
     four = set(jax.devices()[:4])
-    leaves = jax.tree.leaves(state.params) + [xs]
+    xs = sp.batches[-1][0]
+    leaves = jax.tree.leaves(sp.state.params) + [xs]
     if not all({s.device for s in l.addressable_shards} == four for l in leaves):
         raise SystemExit("sp_2x2: a parameter or the batch is not on all four chips")
     b, h, w, c = xs.shape
@@ -228,23 +299,20 @@ def smoke(chips, base_argv, cache_events):
         raise SystemExit("sp_2x2: the batch is not split into 2x2 tiles")
     if not all(peak_bytes(d) > 0 for d in four):
         raise SystemExit("sp_2x2: a chip reports no memory in use")
-    # The comparison needs chip 0's memory back: drop the SP state, batch
-    # and executable before the one-chip program is built.
-    del state, xs, leaves
-    gc.collect()
-    jax.clear_caches()
-    ref_losses, _, _, _ = run_phase(
-        "one_chip_reference", base_argv, False, cache_events
-    )
-    rel = abs(sp_losses[0] - ref_losses[0]) / abs(ref_losses[0])
+    del leaves, xs
+    sp_first |= first_losses(sp, EXTRA_SEEDS)
+    rel = {
+        seed: abs(sp_first[seed] - one_first[seed]) / abs(one_first[seed])
+        for seed in one_first
+    }
     emit(
-        phase="compare", sp_first_loss=sp_losses[0],
-        one_chip_first_loss=ref_losses[0], rel_diff=rel, rtol=SP_LOSS_RTOL,
+        phase="compare", sp_first_loss=sp_first, one_chip_first_loss=one_first,
+        rel_diff=rel, rtol=SP_LOSS_RTOL,
     )
-    if rel > SP_LOSS_RTOL:
+    if not all(math.isfinite(r) and r <= SP_LOSS_RTOL for r in rel.values()):
         raise SystemExit(
-            f"first-step losses disagree: SP {sp_losses[0]} vs one chip "
-            f"{ref_losses[0]} (rel {rel} > {SP_LOSS_RTOL})"
+            f"first-step losses disagree: SP {sp_first} vs one chip "
+            f"{one_first} (rel {rel}, limit {SP_LOSS_RTOL})"
         )
 
 
@@ -278,16 +346,8 @@ def main():
         device_count=len(devices),
         peak_bf16_flops=peak_flops(devices[0]),
     )
-    cache_events = {"cache_hits": 0, "cache_misses": 0}
-
-    def on_event(event, **_):
-        key = event.rsplit("/", 1)[-1]
-        if event.startswith("/jax/compilation_cache/") and key in cache_events:
-            cache_events[key] += 1
-
-    jax.monitoring.register_event_listener(on_event)
-
-    smoke(opts.chips, BASE_ARGV, cache_events)
+    jax.monitoring.register_event_listener(count_cache_event)
+    smoke(opts.chips)
 
     print(json.dumps({
         "ok": True,

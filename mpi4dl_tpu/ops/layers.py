@@ -622,8 +622,8 @@ def max_pool_s1_valid(x, kh: int, kw: int):
     """
     from mpi4dl_tpu.ops import pool_pallas
 
-    if pool_pallas.dispatchable(x, kh, kw, 1, 1, 0, 0):
-        return pool_pallas.max_pool(x, kh, kw, 1, 1, 0, 0)
+    if pool_pallas.dispatchable(x, kh, kw, 0, 0):
+        return pool_pallas.max_pool(x, kh, kw, 0, 0)
     h, w = x.shape[1], x.shape[2]
     # Separable: max over rows, then cols (associativity makes the forward
     # identical to the 2-D window) — kh+kw maximum ops instead of kh*kw, and
@@ -699,23 +699,6 @@ class Pool(nn.Module):
 
         def apply_pool(t, pad):
             if self.kind == "max":
-                from mpi4dl_tpu.ops import pool_pallas
-
-                if (
-                    (sh, sw) != (1, 1)
-                    and pool_bwd_impl() != "decomposed"  # explicit A/B lever
-                    and pool_pallas.dispatchable(
-                        t, kh, kw, sh, sw, pad[0][0], pad[1][0]
-                    )
-                ):
-                    # Strided pools (the REDUCTION cells' k3 s2 / k2 s2):
-                    # identical forward to reduce_window; the backward is
-                    # the one-pass Pallas kernel instead of
-                    # select_and_scatter (6.9% of the AmoebaNet@1024 step —
-                    # docs/PERF.md round 4).
-                    return pool_pallas.max_pool(
-                        t, kh, kw, sh, sw, pad[0][0], pad[1][0]
-                    )
                 if (sh, sw) == (1, 1):
                     # Stride-1: shifted-maximum decomposition (cheap
                     # backward; see max_pool_s1_valid). -inf edge pad ==

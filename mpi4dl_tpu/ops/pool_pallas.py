@@ -1,51 +1,47 @@
-"""Pallas TPU kernel: one-pass max-pool backward (first-max-wins).
+"""Pallas TPU kernel: one-pass stride-1 max-pool backward (first-max-wins).
 
 Why: the round-4 AmoebaNet@1024 profile puts ~16% of the train step in
-max-pool backwards — ``select_and_scatter`` for the reduction cells'
-stride-2 pools (6.9%) plus the stride-1 shifted-maximum tree's
+max-pool backwards, most of it the stride-1 shifted-maximum tree's
 select/accumulate chains (most of the 10.3% ``mul`` + 4.0% ``max``
 classes; the genotype runs a 3x3 s1 max pool in every cell,
-``models/amoebanet.py``). Both existing backwards are multi-pass at HBM:
-``select_and_scatter`` walks windows sequentially, and the kh+kw tree
-backward re-materializes the select chain pass by pass. The reference
-leaves all of this to cuDNN (``MaxPool2d`` inside ``Pool``,
-``spatial.py:1416-1509``); on TPU the op is ours to schedule.
+``models/amoebanet.py``): the kh+kw tree backward re-materializes the
+select chain pass by pass at HBM. The reference leaves all of this to
+cuDNN (``MaxPool2d`` inside ``Pool``, ``spatial.py:1416-1509``); on TPU
+the op is ours to schedule.
 
 This kernel computes dx in ONE streaming pass: per (batch, window-row
-chunk, channel chunk) grid step it loads the padded input, the pooled
-output and the cotangent once into VMEM, recomputes each window's winner
-in-register (kh*kw compare/claim steps, row-major first-max-wins —
-the same tie semantics as ``select_and_scatter``'s GE select; the
-row-major first-claim decomposition was proved bit-equal to it on
-tie-heavy data in ``tests/test_spatial_layers.py``), and accumulates the
-scattered contributions in VMEM. HBM traffic is x + y + dy read once,
-dx written once — the roofline for this op.
+chunk, channel chunk) grid step it loads the padded input and the
+cotangent once into VMEM, recomputes each window's winner in-register
+(kh*kw compare/claim steps, row-major first-max-wins — the same tie
+semantics as ``select_and_scatter``'s GE select; the row-major
+first-claim decomposition was proved bit-equal to it on tie-heavy data in
+``tests/test_spatial_layers.py``), and accumulates the scattered
+contributions in VMEM. HBM traffic is x + dy read once, dx written once —
+the roofline for this op.
 
 Layout notes (mirrors ``wgrad_pallas``): blocks keep NHWC with C on
 lanes and W on sublanes; all in-kernel shifts are static ``lax.slice`` /
 ``jnp.pad`` on values; window-chunk overlap rows arrive through a second
 aligned BlockSpec ("tail"), and the per-chunk rows that spill past the
-chunk (a window's last kh-sh rows) leave through a second output the
+chunk (a window's last kh-1 rows) leave through a second output the
 wrapper folds back in — Pallas index maps cannot express overlapping
 blocks in either direction.
 
-Stride-2 support uses a parity ("polyphase") decomposition: dx rows/cols
-of each residue class (r mod sh, c mod sw) are produced as separate
-dense sub-arrays inside the kernel (taps grouped by parity; per class
-the scatter offsets are plain static shifts), and the wrapper
-interleaves the sh*sw classes back with one strided-set each — no
-interior-padded full-resolution scatter terms (the failure mode that
-made the XLA-level decomposition 32% SLOWER end-to-end,
-``pool_bwd_impl``/docs/PERF.md round 4).
+Stride 1 only. A strided variant (a parity-class decomposition, pre-round)
+is refused by the installed chip compiler — its per-class results are
+stack-allocated in VMEM and overflow it, [2,130,130,832] alone and
+[2,130,130,416] inside the SP 2x2 step (PR 24, compiled for a described
+v5e chip) — and was deleted; strided pools take XLA's
+``select_and_scatter``.
 
 Dispatch: ``usable()`` = TPU backend + shape gate (``supported``);
-shapes the gate declines take the existing tree / reduce_window paths. A
-shape the gate admits and the chip's compiler refuses is a bug in the
-gate and surfaces as the compiler's error (``tests/test_tpu_compile.py``
-compiles the admitted shapes of the full-width models for a described
-v5e chip). ``MPI4DL_TPU_POOL_PALLAS=off`` disables for A/B;
-``=on`` additionally neutralizes trainer-armed ``disable()`` heuristics
-(the >=2048px gate) for A/B re-validation.
+shapes the gate declines take the existing tree path. A shape the gate
+admits and the chip's compiler refuses is a bug in the gate and surfaces
+as the compiler's error (``tests/test_tpu_compile.py`` compiles the
+admitted shapes of the full-width models for a described v5e chip).
+``MPI4DL_TPU_POOL_PALLAS=off`` disables for A/B; ``=on`` additionally
+neutralizes trainer-armed ``disable()`` heuristics (the >=2048px gate)
+for A/B re-validation.
 """
 
 from __future__ import annotations
@@ -106,60 +102,32 @@ class disable:
         return False
 
 
-def _class_geometry(kh, kw, sh, sw):
-    """Per parity class (cr, cc): max row/col shift (D, E). Class (cr, cc)
-    holds dx rows r ≡ cr (mod sh) / cols ≡ cc (mod sw); tap (u, v) with
-    u ≡ cr, v ≡ cc scatters window (a, b) to class position
-    (a + (u-cr)//sh, b + (v-cc)//sw) — a plain static shift."""
-    geo = {}
-    for cr in range(sh):
-        for cc in range(sw):
-            ups = [u for u in range(kh) if u % sh == cr]
-            vps = [v for v in range(kw) if v % sw == cc]
-            if not ups or not vps:
-                continue
-            geo[(cr, cc)] = (
-                max((u - cr) // sh for u in ups),
-                max((v - cc) // sw for v in vps),
-            )
-    return geo
-
-
-def _pool_bwd_kernel(*refs, kh, kw, sh, sw, to, wo):
+def _pool_bwd_kernel(*refs, kh, kw, to, wo):
     """One (batch, window-row chunk, channel chunk) grid step.
 
-    refs: per parity plane (in geometry order) a main x ref
-    [1, to, Wp_p, Cc] and — when the plane has row spill D > 0 — a tail
-    ref [1, D, Wp_p, Cc]; then the dy ref [1, to, Wo, Cc]; then the
-    outputs: per class a main ref [1, to, Wc, Cc] and (D > 0) a tail ref
-    [1, D, Wc, Cc] carved from a 4-D chunk-flattened [b, nrows*D, Wc, C]
-    array (a 5-D [b, nrows, D, Wc, C] form was rejected: the compiler
-    assigned it VMEM memory space and stack-allocated the whole array —
-    see the out_specs comment). Input planes and output classes share the same
-    parity geometry: tap (u, v) lives on plane (u%sh, v%sw) at offset
-    (u//sh, v//sw), and scatters window (a, b) to dx class (u%sh, v%sw)
-    at the same offset — dx is in input coordinates.
+    refs: the main x ref [1, to, Wp, Cc] and — when kh > 1 — a tail ref
+    [1, kh-1, Wp, Cc] with the rows the chunk's last windows reach into;
+    the dy ref [1, to, Wo, Cc]; then the outputs: a main ref
+    [1, to, Wp, Cc] and (kh > 1) a tail ref [1, kh-1, Wp, Cc] carved from
+    a 4-D chunk-flattened [b, nrows*(kh-1), Wp, C] array (a 5-D
+    [b, nrows, kh-1, Wp, C] form was rejected: the compiler assigned it
+    VMEM memory space and stack-allocated the whole array — see the
+    out_specs comment). Tap (u, v) of window (a, b) is input position
+    (a + u, b + v), and scatters there — dx is in input coordinates.
     """
-    geo = _class_geometry(kh, kw, sh, sw)
-    ri = 0
-    planes = {}
-    for key, (dmax, emax) in geo.items():
-        xpl = refs[ri][0]
+    xp = refs[0][0]
+    ri = 1
+    if kh > 1:
+        xp = jnp.concatenate([xp, refs[ri][0]], axis=0)
         ri += 1
-        if dmax:
-            xpl = jnp.concatenate([xpl, refs[ri][0]], axis=0)
-            ri += 1
-        planes[key] = xpl
     dy = refs[ri][0]
     outs = refs[ri + 1 :]
     c = dy.shape[-1]
     zero = jnp.zeros((), dy.dtype)
 
     def tap(u, v):
-        """This tap's value per window: a contiguous plane slice."""
-        xpl = planes[(u % sh, v % sw)]
-        d, e = u // sh, v // sw
-        return lax.slice(xpl, (d, e, 0), (d + to, e + wo, c))
+        """This tap's value per window: a contiguous slice."""
+        return lax.slice(xp, (u, v, 0), (u + to, v + wo, c))
 
     # Online argmax in window order: strict > keeps the FIRST maximum —
     # select_and_scatter's tie rule. Compares run in f32 (Mosaic on this
@@ -185,25 +153,18 @@ def _pool_bwd_kernel(*refs, kh, kw, sh, sw, to, wo):
                 idx = jnp.where(better, ti, idx)
             ti += 1
 
-    # Per-class accumulation: static shifted adds inside VMEM.
-    oi = 0
-    for (cr, cc), (dmax, emax) in geo.items():
-        acc = None
-        for u in range(cr, kh, sh):
-            d = (u - cr) // sh
-            for v in range(cc, kw, sw):
-                e = (v - cc) // sw
-                contrib = jnp.where(idx == (u * kw + v), dy, zero)
-                term = jnp.pad(
-                    contrib,
-                    ((d, dmax - d), (e, emax - e), (0, 0)),
-                )
-                acc = term if acc is None else acc + term
-        outs[oi][0] = acc[:to]
-        oi += 1
-        if dmax:
-            outs[oi][0] = acc[to:]
-            oi += 1
+    # Accumulation: static shifted adds inside VMEM.
+    acc = None
+    for u in range(kh):
+        for v in range(kw):
+            contrib = jnp.where(idx == (u * kw + v), dy, zero)
+            term = jnp.pad(
+                contrib, ((u, kh - 1 - u), (v, kw - 1 - v), (0, 0))
+            )
+            acc = term if acc is None else acc + term
+    outs[0][0] = acc[:to]
+    if kh > 1:
+        outs[1][0] = acc[to:]
 
 
 def _chunk_c(c: int) -> int:
@@ -220,79 +181,56 @@ def _chunk_c(c: int) -> int:
     return c
 
 
-def _plan(c, ho, wo, kh, kw, sh, sw, itemsize):
+def _plan(c, ho, wo, kh, kw, itemsize):
     """Pick (row chunk ``to``, channel chunk); None when nothing fits."""
     cc = _chunk_c(c)
-    geo = _class_geometry(kh, kw, sh, sw)
+    d, e = kh - 1, kw - 1
     for to in (32, 16, 8, 4, 2, 1):
         if ho % to:
             continue
-        # Each plane's tail BlockSpec needs element row (i+1)*to to be a
-        # multiple of its own block height D.
-        if any(d > 0 and to % d for d, _ in geo.values()):
+        # The tail BlockSpec needs element row (i+1)*to to be a multiple
+        # of its own block height kh-1.
+        if d and to % d:
             continue
-        plane_bytes = sum(
-            (to + d) * (wo + e) * cc * itemsize for d, e in geo.values()
-        )
+        x_bytes = (to + d) * (wo + e) * cc * itemsize
         dy_bytes = to * wo * cc * itemsize
         argmax_bytes = to * wo * cc * 8  # f32 best + i32 idx
-        acc_bytes = max(
-            (to + d) * (wo + e) * cc * itemsize * 2  # acc + pad temp
-            for d, e in geo.values()
-        )
-        if (
-            plane_bytes + dy_bytes + argmax_bytes + acc_bytes
-            < _VMEM_BUDGET
-        ):
+        acc_bytes = x_bytes * 2  # acc + pad temp
+        if x_bytes + dy_bytes + argmax_bytes + acc_bytes < _VMEM_BUDGET:
             return to, cc
     return None
 
 
-def _out_geom(hp, wp, kh, kw, sh, sw):
-    """(ho, wo, covered hp, covered wp) under reduce_window "valid"."""
-    ho = (hp - kh) // sh + 1
-    wo = (wp - kw) // sw + 1
-    return ho, wo, (ho - 1) * sh + kh, (wo - 1) * sw + kw
-
-
-def supported(x_shape, kh, kw, sh, sw, ph, pw, itemsize=2) -> bool:
+def supported(x_shape, kh, kw, ph, pw, itemsize=2) -> bool:
     b, h, w, c = x_shape
-    if kh <= sh and kw <= sw:
-        return False  # non-overlapping: XLA's backward is already a reshape
+    if kh == 1 and kw == 1:
+        return False
     # The TPU compiler may stack-allocate a Pallas custom call's results in
     # VMEM (docs/PERF.md round 4) and then fails the compile when they
     # overflow ("Ran out of memory in memory space vmem while allocating
     # on stack"). Measured by compiling for a described v5e chip (jax
     # 0.9.0, libtpu 0.0.34; tests/test_tpu_compile.py holds the gate to
-    # it). Stride-1 shapes compile alone up to 104.8 MiB of padded input
-    # (fail at 211 MiB) and inside the whole AmoebaNet-D 18/416 @1024
-    # step. Strided shapes — whose result set is the parity classes plus
-    # their tails — are refused whatever their size: [2,130,130,832] fails
-    # alone, and [2,130,130,416], which compiles alone, fails inside the
-    # SP 2x2 step, as [2,130,130,832] does under "scan_save". They take
-    # XLA's select_and_scatter.
-    if (sh, sw) != (1, 1):
-        return False
-    if b * (h + 2 * ph) * (w + 2 * pw) * c * itemsize > 100 * 2**20:
-        return False
+    # it): shapes compile alone up to 104.8 MiB of padded input (fail at
+    # 211 MiB) and inside the whole AmoebaNet-D 18/416 @1024 step.
     hp, wp = h + 2 * ph, w + 2 * pw
+    if b * hp * wp * c * itemsize > 100 * 2**20:
+        return False
     if hp < kh or wp < kw:
         return False
-    ho, wo, _, _ = _out_geom(hp, wp, kh, kw, sh, sw)
-    return _plan(c, ho, wo, kh, kw, sh, sw, itemsize) is not None
+    return _plan(c, hp - kh + 1, wp - kw + 1, kh, kw, itemsize) is not None
 
 
-def usable(x, kh, kw, sh, sw, ph, pw) -> bool:
+def usable(x, kh, kw, ph, pw) -> bool:
     if pool_pallas_mode() == "off":
         return False
     if jax.default_backend() != "tpu":
         return False
     if x.ndim != 4:
         return False
-    return supported(tuple(x.shape), kh, kw, sh, sw, ph, pw, x.dtype.itemsize)
+    return supported(tuple(x.shape), kh, kw, ph, pw, x.dtype.itemsize)
 
 
-def dispatchable(x, kh, kw, sh, sw, ph, pw) -> bool:
+def dispatchable(x, kh, kw, ph, pw) -> bool:
     """``usable`` + not under a batched (vmapped) trace. The pipeline's
     micro-batched front vmaps the cell stack; a batched ``pallas_call``
     compiles through an added grid dimension only sometimes, and the
@@ -305,92 +243,61 @@ def dispatchable(x, kh, kw, sh, sw, ph, pw) -> bool:
 
     if _DISABLED[0] or _xla_only_active() or _is_batch_tracer(x):
         return False
-    return usable(x, kh, kw, sh, sw, ph, pw)
+    return usable(x, kh, kw, ph, pw)
 
 
-def _bwd_padded(xp, dy, *, kh, kw, sh, sw, interpret=False):
+def _bwd_padded(xp, dy, *, kh, kw, interpret=False):
     """dxp [B, Hp, Wp, C] from the padded input and the cotangent."""
     b, hp, wp, c = xp.shape
     _, ho, wo, _ = dy.shape
-    _, _, hp_eff, wp_eff = _out_geom(hp, wp, kh, kw, sh, sw)
-    plan = _plan(c, ho, wo, kh, kw, sh, sw, xp.dtype.itemsize)
-    assert plan is not None, (xp.shape, kh, kw, sh, sw)
+    plan = _plan(c, ho, wo, kh, kw, xp.dtype.itemsize)
+    assert plan is not None, (xp.shape, kh, kw)
     to, cchunk = plan
     nrows = ho // to
     nc = c // cchunk
-    geo = _class_geometry(kh, kw, sh, sw)
-
-    # Windows cover padded rows/cols [0, hp_eff) x [0, wp_eff); anything
-    # past that (possible when the torch floor-mode output size leaves a
-    # trailing pad row uncovered, e.g. k3 s2 p1 on even sizes) gets zero
-    # gradient and is appended after the kernel. Parity planes are built
-    # HERE (XLA-side strided slices): Mosaic rejects strided vector
-    # extracts in-kernel, and planes make every kernel slice contiguous.
-    xe = xp[:, :hp_eff, :wp_eff, :]
+    d = kh - 1
 
     grid = (b * nrows * nc,)
 
     def idx(i):
         return (i // (nrows * nc), (i // nc) % nrows, i % nc)
 
-    in_specs, args = [], []
-    for (pr, pc), (dmax, emax) in geo.items():
-        plane = xe[:, pr::sh, pc::sw, :] if (sh, sw) != (1, 1) else xe
-        wpl = wo + emax
+    def main_block(width):
+        return pl.BlockSpec(
+            (1, to, width, cchunk),
+            lambda i: (idx(i)[0], idx(i)[1], 0, idx(i)[2]),
+        )
+
+    in_specs, args = [main_block(wp)], [xp]
+    if d:
+        # Overlap rows [ (i+1)*to, +d ) as an aligned block of height d
+        # (to % d == 0 via _plan).
         in_specs.append(
             pl.BlockSpec(
-                (1, to, wpl, cchunk),
-                lambda i: (idx(i)[0], idx(i)[1], 0, idx(i)[2]),
+                (1, d, wp, cchunk),
+                lambda i: (idx(i)[0], (idx(i)[1] + 1) * (to // d), 0, idx(i)[2]),
             )
         )
-        args.append(plane)
-        if dmax:
-            # Overlap rows [ (i+1)*to, +dmax ) as an aligned block of
-            # height dmax (to % dmax == 0 via _plan).
-            in_specs.append(
-                pl.BlockSpec(
-                    (1, dmax, wpl, cchunk),
-                    lambda i, d=dmax: (
-                        idx(i)[0], (idx(i)[1] + 1) * (to // d), 0, idx(i)[2]
-                    ),
-                )
-            )
-            args.append(plane)
-    in_specs.append(
-        pl.BlockSpec(
-            (1, to, wo, cchunk), lambda i: (idx(i)[0], idx(i)[1], 0, idx(i)[2])
-        )
-    )
+        args.append(xp)
+    in_specs.append(main_block(wo))
     args.append(dy)
 
-    out_specs, out_shapes = [], []
-    for (cr, cc_), (dmax, emax) in geo.items():
-        wc = wo + emax
+    out_specs = [main_block(wp)]
+    out_shapes = [jax.ShapeDtypeStruct((b, ho, wp, c), dy.dtype)]
+    if d:
+        # 4-D, chunk-flattened: [b, nrows*d, wp, c] — a 5-D
+        # [b, nrows, d, ...] form was assigned VMEM memory space by the
+        # compiler and stack-allocated the whole array.
         out_specs.append(
             pl.BlockSpec(
-                (1, to, wc, cchunk),
+                (1, d, wp, cchunk),
                 lambda i: (idx(i)[0], idx(i)[1], 0, idx(i)[2]),
             )
         )
-        out_shapes.append(jax.ShapeDtypeStruct((b, ho, wc, c), dy.dtype))
-        if dmax:
-            # 4-D, chunk-flattened: [b, nrows*dmax, wc, c] — a 5-D
-            # [b, nrows, dmax, ...] form was assigned VMEM memory space
-            # by the compiler and stack-allocated the whole array.
-            out_specs.append(
-                pl.BlockSpec(
-                    (1, dmax, wc, cchunk),
-                    lambda i: (idx(i)[0], idx(i)[1], 0, idx(i)[2]),
-                )
-            )
-            out_shapes.append(
-                jax.ShapeDtypeStruct((b, nrows * dmax, wc, c), dy.dtype)
-            )
+        out_shapes.append(jax.ShapeDtypeStruct((b, nrows * d, wp, c), dy.dtype))
 
     outs = pl.pallas_call(
-        functools.partial(
-            _pool_bwd_kernel, kh=kh, kw=kw, sh=sh, sw=sw, to=to, wo=wo
-        ),
+        functools.partial(_pool_bwd_kernel, kh=kh, kw=kw, to=to, wo=wo),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -398,82 +305,51 @@ def _bwd_padded(xp, dy, *, kh, kw, sh, sw, interpret=False):
         interpret=interpret,
         name=KERNEL_NAME,
     )(*args)
-    outs = list(outs) if isinstance(outs, (list, tuple)) else [outs]
-
-    # Reassemble: fold tails into each class, then interleave the classes
-    # with one strided-set each (sh*sw sub-arrays, not kh*kw full-res
-    # scatter terms).
-    dxe = jnp.zeros((b, hp_eff, wp_eff, c), dy.dtype)
-    oi = 0
-    for (cr, cc_), (dmax, emax) in geo.items():
-        main = outs[oi]
-        oi += 1
-        if dmax:
-            tails = outs[oi]
-            oi += 1
-            wc = wo + emax
-            # Chunk i's tail rows are class rows (i+1)*to + [0, dmax) —
-            # the next chunk's first rows (to >= dmax via _plan's choices).
-            # Lay the tails on a to-strided grid shifted by to, add, crop
-            # back to the class extent ho + dmax.
-            sub = jnp.concatenate(
-                [main, jnp.zeros((b, to, wc, c), dy.dtype)], axis=1
-            )
-            flat = jnp.pad(
-                tails.reshape(b, nrows, dmax, wc, c),
-                ((0, 0), (0, 0), (0, to - dmax), (0, 0), (0, 0)),
-            )
-            flat = flat.reshape(b, nrows * to, wc, c)
-            sub = sub.at[:, to : to + ho].add(flat)
-            sub = sub[:, : ho + dmax]
-        else:
-            sub = main
-        # Class (cr, cc_) rows/cols of dxe are exactly sub's extent:
-        # ceil((hp_eff - cr)/sh) == ho + dmax, same in W.
-        dxe = dxe.at[:, cr :: sh, cc_ :: sw, :].add(sub)
-    if hp_eff < hp or wp_eff < wp:
-        dxe = jnp.pad(
-            dxe,
-            (
-                (0, 0),
-                (0, hp - hp_eff),
-                (0, wp - wp_eff),
-                (0, 0),
-            ),
-        )
-    return dxe
+    if not d:
+        return outs[0]
+    main, tails = outs
+    # Chunk i's tail rows are rows (i+1)*to + [0, d) — the next chunk's
+    # first rows (to >= d via _plan's choices). Lay the tails on a
+    # to-strided grid shifted by to, add, crop back to hp = ho + d.
+    dxp = jnp.concatenate([main, jnp.zeros((b, to, wp, c), dy.dtype)], axis=1)
+    flat = jnp.pad(
+        tails.reshape(b, nrows, d, wp, c),
+        ((0, 0), (0, 0), (0, to - d), (0, 0), (0, 0)),
+    )
+    dxp = dxp.at[:, to : to + ho].add(flat.reshape(b, nrows * to, wp, c))
+    return dxp[:, :hp]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
-def max_pool(x, kh, kw, sh, sw, ph, pw):
-    """Max pool (−inf edge padding, torch ``MaxPool2d`` parity) whose
-    backward is the one-pass Pallas kernel. Forward ==
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def max_pool(x, kh, kw, ph, pw):
+    """Stride-1 max pool (−inf edge padding, torch ``MaxPool2d`` parity)
+    whose backward is the one-pass Pallas kernel. Forward ==
     ``lax.reduce_window(max)`` — the same values every other path here
     produces; only the backward's tie rule (first-max-wins) differs from
     the shifted-maximum tree's maximum-chain subgradients, which callers
     gate on (see ``max_pool_s1_valid``)."""
-    return _fwd_val(x, kh, kw, sh, sw, ph, pw)
+    return _fwd_val(x, kh, kw, ph, pw)
 
 
-def _fwd_val(x, kh, kw, sh, sw, ph, pw):
+def _fwd_val(x, kh, kw, ph, pw):
     neg = jnp.asarray(_NEG, x.dtype)
     xp = lax.pad(x, neg, ((0, 0, 0), (ph, ph, 0), (pw, pw, 0), (0, 0, 0)))
     return lax.reduce_window(
-        xp, neg, lax.max, (1, kh, kw, 1), (1, sh, sw, 1), "valid"
+        xp, neg, lax.max, (1, kh, kw, 1), (1, 1, 1, 1), "valid"
     )
 
 
-def _fwd(x, kh, kw, sh, sw, ph, pw):
+def _fwd(x, kh, kw, ph, pw):
     # Residual is x alone: the backward recomputes each window's winner
     # in-register (online argmax), so the pooled output never needs to
     # be saved or re-read.
-    return _fwd_val(x, kh, kw, sh, sw, ph, pw), x
+    return _fwd_val(x, kh, kw, ph, pw), x
 
 
-def _bwd(kh, kw, sh, sw, ph, pw, x, dy):
+def _bwd(kh, kw, ph, pw, x, dy):
     neg = jnp.asarray(_NEG, x.dtype)
     xp = lax.pad(x, neg, ((0, 0, 0), (ph, ph, 0), (pw, pw, 0), (0, 0, 0)))
-    dxp = _bwd_padded(xp, dy, kh=kh, kw=kw, sh=sh, sw=sw)
+    dxp = _bwd_padded(xp, dy, kh=kh, kw=kw)
     h, w = x.shape[1], x.shape[2]
     return (dxp[:, ph : ph + h, pw : pw + w, :],)
 

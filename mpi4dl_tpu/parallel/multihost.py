@@ -60,7 +60,9 @@ _COORDINATOR_ENV_VARS = (
 
 # Environment markers that unambiguously mean "more than one process was
 # launched" even when no coordinator address is spelled out (the launcher
-# provides it, and jax's own cluster detection reads the rest).
+# provides it, and jax's own cluster detection reads the rest). Checked
+# besides that detection (:func:`_detected_process_count`) so a jax-internal
+# move cannot silently turn these launches into single-host jobs.
 _MULTIPROC_ENV_MARKERS = (
     "OMPI_COMM_WORLD_SIZE",
     "SLURM_NTASKS",
@@ -82,6 +84,38 @@ def _launcher_present() -> bool:
     return False
 
 
+def _detected_process_count() -> int:
+    """How many processes jax's own cluster detection finds for this launch
+    (a GCE or GKE TPU pod, Slurm, OpenMPI, Kubernetes); 1 where it finds no
+    cluster. On such a pod the operator sets nothing: the hosts are found
+    from the metadata server or ``TPU_WORKER_HOSTNAMES``.
+
+    The TPU probes ask the metadata server (GCE for the worker list, GKE
+    for the slice count). Every host of a pod can reach one, so a probe
+    that cannot connect means there is no more to this launch than the
+    environment itself lists (a single TPU VM sealed off from the network
+    lists itself in ``TPU_WORKER_HOSTNAMES``): that one error is passed
+    over and the listed hosts are counted. Anything else a probe raises
+    propagates."""
+    import requests
+    from jax._src.clusters import ClusterEnv
+
+    for env in ClusterEnv._cluster_types:
+        if env.opt_in_only_method:
+            continue
+        try:
+            if env.is_env_present():
+                return env.get_process_count()
+        except requests.exceptions.ConnectionError:
+            continue
+    hosts = (
+        os.environ.get("TPU_PROCESS_ADDRESSES")
+        or os.environ.get("TPU_WORKER_HOSTNAMES")
+        or ""
+    )
+    return max(1, len([h for h in hosts.split(",") if h]))
+
+
 def initialize_distributed(
     coordinator_address: str | None = None,
     num_processes: int | None = None,
@@ -90,20 +124,19 @@ def initialize_distributed(
     """Join the multi-host world (ref ``dist.init_process_group``,
     ``comm.py:154-159``; launcher contract ``README.md:121-125``).
 
-    A world is configured by argument, by a coordinator variable
-    (``JAX_COORDINATOR_ADDRESS`` …) or by a launcher's marker (Slurm,
-    OpenMPI, multi-slice); what the arguments leave open jax's cluster
-    detection fills in. Then this must run before anything that
-    initializes the XLA backend (``jax.devices()``, array creation, …) and
-    its failures propagate — silently degrading a pod launch into N
-    independent single-host jobs is the one outcome this wrapper must
-    never produce. With none of these it is a single-process run: nothing
-    is joined and nothing is looked up. (Until PR 24 a bare
-    ``jax.distributed.initialize()`` was tried regardless and its failure
-    swallowed unless jax's Cloud-TPU sniff said "cluster" — which it says
-    on any machine with a TPU attached, so on a one-chip VM with no
-    metadata server every benchmark entry point died here.) Calling it
-    again once initialized is a no-op.
+    A world is there to join when one is configured — by argument, by a
+    coordinator variable (``JAX_COORDINATOR_ADDRESS`` …) or by a launcher's
+    marker (Slurm, OpenMPI, multi-slice) — or when jax's cluster detection
+    finds more than one process (a multi-host TPU pod, where the operator
+    sets none of these). Then this must run before anything that
+    initializes the XLA backend (``jax.devices()``, array creation, …),
+    what the arguments leave open jax fills in, and failures propagate —
+    silently degrading a pod launch into N independent single-host jobs is
+    the one outcome this wrapper must never produce. Otherwise it is a
+    single-process run and nothing is joined. (Until PR 24 a bare
+    ``jax.distributed.initialize()`` was tried regardless: on a one-chip
+    TPU VM with no metadata server every benchmark entry point died
+    here.) Calling it again once initialized is a no-op.
     """
     if jax.distributed.is_initialized():
         return
@@ -113,6 +146,7 @@ def initialize_distributed(
         or process_id is not None
         or any(os.environ.get(k) for k in _COORDINATOR_ENV_VARS)
         or _launcher_present()
+        or _detected_process_count() > 1
     )
     if not configured:
         return

@@ -144,16 +144,20 @@ def default_remat(image_size: int) -> "bool | str":
 
     - below 2048 px everything is stored (``False``). Compiled for a
       described v5e chip (16 GB) on jax 0.9.0 / libtpu 0.0.34 with nothing
-      rematerialized, AmoebaNet-D 18/416 @1024 bs2 bf16 needs 14.1 GiB
-      (the pool kernel's 42 calls included) and ResNet-110 @1024 bs2
-      13.2 GiB, so both of the reference's 1024 px configurations fit.
-      (On that compiler AmoebaNet's "scan_save", the pre-round headline
-      policy, no longer compiles with the pool kernel on: the stride-2
-      pool backward's results are stack-allocated in VMEM and overflow.)
+      rematerialized, AmoebaNet-D 18/416 @1024 bs2 bf16 needs 14.22 GiB
+      (the pool kernel's 40 calls included) and ResNet-110 @1024 bs2
+      13.23 GiB, so both of the reference's 1024 px configurations fit,
+      and the first ran on the chip (PR 24). (On that compiler AmoebaNet's
+      "scan_save", the pre-round headline policy, did not compile with
+      the pool kernel on.)
     - 2048-3071 px: "scan"; 3072-4095 px: "scanlog" (4x "scan2");
       from 4096 px: "scanq" ("scanlog"'s ~23.7 GB live set is an OOM).
-      These three are BENCH_r05.json / docs/PERF.md rounds 3-5, measured
-      on one v5e chip before this round (an older jax) and not re-checked.
+      UNVERIFIED on the installed compiler: these three are
+      BENCH_r05.json / docs/PERF.md rounds 3-5, measured on one v5e chip
+      before this round (an older jax) through ``bench.py``, which also
+      gave AmoebaNet @2048 a ``grad_accum`` and a save budget that the
+      benchmark entry points do not pass. No step at 2048 px or above has
+      been compiled for, or run on, a chip since.
     """
     if image_size < 2048:
         return False

@@ -62,6 +62,100 @@ def test_initialize_distributed_joins_only_a_configured_world(monkeypatch):
         multihost.initialize_distributed(coordinator_address="badhost:1234")
 
 
+def _metadata_of(endpoints=None):
+    """A metadata server: a GCE TPU VM's when it lists worker endpoints,
+    a GKE node's (no TPU worker attributes) when it does not."""
+    attrs = {"tpu-env": "ACCELERATOR_TYPE: 'v5litepod-8'"}
+    if endpoints:
+        attrs |= {"agent-worker-number": "0",
+                  "worker-network-endpoints": endpoints}
+
+    def get_metadata(key):
+        return (attrs[key], 200) if key in attrs else ("not found", 404)
+
+    return get_metadata
+
+
+def _no_metadata_server(key):
+    import requests
+
+    raise requests.exceptions.ConnectionError("metadata.google.internal")
+
+
+@pytest.mark.parametrize(
+    "tpu_vm, env, get_metadata, joins",
+    [
+        # GKE pod: the hosts are in the environment, the operator set nothing
+        (True, {"TPU_WORKER_HOSTNAMES": "h0,h1", "TPU_WORKER_ID": "1"},
+         _metadata_of(), True),
+        (True, {"TPU_WORKER_HOSTNAMES": "h0", "TPU_WORKER_ID": "0"},
+         _metadata_of(), False),
+        # GCE pod: the hosts are on the metadata server
+        (True, {}, _metadata_of("a:b:10.0.0.1,a:b:10.0.0.2"), True),
+        (True, {}, _metadata_of("a:b:10.0.0.1"), False),
+        # one TPU VM sealed off from the network: no metadata server at all;
+        # the machine behind the chip tool also lists itself as the one worker
+        (True, {}, _no_metadata_server, False),
+        (True, {"TPU_WORKER_HOSTNAMES": "localhost", "TPU_WORKER_ID": "0"},
+         _no_metadata_server, False),
+        # ... while two listed hosts are a pod, metadata server or not
+        (True, {"TPU_WORKER_HOSTNAMES": "h0,h1", "TPU_WORKER_ID": "1"},
+         _no_metadata_server, True),
+        # no TPU VM (the CPU sandbox): the probes are not even made
+        (False, {}, None, False),
+    ],
+    ids=["gke-pod", "gke-one-host", "gce-pod", "gce-one-host",
+         "sealed-one-host", "sealed-one-host-lists-itself",
+         "sealed-two-hosts", "no-tpu-vm"],
+)
+def test_initialize_distributed_joins_a_detected_pod(
+    monkeypatch, tpu_vm, env, get_metadata, joins
+):
+    """A single-slice multi-host TPU pod sets no coordinator variable and
+    no launcher marker: jax finds its hosts itself. It must still be
+    joined (and a failure there must propagate), while one host alone —
+    with or without a metadata server — has nothing to join."""
+    from jax._src.clusters import cloud_tpu_cluster
+
+    for var in (
+        multihost._COORDINATOR_ENV_VARS + multihost._MULTIPROC_ENV_MARKERS
+        + ("TPU_WORKER_HOSTNAMES", "TPU_PROCESS_ADDRESSES", "TPU_WORKER_ID",
+           "TPU_SKIP_MDS_QUERY", "MEGASCALE_COORDINATOR_ADDRESS")
+    ):
+        monkeypatch.delenv(var, raising=False)
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    monkeypatch.setattr(cloud_tpu_cluster, "running_in_cloud_tpu_vm", tpu_vm)
+    monkeypatch.setattr(cloud_tpu_cluster, "get_metadata", get_metadata)
+    calls = []
+
+    def fake_init(coordinator_address=None, num_processes=None, process_id=None):
+        calls.append((coordinator_address, num_processes, process_id))
+        raise RuntimeError("coordinator unreachable")
+
+    monkeypatch.setattr(jax.distributed, "initialize", fake_init)
+    if joins:
+        with pytest.raises(RuntimeError, match="coordinator unreachable"):
+            multihost.initialize_distributed()
+        assert calls == [(None, None, None)]  # jax fills in what it detected
+    else:
+        multihost.initialize_distributed()
+        assert calls == []
+
+
+def test_detection_probe_errors_other_than_no_server_propagate(monkeypatch):
+    from jax._src.clusters import cloud_tpu_cluster
+
+    def broken(key):
+        raise RuntimeError("Getting metadata['agent-worker-number'] failed")
+
+    monkeypatch.delenv("TPU_SKIP_MDS_QUERY", raising=False)
+    monkeypatch.setattr(cloud_tpu_cluster, "running_in_cloud_tpu_vm", True)
+    monkeypatch.setattr(cloud_tpu_cluster, "get_metadata", broken)
+    with pytest.raises(RuntimeError, match="agent-worker-number"):
+        multihost._detected_process_count()
+
+
 def test_initialize_distributed_noop_when_initialized(monkeypatch):
     monkeypatch.setattr(jax.distributed, "is_initialized", lambda: True)
 

@@ -18,55 +18,49 @@ import pytest
 from mpi4dl_tpu.ops import pool_pallas
 
 
-def _kernel_dx(x, dy, kh, kw, sh, sw, ph, pw):
+def _kernel_dx(x, dy, kh, kw, ph, pw):
     neg = jnp.asarray(float("-inf"), x.dtype)
     xp = jax.lax.pad(
         x, neg, ((0, 0, 0), (ph, ph, 0), (pw, pw, 0), (0, 0, 0))
     )
-    dxp = pool_pallas._bwd_padded(
-        xp, dy, kh=kh, kw=kw, sh=sh, sw=sw, interpret=True
-    )
+    dxp = pool_pallas._bwd_padded(xp, dy, kh=kh, kw=kw, interpret=True)
     h, w = x.shape[1], x.shape[2]
     return dxp[:, ph : ph + h, pw : pw + w, :]
 
 
-def _xla_dx(x, dy, kh, kw, sh, sw, ph, pw):
-    f = functools.partial(
-        pool_pallas._fwd_val, kh=kh, kw=kw, sh=sh, sw=sw, ph=ph, pw=pw
-    )
+def _xla_dx(x, dy, kh, kw, ph, pw):
+    f = functools.partial(pool_pallas._fwd_val, kh=kh, kw=kw, ph=ph, pw=pw)
     _, vjp = jax.vjp(f, x)
     (dx,) = vjp(dy)
     return dx
 
 
 @pytest.mark.parametrize(
-    "shape,k,s,p,tie_heavy",
+    "shape,kh,kw,p,tie_heavy",
     [
-        ((2, 16, 16, 8), 3, 1, 1, True),  # normal-cell 3x3 s1 pool
-        ((2, 16, 16, 8), 3, 1, 1, False),
-        ((1, 18, 18, 8), 3, 1, 0, True),  # pre-padded VALID form
-        ((2, 16, 16, 8), 3, 2, 1, True),  # reduction-cell 3x3 s2 pool
-        ((2, 16, 16, 8), 3, 2, 1, False),  # (even size: uncovered pad row)
-        ((1, 8, 32, 16), 3, 1, 1, True),  # rectangular
-        ((1, 32, 8, 128), 3, 2, 1, True),
+        ((2, 16, 16, 8), 3, 3, 1, True),  # normal-cell 3x3 s1 pool
+        ((2, 16, 16, 8), 3, 3, 1, False),
+        ((1, 18, 18, 8), 3, 3, 0, True),  # pre-padded VALID form
+        ((1, 8, 32, 16), 3, 3, 1, True),  # rectangular
+        ((1, 32, 8, 128), 3, 3, 1, False),
+        ((2, 16, 16, 8), 1, 3, 0, True),  # one window row: no tail blocks
+        ((1, 64, 16, 8), 5, 3, 2, True),  # several row chunks, 4-row tails
     ],
 )
-def test_bwd_matches_select_and_scatter(shape, k, s, p, tie_heavy):
+def test_bwd_matches_select_and_scatter(shape, kh, kw, p, tie_heavy):
     rng = np.random.default_rng(0)
     if tie_heavy:
         x = jnp.asarray(rng.integers(0, 3, size=shape), jnp.float32)
     else:
         x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-    ho = (shape[1] + 2 * p - k) // s + 1
-    wo = (shape[2] + 2 * p - k) // s + 1
+    ho = shape[1] + 2 * p - kh + 1
+    wo = shape[2] + 2 * p - kw + 1
     dy = jnp.asarray(
         rng.integers(-64, 64, size=(shape[0], ho, wo, shape[3])), jnp.float32
     )
-    # The dispatch gate declines strided shapes (the chip's compiler refuses
-    # them, see supported()); the kernel's math is held to XLA's either way.
-    assert pool_pallas.supported(shape, k, k, s, s, p, p, 4) == (s == 1)
-    got = _kernel_dx(x, dy, k, k, s, s, p, p)
-    want = _xla_dx(x, dy, k, k, s, s, p, p)
+    assert pool_pallas.supported(shape, kh, kw, p, p, 4)
+    got = _kernel_dx(x, dy, kh, kw, p, p)
+    want = _xla_dx(x, dy, kh, kw, p, p)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -76,20 +70,22 @@ def test_forward_matches_tree():
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((2, 18, 18, 8)), jnp.float32)
     y_tree = max_pool_s1_valid(x, 3, 3)  # CPU: tree path (pallas not usable)
-    y_pool = pool_pallas._fwd_val(x, 3, 3, 1, 1, 0, 0)
+    y_pool = pool_pallas._fwd_val(x, 3, 3, 0, 0)
     np.testing.assert_array_equal(np.asarray(y_tree), np.asarray(y_pool))
 
 
 def test_gates(monkeypatch):
-    # non-overlapping windows: XLA's backward is fine, kernel declines
-    assert not pool_pallas.supported((2, 16, 16, 8), 2, 2, 2, 2, 0, 0)
+    # a 1x1 window is the identity: nothing to schedule
+    assert not pool_pallas.supported((2, 16, 16, 8), 1, 1, 0, 0)
+    # more padded input than the chip's compiler takes (see supported())
+    assert not pool_pallas.supported((2, 1024, 1024, 64), 3, 3, 1, 1)
     # CPU backend: usable() is False even for supported shapes
     x = jnp.zeros((2, 16, 16, 8), jnp.float32)
     if jax.default_backend() != "tpu":
-        assert not pool_pallas.usable(x, 3, 3, 1, 1, 1, 1)
+        assert not pool_pallas.usable(x, 3, 3, 1, 1)
     # env off-switch
     monkeypatch.setenv("MPI4DL_TPU_POOL_PALLAS", "off")
-    assert not pool_pallas.usable(x, 3, 3, 1, 1, 1, 1)
+    assert not pool_pallas.usable(x, 3, 3, 1, 1)
     monkeypatch.setenv("MPI4DL_TPU_POOL_PALLAS", "bogus")
     with pytest.raises(ValueError):
         pool_pallas.pool_pallas_mode()
@@ -103,8 +99,8 @@ def test_disable_context():
     The context must gate dispatchable() regardless of backend."""
     x = jnp.zeros((2, 18, 18, 8), jnp.float32)
     with pool_pallas.disable():
-        assert not pool_pallas.dispatchable(x, 3, 3, 1, 1, 0, 0)
+        assert not pool_pallas.dispatchable(x, 3, 3, 0, 0)
         with pool_pallas.disable():  # re-entrant
-            assert not pool_pallas.dispatchable(x, 3, 3, 1, 1, 0, 0)
-        assert not pool_pallas.dispatchable(x, 3, 3, 1, 1, 0, 0)
+            assert not pool_pallas.dispatchable(x, 3, 3, 0, 0)
+        assert not pool_pallas.dispatchable(x, 3, 3, 0, 0)
     assert not pool_pallas._DISABLED[0]

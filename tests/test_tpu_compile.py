@@ -47,12 +47,16 @@ EMPTY = {
 
 @pytest.fixture(scope="module")
 def topo():
+    """Four described v5e chips. Skipped only where no TPU compiler is
+    installed; where one is, failing to describe the chip fails the tests —
+    a guard that skipped on any error could vanish without anyone seeing."""
+    import importlib.util
+
     from jax.experimental import topologies
 
-    try:
-        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no TPU compiler here: nothing to hold
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("libtpu is not installed: no TPU compiler to hold the gates to")
+    return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 
 
 @pytest.fixture(scope="module")
@@ -112,8 +116,8 @@ def dispatched(topo):
         current.setdefault(kernel, set()).add(sig)
         return out
 
-    def pool(xp, dy, *, kh, kw, sh, sw, interpret=False):
-        sig = (xp.shape, dy.shape, xp.dtype.name, kh, kw, sh, sw)
+    def pool(xp, dy, *, kh, kw, interpret=False):
+        sig = (xp.shape, dy.shape, xp.dtype.name, kh, kw)
         return record("pool", sig, jnp.zeros(xp.shape, dy.dtype))
 
     def wgrad(xp, dy, kh, kw, interpret=False):
@@ -158,11 +162,11 @@ def _on(sharding, shape, dtype):
 
 
 def _compile_pool(one_chip, sig):
-    xp, dy, dtype, kh, kw, sh, sw = sig
+    xp, dy, dtype, kh, kw = sig
     assert pool_pallas._plan(
-        xp[-1], dy[1], dy[2], kh, kw, sh, sw, jnp.dtype(dtype).itemsize
+        xp[-1], dy[1], dy[2], kh, kw, jnp.dtype(dtype).itemsize
     ), sig
-    fn = functools.partial(pool_pallas._bwd_padded, kh=kh, kw=kw, sh=sh, sw=sw)
+    fn = functools.partial(pool_pallas._bwd_padded, kh=kh, kw=kw)
     jax.jit(fn).lower(_on(one_chip, xp, dtype), _on(one_chip, dy, dtype)).compile()
 
 
@@ -235,7 +239,7 @@ def test_default_on_kernels_are_the_ones_the_chip_smoke_expects(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setenv("MPI4DL_TPU_CONV_IMPL", "auto")
     x = jax.ShapeDtypeStruct((2, 256, 256, 208), jnp.bfloat16)
-    assert pool_pallas.dispatchable(x, 3, 3, 1, 1, 0, 0)
+    assert pool_pallas.dispatchable(x, 3, 3, 0, 0)
     assert not dot1x1_pallas.dispatchable(x, x)
     from mpi4dl_tpu.ops import fastconv
 
