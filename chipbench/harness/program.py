@@ -1,0 +1,71 @@
+"""The system under test, built the way its entry points build it.
+
+``benchmarks/common.py``'s ``build_config`` / the configuration's model
+builder / ``make_trainer`` with the reference's command-line flags, so that
+``train.default_remat`` picks the policy as it does for a user. The
+benchmark takes from the program the trainer, its input pipeline
+(``data.SyntheticImages``, the ``--app 3`` stream of ``run_training``) and
+nothing else: the weights are made by the benchmark from the seed
+(``reference/plain.make_params``) and handed over as the initial state.
+"""
+
+from __future__ import annotations
+
+import importlib
+
+
+def _resolve(dotted: str):
+    module, _, attr = dotted.partition(":")
+    return getattr(importlib.import_module(module), attr)
+
+
+def build_trainer(config: dict, batch_size: int):
+    """``(trainer, cfg)`` for the configuration at this batch size, as the
+    entry-point script named in the configuration file builds it."""
+    from benchmarks.common import build_config, make_trainer
+    from mpi4dl_tpu.parallel.pipeline import PipelineTrainer
+    from mpi4dl_tpu.parser import get_parser
+
+    entry = config["entry_point"]
+    argv = list(entry["argv"]) + ["--batch-size", str(batch_size)]
+    args = get_parser().parse_args(argv)
+    cfg = build_config(args, spatial=bool(entry["spatial"]))  # compile cache on
+    build_model = _resolve(entry["build_model"])
+    n_cells = len(build_model(args, cfg)[1])
+    n_spatial = (
+        PipelineTrainer.spatial_cell_count(n_cells, cfg) if cfg.spatial_size else 0
+    )
+    built = build_model(args, cfg, spatial_cells=n_spatial)
+    trainer, _ = make_trainer(
+        args, cfg, built[0], built[1],
+        n_spatial=built[2] if len(built) == 3 else None,
+    )
+    return trainer, cfg
+
+
+def initial_state(trainer, params):
+    """The trainer's state around the benchmark's weights, placed as
+    ``Trainer.init`` places it (replicated on the mesh)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from mpi4dl_tpu.train import TrainState
+
+    state = TrainState(
+        params=params,
+        opt_state=trainer.tx.init(params),
+        step=jnp.zeros((), jnp.int32),
+    )
+    return jax.device_put(state, NamedSharding(trainer.mesh, PartitionSpec()))
+
+
+def input_stream(cfg, batch_size: int, seed: int, prefetch: bool):
+    """The program's synthetic input pipeline (native fill, one-batch
+    prefetch thread), seeded by the run."""
+    from mpi4dl_tpu.data import SyntheticImages
+
+    return SyntheticImages(
+        batch_size, cfg.image_size, cfg.num_classes, seed=seed,
+        prefetch=prefetch,
+    )
