@@ -44,7 +44,7 @@ def _xla_dx(x, dy, kh, kw, ph, pw):
         ((1, 8, 32, 16), 3, 3, 1, True),  # rectangular
         ((1, 32, 8, 128), 3, 3, 1, False),
         ((2, 16, 16, 8), 1, 3, 0, True),  # one window row: no tail blocks
-        ((1, 64, 16, 8), 5, 3, 2, True),  # several row chunks, 4-row tails
+        ((1, 64, 16, 8), 5, 3, 2, True),  # 5x3 window: 4 overlap rows
     ],
 )
 def test_bwd_matches_select_and_scatter(shape, kh, kw, p, tie_heavy):
@@ -62,6 +62,91 @@ def test_bwd_matches_select_and_scatter(shape, kh, kw, p, tie_heavy):
     got = _kernel_dx(x, dy, kh, kw, p, p)
     want = _xla_dx(x, dy, kh, kw, p, p)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _brute_force_dx(x, dy, kh, kw):
+    """Every window's row-major first maximum (``np.argmax`` returns the
+    first), its cotangent scattered there: the definition, tap by tap."""
+    x, dy = np.asarray(x, np.float32), np.asarray(dy, np.float32)
+    b, hp, wp, c = x.shape
+    ho, wo = hp - kh + 1, wp - kw + 1
+    dx = np.zeros_like(x)
+    for a in range(ho):
+        for j in range(wo):
+            win = x[:, a : a + kh, j : j + kw, :].reshape(b, kh * kw, c)
+            first = np.argmax(win, axis=1)
+            for t in range(kh * kw):
+                dx[:, a + t // kw, j + t % kw, :] += np.where(
+                    first == t, dy[:, a, j, :], 0.0
+                )
+    return dx
+
+
+def _inputs(shape, kh, kw, fill, dtype, seed=0):
+    """A pre-padded input and an integer cotangent small enough that a
+    window's sum is exact in ``dtype``."""
+    rng = np.random.default_rng(seed)
+    if fill == "equal":
+        x = np.ones(shape)
+    elif fill == "two-valued":
+        x = rng.integers(0, 2, size=shape)
+    elif fill == "ties":
+        x = rng.integers(0, 3, size=shape)
+    else:
+        x = rng.standard_normal(shape)
+    dy_shape = (shape[0], shape[1] - kh + 1, shape[2] - kw + 1, shape[3])
+    dy = rng.integers(-8, 8, size=dy_shape)
+    return jnp.asarray(x, dtype), jnp.asarray(dy, dtype)
+
+
+@pytest.mark.parametrize("fill", ["ties", "normal"])
+@pytest.mark.parametrize(
+    "shape",
+    [(1, 18, 18, 416), (1, 18, 18, 832), (1, 10, 10, 1664), (1, 34, 34, 208)],
+)
+def test_bwd_at_the_models_channel_widths(shape, fill):
+    """The dispatched widths, bf16, pre-padded, the spatial size cut: 208,
+    416 and 832 end in a ragged 128-lane block, 1664 fills thirteen."""
+    x, dy = _inputs(shape, 3, 3, fill, jnp.bfloat16)
+    got = pool_pallas._bwd_padded(x, dy, kh=3, kw=3, interpret=True)
+    want = _xla_dx(x, dy, 3, 3, 0, 0)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32), np.asarray(want, np.float32)
+    )
+
+
+@pytest.mark.parametrize("kh,kw", [(3, 3), (5, 3)])
+def test_ties_across_every_row_chunk_and_row(monkeypatch, kh, kw):
+    """Several row chunks (the VMEM budget cut so that ``_plan`` takes four
+    window rows a grid step: the rows carried from one chunk to the next are
+    exercised eight times) of several rows each, on an input that is equal
+    down every column: each window's first maximum lies in its first row,
+    across every boundary between rows and between chunks."""
+    shape = (2, 32 + kh - 1, 18, 8)
+    monkeypatch.setattr(pool_pallas, "_VMEM_BUDGET", 900 * 1024)
+    assert pool_pallas._plan(8, 32, 16, kh, kw, 4)[0] == 4
+    rng = np.random.default_rng(3)
+    column = rng.integers(0, 3, size=(shape[0], 1, shape[2], shape[3]))
+    x = jnp.asarray(np.broadcast_to(column, shape), jnp.float32)
+    _, dy = _inputs(shape, kh, kw, "equal", jnp.float32)
+    got = pool_pallas._bwd_padded(x, dy, kh=kh, kw=kw, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), _brute_force_dx(x, dy, kh, kw))
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(_xla_dx(x, dy, kh, kw, 0, 0))
+    )
+    # all of it lands in rows a window starts in: none in the last kh-1
+    assert not np.asarray(got)[:, -(kh - 1) :].any()
+
+
+@pytest.mark.parametrize("fill", ["equal", "two-valued"])
+@pytest.mark.parametrize("kh,kw", [(3, 3), (2, 4)])
+def test_separable_winner_is_the_row_major_first_maximum(kh, kw, fill):
+    """First maximum over the column taps inside each row, then over the
+    rows, against the definition taken tap by tap in row-major order."""
+    x, dy = _inputs((2, 12, 11, 8), kh, kw, fill, jnp.float32, seed=5)
+    got = pool_pallas._bwd_padded(x, dy, kh=kh, kw=kw, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), _brute_force_dx(x, dy, kh, kw))
 
 
 def test_forward_matches_tree():

@@ -106,14 +106,15 @@ def _trainer(model, devices):
 
 @pytest.fixture(scope="module")
 def dispatched(topo):
-    """{model: {kernel: set of call signatures}} from one abstract trace of
+    """{model: {kernel: {call signature: calls}}} from one abstract trace of
     each model's train step. The gates ask ``jax.default_backend()``; here
     the test answers "tpu" for them, and records instead of lowering."""
     seen = {}
     current = {}
 
     def record(kernel, sig, out):
-        current.setdefault(kernel, set()).add(sig)
+        calls = current.setdefault(kernel, {})
+        calls[sig] = calls.get(sig, 0) + 1
         return out
 
     def pool(xp, dy, *, kh, kw, interpret=False):
@@ -211,6 +212,25 @@ def test_admitted_shapes_compile_for_v5e(topo, cache_off, dispatched, model, ker
         except Exception as e:
             e.add_note(f"{kernel} kernel, {model}, admitted signature {sig}")
             raise
+
+
+@pytest.mark.parametrize(
+    "model,padded_inputs",
+    [
+        ("amoebanet", [(130, 416), (66, 832), (34, 1664), (258, 208)]),
+        ("amoebanet_sp2x2", [(66, 416), (34, 832), (18, 1664), (130, 208)]),
+    ],
+)
+def test_the_step_dispatches_its_forty_pool_backwards(dispatched, model, padded_inputs):
+    """A step runs the stride-1 3x3 max-pool backward 40 times, at four
+    shapes: 13 each in the three groups of normal cells and one in the stem.
+    A gate that turns one of them down sends it to the tree path in silence;
+    here it is seen as a missing signature, not later as a missing gain."""
+    want = {
+        ((2, s, s, c), (2, s - 2, s - 2, c), "bfloat16", 3, 3): calls
+        for (s, c), calls in zip(padded_inputs, (13, 13, 13, 1))
+    }
+    assert dispatched[model]["pool"] == want
 
 
 def test_halo_swap_compiles_for_v5e_2x2(topo, cache_off, dispatched):
