@@ -20,7 +20,9 @@ readings they were set from are in ``chipbench/cells/<cell>.json``):
    reference's input of that cell and a seeded cotangent; output, input
    cotangent and parameter cotangents are compared with the reference's by
    relative L2 error. Nothing has been amplified yet at a cell's input, so
-   this is the number a lower precision fails.
+   this is the number a lower precision fails. A cell fed integers (token
+   ids) has no input cotangent: its VJPs return ``(y, dv)`` and its
+   ``cell_dx_err`` is not taken.
 """
 
 from __future__ import annotations
@@ -105,11 +107,12 @@ def seeded_cotangent(y, seed: int, index: int):
 
 
 def reference_cell_vjp(fn, mode, variables, x, ct):
-    """``(y, dv, dx)`` of one reference cell in ``mode``'s arithmetic."""
-    from chipbench.reference.plain import Scope
+    """``(y, dv, dx)`` of one reference cell in ``mode``'s arithmetic;
+    ``(y, dv)`` where the input is integer."""
+    from chipbench.reference.plain import Scope, vjp
 
     def run(v, x, ct):
-        y, pull = jax.vjp(lambda v_, x_: fn(Scope(v_["params"], mode), x_), v, x)
+        y, pull = vjp(lambda v_, x_: fn(Scope(v_["params"], mode), x_), v, x)
         return (y,) + tuple(pull(ct))
 
     return jax.jit(run)(variables, x, ct)
@@ -117,9 +120,12 @@ def reference_cell_vjp(fn, mode, variables, x, ct):
 
 def program_cell_vjp(trainer, index, variables, x, ct):
     """``(y, dv, dx)`` of the program's own cell ``index`` at the input and
-    cotangent given, in the program's dtype; a spatial cell runs on tiles
-    under ``shard_map`` over the trainer's mesh, as it does in the step."""
+    cotangent given, floating inputs in the program's dtype (``(y, dv)``
+    where the input is integer); a spatial cell runs on tiles under
+    ``shard_map`` over the trainer's mesh, as it does in the step."""
     from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from chipbench.reference.plain import cast_floating, vjp
 
     cell = trainer.cells[index]
     dtype = jnp.dtype(getattr(cell, "dtype", None) or jnp.float32)
@@ -142,8 +148,7 @@ def program_cell_vjp(trainer, index, variables, x, ct):
         x, ct = put(x, x_specs), put(ct, y_specs)
 
     def run(v, x, ct):
-        x = jax.tree.map(lambda a: a.astype(dtype), x)
-        y, pull = jax.vjp(apply, v, x)
+        y, pull = vjp(apply, v, cast_floating(x, dtype))
         ct = jax.tree.map(lambda c, o: c.astype(o.dtype), ct, y)
         return (y,) + tuple(pull(ct))
 
@@ -151,22 +156,27 @@ def program_cell_vjp(trainer, index, variables, x, ct):
 
 
 def verdict(numbers: dict, limits: dict):
-    """Print every number beside its limit; ``correct`` is true when every
-    number that has a limit is finite and within it. Numbers without a
-    limit are shown for the record."""
+    """``(correct, compared)``: every number is printed beside its limit and
+    kept, ``{name: {"value", "limit"}}``, for the end of the result line;
+    ``correct`` is true when every number that has a limit is finite and
+    within it. Numbers without a limit are shown for the record."""
     import json
 
-    correct = True
+    correct, compared = True, {}
     for name, value in numbers.items():
         limit = limits.get(name)
         ok = limit is None or (math.isfinite(value) and value <= limit)
         correct = correct and ok
+        # strict JSON has no NaN: a number that is not finite goes as text
+        shown = value if math.isfinite(value) else repr(value)
+        compared[name] = {"value": shown, "limit": limit}
         print(json.dumps({
-            "check": name, "value": value, "limit": limit,
+            "check": name, "value": shown, "limit": limit,
             "ok": bool(ok) if limit is not None else None,
         }), flush=True)
     missing = sorted(set(limits) - set(numbers))
     if missing:
         print(json.dumps({"check": "missing", "names": missing}), flush=True)
+        compared.update({name: {"value": None, "limit": limits[name]} for name in missing})
         correct = False
-    return correct
+    return correct, compared

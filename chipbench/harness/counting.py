@@ -18,8 +18,8 @@ import jax.numpy as jnp
 from chipbench.reference.plain import Scope, record_specs
 
 
-def _forward_jaxpr(cells, x_shape):
-    specs = record_specs(cells, x_shape)
+def _forward_jaxpr(cells, x_shape, x_dtype):
+    specs = record_specs(cells, x_shape, x_dtype)
 
     def shapes(spec):
         tree: dict = {}
@@ -38,7 +38,7 @@ def _forward_jaxpr(cells, x_shape):
         return x
 
     return jax.make_jaxpr(run)(
-        params, jax.ShapeDtypeStruct(tuple(x_shape), jnp.float32)
+        params, jax.ShapeDtypeStruct(tuple(x_shape), x_dtype)
     ).jaxpr
 
 
@@ -80,20 +80,25 @@ def _eqn_flops(eqn) -> float:
     return 0.0
 
 
-def train_flops_per_image(cells, image_shape) -> float:
-    """3 x the forward pass's conv and matmul FLOPs for one image."""
-    jaxpr = _forward_jaxpr(cells, (1,) + tuple(image_shape))
+def train_flops_per_sample(cells, sample_shape, x_dtype) -> float:
+    """3 x the forward pass's conv and matmul FLOPs for one sample (an
+    image; a sequence). Right for a model whose every parameter multiplies
+    every sample. A reference that computes more than the model asks for (a
+    plain expert layer multiplies every expert by every token) defines its
+    own ``train_flops_per_sample(model, traffic)``, which the harness takes
+    in this one's place."""
+    jaxpr = _forward_jaxpr(cells, (1,) + tuple(sample_shape), x_dtype)
     return 3.0 * sum(_eqn_flops(e) for e in _walk(jaxpr))
 
 
-def stride1_max_pool_bytes(cells, x_shape, itemsize: int) -> float:
+def stride1_max_pool_bytes(cells, x_shape, x_dtype, itemsize: int) -> float:
     """The least bytes the backward passes of the model's stride-1 3x3 max
     pools must move in one step on a batch of ``x_shape``: read the pool's
     input and the output's cotangent, write the input's cotangent, each
     once, at ``itemsize`` bytes an element (the window shapes are found on
     the reference's forward pass)."""
     total = 0.0
-    for eqn in _walk(_forward_jaxpr(cells, x_shape)):
+    for eqn in _walk(_forward_jaxpr(cells, x_shape, x_dtype)):
         if eqn.primitive.name != "reduce_window_max":
             continue
         if tuple(eqn.params["window_dimensions"]) != (1, 3, 3, 1):

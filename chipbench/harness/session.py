@@ -10,11 +10,12 @@ freed and returns every compared number.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib
 import json
 import time
 
-from . import check, program, spec
+from . import check, counting, defaults, program, spec
 from .loop import StepLoop
 
 
@@ -36,6 +37,12 @@ class FirstSteps:
 
 
 class Session:
+    """One cell's program and reference. The reference module is the
+    family's face: ``cells(model)`` and ``kinds(model)`` it must define;
+    ``input_spec(model, traffic)``, ``loss(logits, labels)`` and
+    ``train_flops_per_sample(model, traffic)`` it may (``defaults.py`` and
+    ``counting.py`` hold what stands in for each)."""
+
     def __init__(self, cell: spec.Cell):
         from chipbench.reference import plain
 
@@ -44,21 +51,31 @@ class Session:
         self.check_steps = int(cell.traffic["check_steps"])
         t0 = time.perf_counter()
         self.trainer, self.cfg = program.build_trainer(cell.config, self.batch)
-        reference = importlib.import_module(cell.config["reference"]["module"])
-        model = cell.config["model"]
-        self.ref_cells = reference.cells(model)
-        self.kinds = reference.kinds(model)
-        self.x_shape = (
-            self.batch, model["image_size"], model["image_size"],
-            model["image_channels"],
-        )
-        self.specs = plain.record_specs(self.ref_cells, self.x_shape)
+        self.reference = importlib.import_module(cell.config["reference"]["module"])
+        self.ref_cells = self.reference.cells(cell.model)
+        self.kinds = self.reference.kinds(cell.model)
+        input_spec = getattr(self.reference, "input_spec", defaults.input_spec)
+        sample_shape, self.x_dtype = input_spec(cell.model, cell.traffic)
+        self.x_shape = (self.batch,) + tuple(sample_shape)
+        self.loss = getattr(self.reference, "loss", defaults.loss)
+        self.specs = plain.record_specs(self.ref_cells, self.x_shape, self.x_dtype)
         self.make_params = plain.params_maker(self.specs)
         say(phase="build", seconds=time.perf_counter() - t0,
             remat=self.trainer.remat, mesh=dict(self.trainer.mesh.shape),
             cells=len(self.ref_cells), spatial_cells=self.trainer.n_spatial,
             parameters=sum(
                 _size(shape) for s in self.specs for shape, _ in s.values()))
+
+    @functools.cached_property
+    def flops_per_sample(self) -> float:
+        """Training FLOPs of one sample: the family's own count, else 3 x
+        the reference's forward conv and matmul FLOPs (read after the
+        window: the count traces the whole reference)."""
+        own = getattr(self.reference, "train_flops_per_sample", None)
+        if own is not None:
+            return float(own(self.cell.model, self.cell.traffic))
+        return counting.train_flops_per_sample(
+            self.ref_cells, self.x_shape[1:], self.x_dtype)
 
     def first_steps(self, seed: int, steps: int, wrap_step=None) -> FirstSteps:
         """Weights and state from ``seed``; the first ``steps`` steps (at
@@ -72,8 +89,7 @@ class Session:
         state = program.initial_state(self.trainer, params)
         del params
         stream = program.input_stream(
-            self.cfg, self.batch, seed, bool(self.cell.traffic["prefetch"])
-        )
+            self.cell.config, self.cfg, self.cell.traffic, seed)
         first = FirstSteps(seed, None, [], [], None, None)
 
         def keep(index, state, host_batch):
@@ -106,7 +122,9 @@ class Session:
     def batches_only(self, seed: int) -> FirstSteps:
         """The compared steps' batches without the program: what a reading
         of the control alone needs."""
-        stream = iter(program.input_stream(self.cfg, self.batch, seed, False))
+        stream = iter(program.input_stream(
+            self.cell.config, self.cfg, dict(self.cell.traffic, prefetch=False),
+            seed))
         batches = [next(stream) for _ in range(self.check_steps)]
         return FirstSteps(seed, None, batches, None, None, None)
 
@@ -125,7 +143,8 @@ class Session:
 
         taps = check.sample_taps(self.kinds, first.seed)
         # in the order the VJPs return them: output, the parameters'
-        # cotangents, the input's cotangent
+        # cotangents, the input's cotangent (none from a cell fed integers:
+        # ``zip`` then ends before ``cell_dx_err``)
         keys = ("cell_y_err", "cell_dv_err", "cell_dx_err")
         errors = {k: {} for k in keys}
         control_errors = {k: {} for k in keys}
@@ -187,7 +206,7 @@ class Session:
         opt = self.cell.config["optimizer"]
         follower = Follower(
             self.ref_cells, self.make_params(first.seed),
-            opt["learning_rate"], opt["momentum"], mode=mode,
+            opt["learning_rate"], opt["momentum"], self.loss, mode=mode,
         )
         losses, grad_norms, grad_scale = [], None, 0.0
         for k, (x, y) in enumerate(first.batches):

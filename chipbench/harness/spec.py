@@ -43,6 +43,10 @@ class Cell:
         self.config = _read_json(
             os.path.join(ROOT, configs[self.entry["config"]]["file"])
         )
+        # the sizes the reference and the program's builder read: a group
+        # of their own, or the file itself where it holds a published
+        # config's keys at its top level
+        self.model = self.config.get("model", self.config)
         self.traffic = _read_json(
             os.path.join(bench_dir, "traffic", self.entry["traffic"] + ".json")
         )

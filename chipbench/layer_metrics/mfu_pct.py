@@ -1,12 +1,10 @@
-"""Model FLOP/s utilisation: the reference model's conv and matmul FLOPs
-(3 x forward per image, recomputation and packing not counted) x this
-run's images/s / (the cell's chips x the chip's peak bf16 FLOP/s)."""
-
-from chipbench.harness import counting
+"""Model FLOP/s utilisation: the model's training FLOPs per sample
+(``Session.flops_per_sample``: the configuration's own count where its
+reference module gives one, else 3 x the reference's forward conv and
+matmul FLOPs; recomputation and packing not counted) x this run's samples/s
+/ (the cell's chips x the chip's peak bf16 FLOP/s)."""
 
 
 def read(context):
-    session = context["session"]
-    per_image = counting.train_flops_per_image(session.ref_cells, session.x_shape[1:])
     peak = context["peaks"]["bf16_flops_per_s"] * context["cell"].chips
-    return 100.0 * per_image * context["window_rate"] / peak
+    return 100.0 * context["session"].flops_per_sample * context["window_rate"] / peak

@@ -17,5 +17,6 @@ def read(context):
         return None
     session, trainer = context["session"], context["trainer"]
     tiles = trainer.mesh.devices.size if trainer.n_spatial else 1
-    least = counting.stride1_max_pool_bytes(session.ref_cells, session.x_shape, 2)
+    least = counting.stride1_max_pool_bytes(
+        session.ref_cells, session.x_shape, session.x_dtype, 2)
     return 100.0 * (least / tiles / context["peaks"]["hbm_bytes_per_s"]) / seconds

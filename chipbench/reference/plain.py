@@ -178,9 +178,10 @@ def avg_pool(x, kernel, strides, padding=0):
 # -- parameters from a seed --------------------------------------------------
 
 
-def record_specs(cells, x_shape):
+def record_specs(cells, x_shape, x_dtype=jnp.float32):
     """Per cell, ``{path: (shape, init)}`` of its parameters, found by
-    running the model abstractly on an input of ``x_shape``."""
+    running the model abstractly on an input of ``x_shape`` and ``x_dtype``
+    (float32 images; a token model's ids are integers)."""
     specs = []
 
     def run(x):
@@ -190,8 +191,33 @@ def record_specs(cells, x_shape):
             specs.append(spec)
         return x
 
-    jax.eval_shape(run, jax.ShapeDtypeStruct(tuple(x_shape), jnp.float32))
+    jax.eval_shape(run, jax.ShapeDtypeStruct(tuple(x_shape), x_dtype))
     return specs
+
+
+def takes_cotangent(x) -> bool:
+    """Whether a cell's input (arrays or shapes) has a cotangent: one of
+    floating leaves has, one of integer leaves (token ids) has none."""
+    floating = [jnp.issubdtype(a.dtype, jnp.floating) for a in jax.tree.leaves(x)]
+    if any(floating) != all(floating):
+        raise TypeError("a cell's input mixes floating and integer leaves")
+    return all(floating)
+
+
+def vjp(apply, v, x):
+    """``jax.vjp`` of ``apply(v, x)`` over both, or over ``v`` alone where
+    ``x`` has no cotangent: the pull-back then returns ``(dv,)``."""
+    if takes_cotangent(x):
+        return jax.vjp(apply, v, x)
+    return jax.vjp(lambda v_: apply(v_, x), v)
+
+
+def cast_floating(x, dtype):
+    """``x`` with its floating leaves in ``dtype``; integer leaves (token
+    ids) as they are."""
+    return jax.tree.map(
+        lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a,
+        x)
 
 
 def seed_key(seed: int):
@@ -205,7 +231,9 @@ def params_maker(specs):
     """``seed -> parameters``: the whole tree in one jitted call, float32,
     on the default device: conv and dense kernels normal with standard
     deviation ``1/sqrt(fan_in)`` (the LeCun rule the program's layers use,
-    without its truncation), BatchNorm scales one, every bias zero. One
+    without its truncation), a parameter whose ``init`` is a float normal
+    with that standard deviation (an embedding table), scales one
+    (``"ones"``), every bias zero. One
     ``{"params": {...}}`` tree per cell. (One maker per run: a second call
     finds its program already traced.)"""
 
@@ -214,12 +242,17 @@ def params_maker(specs):
         for i, spec in enumerate(specs):
             tree: dict = {}
             for j, (path, (shape, init)) in enumerate(sorted(spec.items())):
+                def normal(std):
+                    k = jax.random.fold_in(jax.random.fold_in(key, i), j)
+                    return jax.random.normal(k, shape, jnp.float32) * std
+
                 if init == "fan_in":
                     fan_in = 1
                     for d in shape[:-1]:
                         fan_in *= d
-                    k = jax.random.fold_in(jax.random.fold_in(key, i), j)
-                    leaf = jax.random.normal(k, shape, jnp.float32) * fan_in**-0.5
+                    leaf = normal(fan_in**-0.5)
+                elif isinstance(init, float):
+                    leaf = normal(init)
                 elif init == "ones":
                     leaf = jnp.ones(shape, jnp.float32)
                 else:

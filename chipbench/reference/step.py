@@ -1,12 +1,15 @@
 """The reference's training step, followed layer by layer.
 
-Loss: mean softmax cross-entropy of the logits over the batch. Optimiser:
+Loss: the family's (``loss(logits, labels)``, handed in by the harness; mean
+softmax cross-entropy where the reference module defines none). Optimiser:
 SGD with momentum, ``trace = g + momentum * trace; p -= lr * trace`` (the
 configuration file states ``lr`` and ``momentum``). The forward keeps each
 cell's input and the backward takes one cell's VJP at a time, recomputing
 that cell's forward, so that a float32 step of a model whose bf16 step
 fills the chip still fits: at any moment one cell's residuals are alive.
-Cell inputs beyond ``device_budget`` bytes wait on the host.
+Cell inputs beyond ``device_budget`` bytes wait on the host. An integer
+input (token ids) has no cotangent: the first cell's VJP is then taken
+over its parameters alone and the backward pass ends there.
 """
 
 from __future__ import annotations
@@ -20,23 +23,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import plain
 from .plain import Scope, jitted
 
 
 def _cell_vjp(fn, mode):
+    """``(v, x, ct) -> (dv, dx)``; ``(dv,)`` where the input has no
+    cotangent."""
     def vjp(v, x, ct):
-        _, pull = jax.vjp(lambda v_, x_: fn(Scope(v_["params"], mode), x_), v, x)
+        _, pull = plain.vjp(lambda v_, x_: fn(Scope(v_["params"], mode), x_), v, x)
         return pull(ct)
 
     return jax.jit(vjp)
 
 
-def _head_loss_grad(fn, mode):
+def _head_loss_grad(fn, mode, loss_fn):
     def loss(v, x, labels):
-        logits = fn(Scope(v["params"], mode), x)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        picked = jnp.take_along_axis(logp, labels[:, None], axis=1)
-        return -jnp.mean(picked)
+        return loss_fn(fn(Scope(v["params"], mode), x), labels)
 
     return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))
 
@@ -79,9 +82,9 @@ class Follower:
     arithmetic. Cells of equal settings and input shapes share one compiled
     forward and one compiled VJP; all are compiled before the first step."""
 
-    def __init__(self, cells, params, lr, momentum, mode="f32",
+    def __init__(self, cells, params, lr, momentum, loss, mode="f32",
                  device_budget=2 << 30):
-        self.cells, self.mode = list(cells), mode
+        self.cells, self.mode, self.loss = list(cells), mode, loss
         self.params = params
         self.trace = jax.tree.map(jnp.zeros_like, params)
         self.lr, self.momentum = float(lr), float(momentum)
@@ -102,8 +105,12 @@ class Follower:
             y = jax.eval_shape(forward, v, h)
             if i == len(self.cells) - 1:
                 jobs.setdefault(("head", key), (
-                    _head_loss_grad(fn, self.mode), (v, h, _shapes(labels))))
+                    _head_loss_grad(fn, self.mode, self.loss),
+                    (v, h, _shapes(labels))))
             elif ("forward", key) not in jobs:
+                if i and not plain.takes_cotangent(h):
+                    raise TypeError(
+                        f"cell {i} takes an integer input; only the first may")
                 jobs[("forward", key)] = (forward, (v, h))
                 jobs[("vjp", key)] = (_cell_vjp(fn, self.mode), (v, h, y))
             h = y
@@ -136,7 +143,7 @@ class Follower:
         index in ``taps``, ``on_tap(self, index, cell_input)`` is called
         before the parameters move (the cell-by-cell comparison feeds that
         input to the program's cell)."""
-        x = jnp.asarray(x, jnp.float32)
+        x = plain.cast_floating(jnp.asarray(x), jnp.float32)
         labels = jnp.asarray(labels, jnp.int32)
         if self.programs is None:
             self.prepare(x, labels)
@@ -151,10 +158,11 @@ class Follower:
         for i in range(n - 2, -1, -1):
             xi = jax.tree.map(jnp.asarray, inputs[i])
             inputs[i] = None
-            grads[i], dx = self.vjp_cell(i, xi, ct)
+            # (dv, dx); (dv,) from a first cell fed integers
+            grads[i], *dx = self.vjp_cell(i, xi, ct)
             if i in taps:
                 on_tap(self, i, xi)
-            ct = dx
+            ct = dx[0] if dx else None
         self.params, self.trace = _sgd(
             self.params, self.trace, grads, self.lr, self.momentum
         )

@@ -19,6 +19,7 @@ import time
 T_START = time.perf_counter()  # set-up is counted from here
 
 import argparse  # noqa: E402
+import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -98,6 +99,14 @@ def run(opts, devices, wrap_step=None, cell=None, peaks=None):
         opts.seed, int(cell.traffic["warmup_steps"]), wrap_step
     )
     loop = first.loop
+    # Tracing the step leaves over a million tracked objects behind, and one
+    # pass of Python's oldest generation over them stalls the host for
+    # 0.4-0.5 s. When the next pass is due depends on how many objects
+    # set-up happened to allocate, so it fell at step 98 of one tree's
+    # window and outside another's: 0.5% of images/s on one chip, 1.3% on
+    # four (PERF.md section 6, PR 30). The window starts from a collected
+    # heap, and the pass counts as set-up.
+    gc.collect()
     setup_s = time.perf_counter() - T_START
     say(phase="setup", setup_s=setup_s,
         compile_cache_dir=jax.config.jax_compilation_cache_dir,
@@ -156,7 +165,7 @@ def run(opts, devices, wrap_step=None, cell=None, peaks=None):
     # -- correctness: the reference follows the first steps -----------------
     t_ref = time.perf_counter()
     numbers = session.compare(first)
-    correct = check.verdict(numbers, cell.limits["limits"])
+    correct, compared = check.verdict(numbers, cell.limits["limits"])
     if failed or loop.failed:
         correct = False
     say(phase="check", reference_s=time.perf_counter() - t_ref, correct=correct)
@@ -199,6 +208,8 @@ def run(opts, devices, wrap_step=None, cell=None, peaks=None):
         result["breakdown"] = {
             "device_ops": reduced.device_ops, "idle_gaps": reduced.idle_gaps,
         }
+    # last on the line: what was compared, each number beside its limit
+    result["compared"] = compared
     return result
 
 
@@ -214,6 +225,9 @@ def main(argv=None):
 
     devices = find_chips(spec.Cell(opts.workload).chips)
     result = run(opts, devices)
+    for name, row in result["compared"].items():
+        print(f"compared {name} {row['value']} limit {row['limit']}",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
 
 

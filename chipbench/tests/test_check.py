@@ -1,6 +1,7 @@
 """The comparison that decides ``correct``, at sizes a test run can hold:
 the bf16 program passes the cell-by-cell check, the fp8 control fails it
-(for every configuration: on four virtual devices for the spatial one), and
+(for every configuration that has a tiny file under ``tests/tiny/``: on four
+virtual devices for the spatial one), and
 a run whose timed path is broken underneath comes out not correct."""
 
 import jax
@@ -15,9 +16,7 @@ from chipbench.tests import tiny
 CELL_Y_LIMIT = 0.02
 
 
-@pytest.mark.parametrize(
-    "config", ["amoebanetd_1024", "resnet110_1024", "amoebanetd_1024_sp2x2"]
-)
+@pytest.mark.parametrize("config", tiny.configs())
 def test_program_passes_and_fp8_control_fails(tmp_path, config):
     cell = tiny.tiny_cell(tmp_path, config)
     assert len(jax.devices()) >= cell.chips
@@ -117,3 +116,50 @@ def test_a_run_with_the_timed_path_broken_is_not_correct(tmp_path, broken):
     assert result["attempted"] > 0 and result["failed"] == 0
     assert set(result["metrics"]) == {"images_per_s", "step_ms_p90", "setup_s"}
     assert all(m["value"] > 0 for m in result["metrics"].values())
+    # what was compared closes the line, each number beside its limit
+    assert list(result)[-1] == "compared"
+    assert {k: v["limit"] for k, v in result["compared"].items() if v["limit"]} == LIMITS
+    over = [k for k, v in result["compared"].items()
+            if v["limit"] and not v["value"] <= v["limit"]]
+    assert bool(over) is (broken is not None)
+
+
+def test_the_window_starts_from_a_collected_heap(tmp_path, monkeypatch, capsys):
+    """One full collection ends set-up: after the warm-up steps, before
+    ``setup_s`` is read and the window opens, so that no tree's window holds
+    the pass over the step's trace that another tree's lacks."""
+    import gc
+    import json
+    import types
+
+    def collect():
+        print(json.dumps({"phase": "collect", "objects": gc.collect()}))
+
+    monkeypatch.setattr(run, "gc", types.SimpleNamespace(collect=collect))
+    cell = tiny.tiny_cell(tmp_path, "resnet110_1024", limits=LIMITS)
+    run.run(tiny.options(cell.name, seed=97), jax.devices(), cell=cell, peaks=tiny.PEAKS)
+    phases = [json.loads(line).get("phase") for line in capsys.readouterr().out.splitlines()
+              if line.startswith("{")]
+    assert phases.count("collect") == 1
+    assert phases.index("build") < phases.index("collect") < phases.index("setup") \
+        < phases.index("window")
+
+
+def test_a_run_with_the_exchange_between_chips_left_out_is_not_correct(
+        tmp_path, monkeypatch):
+    """The spatial cell on four virtual devices with every halo strip
+    arriving as zeros, as if no neighbour had sent: the tapped spatial
+    cells' outputs are wrong at every tile edge. (The sound program under
+    the same limit: ``test_program_passes_and_fp8_control_fails``.)"""
+    import jax.numpy as jnp
+
+    from mpi4dl_tpu.parallel import halo
+
+    monkeypatch.setattr(
+        halo, "_shift", lambda x, axis_name, direction: jnp.zeros_like(x))
+    cell = tiny.tiny_cell(tmp_path, "amoebanetd_1024_sp2x2", limits=LIMITS)
+    result = run.run(
+        tiny.options(cell.name, seed=97), jax.devices(), cell=cell, peaks=tiny.PEAKS,
+    )
+    assert result["correct"] is False and result["failed"] == 0
+    assert result["compared"]["cell_y_err"]["value"] > 5 * CELL_Y_LIMIT
