@@ -57,6 +57,7 @@ def build_config(args, spatial: bool, num_cells: int | None = None):
         slice_method=args.slice_method,
         times=args.times,
         image_size=args.image_size,
+        sequence_length=getattr(args, "sequence_length", 0),
         num_classes=args.num_classes,
         balance=parse_csv_ints(args.balance),
         halo_d2=args.halo_d2,
@@ -124,6 +125,73 @@ def build_amoebanet(args, cfg, spatial_cells=0):
     )
 
 
+def lfm2_args(argv, model: "dict | None" = None):
+    """The parsed flags of an LFM2 run: the shared CLI plus ``--model-config``
+    (a JSON file of the model's ``config.json`` keys, read into ``args.model``
+    unless ``model`` hands them over) and ``--sequence-length``. There is no
+    image (``--image-size`` 0) and ``--num-classes`` is the vocabulary the
+    model holds."""
+    import json
+
+    from mpi4dl_tpu.parser import get_parser
+
+    parser = get_parser()
+    parser.add_argument(
+        "--model-config",
+        help="JSON file of the model's published config.json keys; a chip's "
+        "share of a deployment counts what it holds and states the "
+        "published values under `cut` (mpi4dl_tpu/models/lfm2.py)")
+    parser.add_argument(
+        "--sequence-length", type=int, default=8192,
+        help="Tokens in a sequence (one document a sequence)")
+    parser.set_defaults(image_size=0, split_size=1, batch_size=1)
+    args = parser.parse_args(argv)
+    if model is None:
+        if args.model_config is None:
+            parser.error("--model-config is required")
+        with open(args.model_config) as f:
+            model = json.load(f)
+    args.model = model
+    args.num_classes = int(model["vocab_size"])
+    return args
+
+
+def build_lfm2(args, cfg, spatial_cells=0):
+    """(cells, float32 twin) of the LFM2 model ``args.model`` describes."""
+    import jax.numpy as jnp
+
+    from mpi4dl_tpu.models.lfm2 import LFM2Config, lfm2
+
+    if spatial_cells:
+        raise ValueError("a token model has no spatial stages")
+    config = LFM2Config.from_dict(args.model)
+    dtype = jnp.bfloat16 if args.precision == "bf16" else jnp.float32
+    return lfm2(config, dtype), lfm2(config, jnp.float32)
+
+
+def lfm2_trainer(config: dict, batch_size: int):
+    """``(trainer, cfg)`` of a benchmark configuration of LFM2 (its file as a
+    dict: the model's keys and ``entry_point.argv``), built as
+    ``benchmarks/layer_parallelism/benchmark_lfm2_lp.py`` builds it: the
+    builder such a configuration names (``entry_point.build_trainer``)."""
+    argv = list(config["entry_point"]["argv"]) + ["--batch-size", str(batch_size)]
+    args = lfm2_args(argv, model=config)
+    cfg = build_config(args, spatial=False)
+    cells, plain = build_lfm2(args, cfg)
+    trainer, _ = make_trainer(args, cfg, cells, plain)
+    return trainer, cfg
+
+
+def lfm2_input_stream(cfg, traffic: dict, seed: int):
+    """The program's token pipeline under a benchmark's traffic mix, seeded
+    by the run (``entry_point.input_stream``)."""
+    from mpi4dl_tpu.data import SyntheticTokens
+
+    return SyntheticTokens(
+        int(traffic["batch_size"]), int(traffic["sequence_length"]),
+        cfg.num_classes, seed=seed, prefetch=bool(traffic["prefetch"]))
+
+
 def make_trainer(args, cfg, cells, plain_cells, gems: bool = False, n_spatial=None):
     """Build the trainer the config asks for. The single-program
     ``Trainer`` runs under the fixed remat rule (``train.default_remat``);
@@ -172,7 +240,9 @@ def make_trainer(args, cfg, cells, plain_cells, gems: bool = False, n_spatial=No
         )
     if cfg.split_size == 1 or cfg.spatial_size == cfg.split_size:
         remat = default_remat(cfg.image_size)
-        print(f"remat policy: {remat} (@{cfg.image_size}px)")
+        size = (f"{cfg.sequence_length} tokens" if cfg.sequence_length
+                else f"{cfg.image_size}px")
+        print(f"remat policy: {remat} (@{size})")
         return (
             Trainer(
                 cells,
@@ -226,10 +296,7 @@ def run_training(args, trainer, tag: str):
     if hasattr(trainer, "init_params") or not hasattr(trainer, "n_spatial"):
         state = trainer.init(jax.random.PRNGKey(0))
     else:
-        state = trainer.init(
-            jax.random.PRNGKey(0),
-            (global_batch, cfg.image_size, cfg.image_size, 3),
-        )
+        state = trainer.init(jax.random.PRNGKey(0), *cfg.input_spec(global_batch))
     ckpt_dir = getattr(args, "checkpoint_dir", None)
     if ckpt_dir and getattr(args, "resume", False):
         try:
@@ -253,6 +320,7 @@ def run_training(args, trainer, tag: str):
     # the full step budget on top of the checkpointed weights (which would
     # train up to (max_restarts+1)x the requested duration under repeated
     # crashes).
+    unit = "seq/s" if cfg.sequence_length else "img/s"
     done = int(state.step)
     seen = 0  # global (epoch, step) slots consumed, trained or skipped
     trained = 0
@@ -290,7 +358,7 @@ def run_training(args, trainer, tag: str):
                     print(
                         f"epoch {epoch} step {step}: loss {loss:.4f} "
                         f"acc {float(metrics['accuracy']):.4f} "
-                        f"({global_batch / dt:.3f} img/s)"
+                        f"({global_batch / dt:.3f} {unit})"
                     )
                 if ckpt_dir and int(state.step) % args.checkpoint_every == 0:
                     ckpt.save_checkpoint(ckpt_dir, state)
@@ -301,18 +369,19 @@ def run_training(args, trainer, tag: str):
     if perf:
         mean_ips = statistics.mean(perf)
         line = (
-            f"{tag}: Mean {mean_ips:.3f} img/s "
-            f"Median {statistics.median(perf):.3f} img/s"
+            f"{tag}: Mean {mean_ips:.3f} {unit} "
+            f"Median {statistics.median(perf):.3f} {unit}"
         )
         # MFU against the model's analytic FLOPs (BASELINE.json north star
         # is stated in MFU; the reference never reports it). Counted on the
         # plain twin — same math, no spatial collectives to trace.
         from mpi4dl_tpu.flops import mfu, train_flops_per_image
 
-        fpi = train_flops_per_image(trainer.plain_cells, cfg.image_size)
-        util = mfu(mean_ips, fpi, n_devices=jax.device_count())
-        if util is not None:  # None on CPU only
-            line += f" MFU {100 * util:.1f}%"
+        if cfg.image_size:  # flops.py counts convs and dots off an image's jaxpr
+            fpi = train_flops_per_image(trainer.plain_cells, cfg.image_size)
+            util = mfu(mean_ips, fpi, n_devices=jax.device_count())
+            if util is not None:  # None on CPU only
+                line += f" MFU {100 * util:.1f}%"
         print(line)
     if getattr(args, "eval_batches", 0):
         # skip: the (epoch, step) slots training consumed, reduced modulo

@@ -89,6 +89,10 @@ class ParallelConfig:
     fused_layers: int = 1
     data_parallel: int = 1
     precision: str = "bf16"
+    # > 0: a token-sequence model. A sample is ``sequence_length`` token ids
+    # with a label at every position, ``num_classes`` is the vocabulary held
+    # and there is no image (``image_size`` 0).
+    sequence_length: int = 0
 
     def __post_init__(self):
         if isinstance(self.num_spatial_parts, int):
@@ -103,6 +107,11 @@ class ParallelConfig:
     def validate(self) -> None:
         if self.parts < 1 or self.split_size < 1:
             raise ValueError("parts and split_size must be >= 1")
+        if self.sequence_length and (self.image_size or self.spatial_size):
+            raise ValueError(
+                "a token-sequence model has no image: image_size 0 and no "
+                "spatial stages"
+            )
         if self.batch_size % self.parts != 0:
             raise ValueError("batch_size must divide evenly into `parts` micro-batches")
         if self.spatial_size:
@@ -221,6 +230,15 @@ class ParallelConfig:
             )
         dev = np.asarray(devices[:n]).reshape(self.mesh_shape)
         return Mesh(dev, (AXIS_DATA, AXIS_PIPE, AXIS_TILE_H, AXIS_TILE_W))
+
+    def input_spec(self, batch: int):
+        """``(shape, dtype)`` of a batch of ``batch`` samples: token ids
+        where ``sequence_length`` is set, else square NHWC images."""
+        import jax.numpy as jnp
+
+        if self.sequence_length:
+            return (batch, self.sequence_length), jnp.int32
+        return (batch, self.image_size, self.image_size, 3), jnp.float32
 
     def micro_batch_size(self) -> int:
         return self.batch_size // self.parts

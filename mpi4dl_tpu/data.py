@@ -59,50 +59,87 @@ class SyntheticImages:
         return x, y
 
     def __iter__(self):
-        if not self.prefetch:
-            for i in range(len(self)):
-                yield self._make_batch(i)
-            return
+        return _batches(self._make_batch, len(self), self.prefetch)
 
-        import queue
-        import threading
 
-        q: "queue.Queue" = queue.Queue(maxsize=2)
-        stop = threading.Event()
-        n = len(self)
+def _batches(make_batch, n: int, prefetch: bool):
+    """``make_batch(0) .. make_batch(n - 1)``, with ``prefetch`` made one
+    batch ahead by a background thread so that host synthesis overlaps
+    device compute."""
+    if not prefetch:
+        for i in range(n):
+            yield make_batch(i)
+        return
 
-        def producer():
-            try:
-                for i in range(n):
-                    item = (None, self._make_batch(i))
-                    # Bounded put so an abandoned consumer (early break in the
-                    # epoch loop) doesn't pin this thread + batches forever.
-                    while not stop.is_set():
-                        try:
-                            q.put(item, timeout=0.1)
-                            break
-                        except queue.Full:
-                            continue
-                    if stop.is_set():
-                        return
-            except BaseException as e:  # propagate instead of hanging q.get
-                q.put((e, None))
-                return
-            q.put(None)
+    import queue
+    import threading
 
-        t = threading.Thread(target=producer, daemon=True)
-        t.start()
+    q: "queue.Queue" = queue.Queue(maxsize=2)
+    stop = threading.Event()
+
+    def producer():
         try:
-            while True:
-                item = q.get()
-                if item is None:
-                    break
-                err, batch = item
-                if err is not None:
-                    raise err
-                yield batch
-        finally:
-            stop.set()  # runs on generator close/GC too — unblocks producer
+            for i in range(n):
+                item = (None, make_batch(i))
+                # Bounded put so an abandoned consumer (early break in the
+                # epoch loop) doesn't pin this thread + batches forever.
+                while not stop.is_set():
+                    try:
+                        q.put(item, timeout=0.1)
+                        break
+                    except queue.Full:
+                        continue
+                if stop.is_set():
+                    return
+        except BaseException as e:  # propagate instead of hanging q.get
+            q.put((e, None))
+            return
+        q.put(None)
+
+    t = threading.Thread(target=producer, daemon=True)
+    t.start()
+    try:
+        while True:
+            item = q.get()
+            if item is None:
+                break
+            err, batch = item
+            if err is not None:
+                raise err
+            yield batch
+    finally:
+        stop.set()  # runs on generator close/GC too — unblocks producer
+
+
+class SyntheticTokens:
+    """Deterministic token stream for a sequence model: each row draws
+    ``sequence_length + 1`` ids uniformly over ``vocab_size`` from
+    ``(seed, batch index)``; the input is the first ``sequence_length`` of
+    them and the labels are the next token at every position. One document
+    a sequence: no padding, no segment mask. ``[batch, sequence_length]``
+    int32 both. Prefetched like :class:`SyntheticImages`."""
+
+    def __init__(self, batch_size, sequence_length, vocab_size, length=60000,
+                 seed=0, prefetch=True):
+        self.batch_size = batch_size
+        self.sequence_length = sequence_length
+        self.vocab_size = vocab_size
+        self.length = length
+        self.seed = seed
+        self.prefetch = prefetch
+
+    def __len__(self):
+        return max(self.length // self.batch_size, 1)
+
+    def _make_batch(self, i):
+        rng = np.random.default_rng(np.random.SeedSequence((self.seed, i)))
+        ids = rng.integers(
+            0, self.vocab_size, (self.batch_size, self.sequence_length + 1),
+            dtype=np.int32)
+        return ids[:, :-1], ids[:, 1:]
+
+    def __iter__(self):
+        return _batches(self._make_batch, len(self), self.prefetch)
 
 
 class ClassPatternImages:
@@ -227,7 +264,11 @@ def get_dataset(args, batch_size, num_classes, shard_id=0, num_shards=1):
     ``shard_id``/``num_shards`` shard the stream for multi-process runs
     along the batch axis (``run_training`` passes ``multihost.data_shard``,
     which keeps model-parallel co-hosts — same data coordinates — on the
-    SAME shard)."""
+    SAME shard). A token model (``--sequence-length``) reads
+    :class:`SyntheticTokens`, ``num_classes`` being its vocabulary."""
+    if getattr(args, "sequence_length", 0):
+        return SyntheticTokens(
+            batch_size, args.sequence_length, num_classes, seed=shard_id)
     if args.app in (1, 2):
         kind = "imagefolder" if args.app == 1 else "cifar"
         try:

@@ -142,6 +142,12 @@ def default_remat(image_size: int) -> "bool | str":
     on image size, not a ladder of attempts — a policy whose compile
     fails, fails the run.
 
+    - a model without an image (``image_size`` 0: a token-sequence model,
+      ``ParallelConfig.sequence_length``): "cell", every cell's input kept
+      and the cell recomputed in the backward pass. Such a model is sized
+      so that parameters, gradients and momentum fill most of the chip
+      (LFM2-8B-A1B's share: 11.5 of 16 GB), and one layer's activations at
+      8,192 positions are what is left to hold.
     - below 2048 px everything is stored (``False``). Compiled for a
       described v5e chip (16 GB) on jax 0.9.0 / libtpu 0.0.34 with nothing
       rematerialized, AmoebaNet-D 18/416 @1024 bs2 bf16 needs 14.22 GiB
@@ -159,6 +165,8 @@ def default_remat(image_size: int) -> "bool | str":
       benchmark entry points do not pass. No step at 2048 px or above has
       been compiled for, or run on, a chip since.
     """
+    if not image_size:
+        return "cell"
     if image_size < 2048:
         return False
     if image_size < 3072:
@@ -280,14 +288,20 @@ class Trainer:
         self.config = config
         self.mesh = mesh if mesh is not None else config.make_mesh()
         self.tx = make_optimizer(learning_rate, momentum)
-        if self.n_spatial > 0:
+        if config.sequence_length:
+            # token ids [batch, positions], a label at every position
+            self.x_spec = P(AXIS_DATA, None)
+        elif self.n_spatial > 0:
             self.x_spec = P(AXIS_DATA, AXIS_TILE_H, AXIS_TILE_W, None)
         else:
             # No spatial section → the input is only batch-sharded; any tile
             # axes in the mesh run the whole model redundantly (still correct
             # via the psum-of-contributions normalization).
             self.x_spec = P(AXIS_DATA, None, None, None)
-        self.y_spec = P(AXIS_DATA)
+        self.y_spec = P(AXIS_DATA, None) if config.sequence_length else P(AXIS_DATA)
+        # What the newest step returned, left on the device: loss, accuracy
+        # and the step's counters (``ops.sequence.step_counters``).
+        self.last_metrics: dict = {}
         self._jit_step = jax.jit(self._train_step, donate_argnums=0)
         # Host-side step counter for XProf step annotation (profiling.
         # annotate_step): reading state.step would force a device sync.
@@ -874,6 +888,36 @@ class Trainer:
             h = run_cell(i, params[i], h)
         return h
 
+    def _apply_counting_cells(self, params, x):
+        """:meth:`_apply_cells_remat` for a model whose cells count what
+        they did (a cell names the flax collection it sows into as its
+        ``counters``: an expert layer's token-expert pairs). Returns the
+        logits and ``{name: [one array per sowing module]}``. Such a model
+        has no spatial section and runs under "cell" remat or none."""
+        if self.remat not in (False, True, "cell") or self.n_spatial:
+            raise ValueError(
+                "cells that count run under remat False or 'cell' and have "
+                f"no spatial section; got remat {self.remat!r}, "
+                f"{self.n_spatial} spatial cells")
+
+        def run_cell(i, p, h):
+            cell = self.cells[i]
+            collection = getattr(cell, "counters", None)
+            if collection is None:
+                return cell.apply(p, h), {}
+            y, sown = cell.apply(p, h, mutable=[collection])
+            return y, sown.get(collection, {})
+
+        counted: dict = {}
+        h = x
+        for i in range(len(self.cells)):
+            run = functools.partial(run_cell, i)
+            h, sown = (jax.checkpoint(run) if self.remat else run)(params[i], h)
+            for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
+                name = next(k.key for k in reversed(path) if hasattr(k, "key"))
+                counted.setdefault(name, []).append(leaf)
+        return h, counted
+
     # -- loss ----------------------------------------------------------------
     def _local_loss(self, params, x, y):
         """Per-device loss contribution; runs inside shard_map.
@@ -884,16 +928,24 @@ class Trainer:
         (replicated) section. This one line replaces the reference's
         ``divide_bs`` case analysis (``comm.py:349-358``).
         """
-        logits = self._apply_cells_remat(params, x)
+        counted = {}
+        if any(getattr(c, "counters", None) for c in self.cells):
+            logits, counted = self._apply_counting_cells(params, x)
+            # each data shard counted its own rows; the tile axes repeat them
+            counted = jax.tree.map(lambda c: lax.psum(c, AXIS_DATA), counted)
+        else:
+            logits = self._apply_cells_remat(params, x)
 
         d = axis_size(AXIS_DATA)
         replicas = axis_size(AXIS_TILE_H) * axis_size(AXIS_TILE_W)
-        global_b = y.shape[0] * d
-        denom = global_b * replicas
+        # labels in the global batch: one an image, one a position of a
+        # token sequence (loss and accuracy are means over them)
+        global_labels = y.size * d
+        denom = global_labels * replicas
         axes = (AXIS_DATA, AXIS_TILE_H, AXIS_TILE_W)
         loss = lax.psum(cross_entropy_sum(logits, y) / denom, axes)
         acc = lax.psum(correct_count(logits, y).astype(jnp.float32) / denom, axes)
-        return loss, acc
+        return loss, (acc, counted)
 
     def _sharded_loss(self, params, x, y):
         fn = shard_map(
@@ -908,24 +960,25 @@ class Trainer:
     # -- step ----------------------------------------------------------------
     def _train_step(self, state: TrainState, x, y):
         from mpi4dl_tpu.ops.halo_pallas import reset_collective_ids
+        from mpi4dl_tpu.ops.sequence import step_counters
 
         reset_collective_ids()  # deterministic per-program ids (see there)
 
+        counted = {}
         if self.grad_accum == 1:
             def loss_fn(params):
                 return self._sharded_loss(params, x, y)
 
-            (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                state.params
-            )
-        else:
+            (loss, (acc, counted)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(state.params)
+        else:  # the chunks' counters are not kept
             loss, acc, grads = self._accum_grads(state.params, x, y)
         updates, opt_state = self.tx.update(grads, state.opt_state, state.params)
         params = optax.apply_updates(state.params, updates)
         new_state = TrainState(
             params=params, opt_state=opt_state, step=state.step + 1
         )
-        return new_state, {"loss": loss, "accuracy": acc}
+        return new_state, {"loss": loss, "accuracy": acc, **step_counters(counted)}
 
     def _accum_grads(self, params, x, y):
         """Gradient accumulation: the batch runs as ``grad_accum`` equal
@@ -955,14 +1008,14 @@ class Trainer:
         if b % k != 0:
             raise ValueError(f"batch {b} not divisible by grad_accum={k}")
         xs = x.reshape(k, b // k, *x.shape[1:])
-        ys = y.reshape(k, b // k)
+        ys = y.reshape(k, b // k, *y.shape[1:])
 
         def chunk_loss(params, xc, yc):
             return self._sharded_loss(params, xc, yc)
 
         def body(carry, xy):
             gsum, lsum, asum = carry
-            (l, a), g = jax.value_and_grad(chunk_loss, has_aux=True)(
+            (l, (a, _)), g = jax.value_and_grad(chunk_loss, has_aux=True)(
                 params, *xy
             )
             carry = (jax.tree.map(jnp.add, gsum, g), lsum + l, asum + a)
@@ -1142,7 +1195,9 @@ class Trainer:
                 stack.enter_context(pool_pallas.disable())
                 stack.enter_context(dot1x1_pallas.disable())
             try:
-                return call_with_halo_hint(self._jit_step, state, x, y)
+                state, self.last_metrics = call_with_halo_hint(
+                    self._jit_step, state, x, y)
+                return state, self.last_metrics
             except Exception as e:
                 # OOM forensics (telemetry/memory.py): a RESOURCE_EXHAUSTED
                 # train step emits a structured oom.report — the parsed HBM
@@ -1170,6 +1225,14 @@ class Trainer:
                     finally:
                         events.close()
                 raise
+
+    def compiled_step(self, state, x, y):
+        """The compiled train step for arguments like these (arrays, or
+        ``jax.ShapeDtypeStruct``s with their shardings); its ``as_text()``
+        names every HLO instruction with the jax name stack it came from.
+        For a step the process already ran this is one more trace and a
+        cache hit."""
+        return self._jit_step.lower(state, x, y).compile()
 
     def record_memory_footprint(
         self, state, x, y, ledger=None, registry=None,
@@ -1219,14 +1282,15 @@ def single_device_step(cells: Sequence[Any], learning_rate=0.001, momentum=0.9, 
         def loss_fn(params):
             b = y.shape[0]
             xm = x.reshape((parts, b // parts) + tuple(x.shape[1:]))
-            ym = y.reshape((parts, b // parts))
+            ym = y.reshape((parts, b // parts) + tuple(y.shape[1:]))
             ce = jnp.zeros((), jnp.float32)
             cc = jnp.zeros((), jnp.float32)
             for m in range(parts):
                 logits = apply_cells(cells, params, xm[m])
                 ce += cross_entropy_sum(logits, ym[m])
                 cc += correct_count(logits, ym[m]).astype(jnp.float32)
-            return ce / b, cc / b
+            # over the labels: one an image, one a position of a sequence
+            return ce / y.size, cc / y.size
 
         (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
         updates, opt_state = tx.update(grads, state.opt_state, state.params)

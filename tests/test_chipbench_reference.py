@@ -1,0 +1,9 @@
+"""Tier-1 collects the yardstick's own tests: ``chipbench/tests/test_reference.py``
+runs here as it stands (ROADMAP D2). One thin file for each of the
+benchmark's test files, so that ``--dist loadfile`` spreads them."""
+
+import pytest
+
+pytest.register_assert_rewrite("chipbench.tests.test_reference")
+
+from chipbench.tests.test_reference import *  # noqa: E402,F401,F403

@@ -265,3 +265,52 @@ def test_default_on_kernels_are_the_ones_the_chip_smoke_expects(monkeypatch):
 
     assert not fastconv._wgrad_impl_allows(208)
     assert halo_pallas.default_impl() == "xla"
+
+
+def _compiled_grad(fn, one_chip, *shapes):
+    """``fn``'s value and gradient (all arguments) compiled for the described
+    chip on ``(shape, dtype)`` arguments."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    loss = lambda *a: jnp.sum(fn(*a).astype(jnp.float32))  # noqa: E731
+    grad = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(len(args)))))
+    return grad.lower(*args).compile()
+
+
+def test_blocked_attention_holds_one_block_of_scores_at_full_width(topo, cache_off):
+    """LFM2-8B-A1B's attention at 8,192 positions (8 key-value heads x 4
+    query heads of 64, bf16), forward and its own backward, for one
+    described chip: all 16 blocks' float32 scores together would be 4.3 GB
+    a pass; the barriers between blocks keep it to one block's."""
+    from mpi4dl_tpu.ops.sequence import causal_attention
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    compiled = _compiled_grad(
+        functools.partial(causal_attention, block=512), one_chip,
+        ((1, 8192, 8, 4, 64), jnp.bfloat16), ((1, 8192, 8, 64), jnp.bfloat16),
+        ((1, 8192, 8, 64), jnp.bfloat16))
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
+def test_the_expert_layers_grouped_products_are_the_chips_ragged_dot(topo, cache_off):
+    """The share of LFM2-8B-A1B's expert layer (8 of 32 experts, 4 a token,
+    widths 2048 / 1792) on 8,192 tokens, forward and backward: each
+    ``jax.lax.ragged_dot`` must reach the chip as its grouped-matmul custom
+    call (which skips the rows past the last group); expanded into one dense
+    product an expert it would multiply all 32,768 rows by all 8 experts."""
+    from mpi4dl_tpu.ops.sequence import ExpertFFN
+
+    layer = ExpertFFN(2048, 1792, 32, 8, 0, 4)
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.float32)
+    variables = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros(x.shape)))
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), (variables, x))
+    grad = jax.jit(jax.grad(
+        lambda v, x_: jnp.sum(layer.apply(v, x_).astype(jnp.float32)), argnums=(0, 1)))
+    text = grad.lower(*shapes).compile().as_text()
+    products = [line for line in text.splitlines()
+                if "custom-call(" in line and "%ragged-dot" in line.split(" = ")[0]
+                and "metadata" not in line.split(" = ")[0]]
+    # forward: three; backward: an input and a weight gradient for each
+    assert len([l for l in products if "ragged-dot-metadata" not in l.split(" = ")[0]]) == 9
+    assert all('custom_call_target="tpu_custom_call"' in line for line in products)
