@@ -3,12 +3,15 @@
 ``ops/layers.py`` holds the image classifiers' operators (NHWC convs, pools,
 BatchNorm); this file holds RMSNorm, the rotary embedding, a causal
 depthwise conv1d, causal grouped-query attention and an expert layer that
-holds a share of the experts. All of it is plain JAX (no Pallas kernel):
-the grouped matrix products are ``jax.lax.ragged_dot`` over the token-expert
-pairs sorted by expert, and attention is taken a block of query rows at a
-time, each block recomputed in the backward pass, so that the ``S x S``
-scores of a long sequence never exist at once (32 heads x 8192^2 floats are
-8.6 GB).
+holds a share of the experts. The grouped matrix products are
+``jax.lax.ragged_dot`` over the token-expert pairs sorted by expert. The
+``S x S`` scores of a long sequence never exist at once (32 heads x 8192^2
+floats are 8.6 GB): on a TPU, at the shapes ``ops/attention_pallas.py``
+takes, ``causal_attention`` is that module's fused kernels, which keep a
+block's scores in VMEM; everywhere else (the CPU, a vmapped trace, a length
+that is not whole blocks, another head dim) it is plain JAX, a block of
+query rows at a time, each block recomputed in the backward pass. The rest
+is plain JAX everywhere.
 
 Activations are ``[batch, positions, features]``. Each module computes in
 its ``dtype`` (bfloat16 on the chip) with float32 parameters, float32
@@ -28,6 +31,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from mpi4dl_tpu.ops import attention_pallas
 
 COUNTERS = "counters"  # the flax collection an expert layer sows its counts into
 
@@ -137,18 +142,32 @@ def _attention_forward(q, k, v, block):
     return jnp.concatenate(out, axis=1), jnp.concatenate(lse, axis=-1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def causal_attention(q, k, v, block: int):
-    """Causal softmax attention with grouped queries, one block of query
-    rows' scores alive at a time: ``q [B, S, KV, G, D]`` (G query heads
-    share a key-value head), ``k, v [B, S, KV, D]`` -> ``[B, S, KV, G, D]``.
-    One softmax a row; a block meets only the keys up to its own last row,
-    so the work is a little over half the square. The backward pass is its
-    own: it keeps the output and each row's log-sum-exp, recomputes a
-    block's probabilities from them, and takes the softmax's backward in
-    float32 (``dp`` accumulated in float32: as the transpose of a bfloat16
-    product it would be rounded to bfloat16 before the subtraction that
-    cancels most of it)."""
+    """Causal softmax attention with grouped queries:
+    ``q [B, S, KV, G, D]`` (G query heads share a key-value head),
+    ``k, v [B, S, KV, D]`` -> ``[B, S, KV, G, D]``. Where
+    ``attention_pallas.dispatchable`` says so (TPU backend, not under
+    ``vmap``, bfloat16, head dim 64, a length of whole kernel blocks) the
+    fused kernels, whose block is chosen from the length; else
+    ``blocked_causal_attention`` at ``block`` query rows. Both are the same
+    arithmetic: bfloat16 operands, every product accumulated in float32,
+    float32 softmax statistics, ``dp - delta`` in float32."""
+    if attention_pallas.dispatchable(q, k):
+        return attention_pallas.attention(q, k, v, attention_pallas.block_for(q.shape[1]))
+    return blocked_causal_attention(q, k, v, block)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def blocked_causal_attention(q, k, v, block: int):
+    """``causal_attention`` in plain JAX, one block of query rows' scores
+    alive at a time (the CPU's path, and the kernels' oracle). One softmax a
+    row; a block meets only the keys up to its own last row, so the work is
+    a little over half the square. The backward pass is its own: it keeps
+    the output and each row's log-sum-exp, recomputes a block's
+    probabilities from them, and takes the softmax's backward in float32
+    (``dp`` accumulated in float32: as the transpose of a bfloat16 product
+    it would be rounded to bfloat16 before the subtraction that cancels
+    most of it)."""
     return _attention_forward(q, k, v, block)[0]
 
 
@@ -184,7 +203,7 @@ def _attention_bwd(block, residuals, d_out):
     return jnp.concatenate(dq, axis=1), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-causal_attention.defvjp(_attention_fwd, _attention_bwd)
+blocked_causal_attention.defvjp(_attention_fwd, _attention_bwd)
 
 
 class Attention(nn.Module):

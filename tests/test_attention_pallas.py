@@ -1,0 +1,169 @@
+"""The fused causal-attention kernels (``ops/attention_pallas.py``) in the
+Pallas interpreter, at lengths the kernels take (whole blocks of 128): held
+to the plain blocked path they stand in for and to full-matrix attention in
+float32, to causality and to the grouping of query heads; and the dispatch
+rule of ``ops/sequence.causal_attention``.
+
+``tests/test_lfm2.py::test_position_t_does_not_see_t_plus_1`` runs 40
+positions and so holds the plain path; the kernels' causality is held here.
+What the chip's compiler makes of the kernels at full width is
+``tests/test_tpu_compile.py``'s.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mpi4dl_tpu.ops import attention_pallas
+from mpi4dl_tpu.ops.sequence import blocked_causal_attention, causal_attention
+
+KV, G, D, BLOCK = 2, 4, 64, 128
+kernel = functools.partial(attention_pallas.attention, block=BLOCK, interpret=True)
+
+
+def _inputs(length, seed=0):
+    """``q, k, v`` and a cotangent ``w`` for the output, bfloat16."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shapes = ((1, length, KV, G, D), (1, length, KV, D), (1, length, KV, D), (1, length, KV, G, D))
+    return [jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
+            for key, shape in zip(keys, shapes)]
+
+
+def _out_and_grads(attend, q, k, v, w):
+    """The output and the cotangents of ``q, k, v`` under the output's ``w``."""
+    out, vjp = jax.vjp(attend, q, k, v)
+    return (out,) + vjp(w.astype(out.dtype))
+
+
+def _full_matrix(q, k, v):
+    """Causal grouped-query attention on the whole score matrix, float32."""
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    scores = jnp.einsum("bqkgd,bnkd->bkgqn", q, k, precision="highest") * q.shape[-1] ** -0.5
+    length = q.shape[1]
+    scores = jnp.where(jnp.tril(jnp.ones((length, length), bool)), scores, -jnp.inf)
+    return jnp.einsum("bkgqn,bnkd->bqkgd", jax.nn.softmax(scores, axis=-1), v, precision="highest")
+
+
+def _gap(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+# Against the plain path: the same arithmetic (bfloat16 operands, float32
+# accumulation and statistics), but the online softmax rounds a probability
+# to bfloat16 against the running maximum, the plain path against the row's,
+# and each result is rounded to bfloat16 once (2^-9 an element): relative L2
+# 0.0004-0.0010 at these lengths, so 0.003 holds it to the rounding.
+# Against float32 full-matrix attention: the probabilities and ``ds`` are
+# bfloat16 operands of the next product, as the configuration states: 0.002-
+# 0.004 read here, 0.01 allowed; a missing block or a wrong mask reads 0.1+.
+@pytest.mark.parametrize("length", [256, 512])
+@pytest.mark.parametrize("oracle, limit", [("plain", 0.003), ("float32", 0.01)])
+def test_output_and_cotangents_match(length, oracle, limit):
+    q, k, v, w = _inputs(length)
+    attend = (functools.partial(blocked_causal_attention, block=BLOCK)
+              if oracle == "plain" else _full_matrix)
+    got = _out_and_grads(kernel, q, k, v, w)
+    want = _out_and_grads(attend, q, k, v, w)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape and a.dtype == jnp.bfloat16, name
+        assert _gap(a, b) < limit, (name, _gap(a, b))
+
+
+def test_log_sum_exp_is_the_rows_own():
+    q, k, v, _ = _inputs(256)
+    _, lse = attention_pallas.forward(q, k, v, BLOCK, interpret=True)
+    scores = jnp.einsum("bqkgd,bnkd->bkgqn", q.astype(jnp.float32), k.astype(jnp.float32),
+                        precision="highest") * D ** -0.5
+    scores = jnp.where(jnp.tril(jnp.ones((256, 256), bool)), scores, -jnp.inf)
+    assert lse.shape == (1, KV, G, 256) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(lse, jax.nn.logsumexp(scores, axis=-1), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("t", [60, 127, 128, 200])
+def test_position_t_does_not_see_later_positions(t):
+    """Everything after position ``t`` changed (inside a diagonal block, at a
+    block's last row, at the next block's first): the output rows and ``dq``
+    rows up to ``t`` are the same bits, the masked entries being exact
+    zeros and the blocks above the diagonal never read."""
+    q, k, v, w = _inputs(256)
+    other = _inputs(256, seed=1)
+    later = jnp.arange(256) > t
+    changed = [jnp.where(later.reshape(1, -1, *(1,) * (a.ndim - 2)), b, a)
+               for a, b in zip((q, k, v), other)]
+    out, dq, _, _ = _out_and_grads(kernel, q, k, v, w)
+    out2, dq2, _, _ = _out_and_grads(kernel, *changed, w)
+    np.testing.assert_array_equal(out[:, :t + 1], out2[:, :t + 1])
+    np.testing.assert_array_equal(dq[:, :t + 1], dq2[:, :t + 1])
+    assert not np.array_equal(out[:, t + 1:], out2[:, t + 1:])
+
+
+def test_a_key_value_head_serves_its_own_group_alone():
+    """Key-value head 1 changed: the four query heads of group 0 give the
+    same bits, those of group 1 move; ``dk`` / ``dv`` of head 0 are the same
+    bits (a group's sum takes nothing from the other group)."""
+    q, k, v, w = _inputs(256)
+    k2, v2 = (a.at[:, :, 1].set(b[:, :, 1]) for a, b in zip((k, v), _inputs(256, seed=1)[1:3]))
+    out, dq, dk, dv = _out_and_grads(kernel, q, k, v, w)
+    out2, dq2, dk2, dv2 = _out_and_grads(kernel, q, k2, v2, w)
+    for a, b in ((out, out2), (dq, dq2)):
+        np.testing.assert_array_equal(a[:, :, 0], b[:, :, 0])
+        assert all(not np.array_equal(a[:, :, 1, g], b[:, :, 1, g]) for g in range(G))
+    for a, b in ((dk, dk2), (dv, dv2)):
+        np.testing.assert_array_equal(a[:, :, 0], b[:, :, 0])
+        assert not np.array_equal(a[:, :, 1], b[:, :, 1])
+
+
+# -- dispatch ----------------------------------------------------------------
+
+
+def _shapes(length, dtype=jnp.bfloat16, kv=8, group=4, d=64):
+    return (jax.ShapeDtypeStruct((1, length, kv, group, d), dtype),
+            jax.ShapeDtypeStruct((1, length, kv, d), dtype))
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The dispatch gate steered to its TPU branch (nothing is run there)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def test_on_the_cpu_the_plain_path_runs():
+    assert not attention_pallas.dispatchable(*_shapes(8192))
+    q, k, v, _ = _inputs(256)
+    np.testing.assert_array_equal(
+        causal_attention(q, k, v, BLOCK), blocked_causal_attention(q, k, v, BLOCK))
+
+
+def test_the_full_width_shape_takes_the_kernel_on_a_tpu(on_tpu):
+    assert attention_pallas.dispatchable(*_shapes(8192))
+    assert attention_pallas.block_for(8192) == attention_pallas.BLOCKS[0]
+
+
+@pytest.mark.parametrize("why, shapes", [
+    ("the tiny cut's 64 positions", _shapes(64)),
+    ("an odd length", _shapes(8191)),
+    ("not whole blocks", _shapes(8192 + 64)),
+    ("float32, the CPU tests' precision", _shapes(8192, jnp.float32)),
+    ("a head dim the kernels were not written for", _shapes(8192, d=128)),
+    ("one head's queries, cotangents and dq past VMEM", _shapes(65536)),
+])
+def test_shapes_the_kernels_do_not_take_go_the_plain_way(on_tpu, why, shapes):
+    assert not attention_pallas.dispatchable(*shapes), why
+
+
+def test_under_vmap_the_plain_path_runs(on_tpu):
+    """A batched ``pallas_call`` is not what the gate vouches for."""
+    seen = []
+
+    def attend(q, k):
+        seen.append(attention_pallas.dispatchable(q, k))
+        return q
+
+    q, k = (jnp.zeros((2,) + s.shape, s.dtype) for s in _shapes(1024))
+    jax.vmap(attend)(q, k)
+    assert seen == [False]
+    assert attention_pallas.dispatchable(q[0], k[0])

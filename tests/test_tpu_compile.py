@@ -23,6 +23,7 @@ chip (PR 24), outside the tier-1 budget; it stays a scratch script.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -276,19 +277,50 @@ def _compiled_grad(fn, one_chip, *shapes):
     return grad.lower(*args).compile()
 
 
+_ATTENTION_SHAPES = (((1, 8192, 8, 4, 64), jnp.bfloat16), ((1, 8192, 8, 64), jnp.bfloat16),
+                     ((1, 8192, 8, 64), jnp.bfloat16))
+
+
 def test_blocked_attention_holds_one_block_of_scores_at_full_width(topo, cache_off):
     """LFM2-8B-A1B's attention at 8,192 positions (8 key-value heads x 4
     query heads of 64, bf16), forward and its own backward, for one
     described chip: all 16 blocks' float32 scores together would be 4.3 GB
     a pass; the barriers between blocks keep it to one block's."""
-    from mpi4dl_tpu.ops.sequence import causal_attention
+    from mpi4dl_tpu.ops.sequence import blocked_causal_attention
 
     one_chip = SingleDeviceSharding(topo.devices[0])
     compiled = _compiled_grad(
-        functools.partial(causal_attention, block=512), one_chip,
-        ((1, 8192, 8, 4, 64), jnp.bfloat16), ((1, 8192, 8, 64), jnp.bfloat16),
-        ((1, 8192, 8, 64), jnp.bfloat16))
+        functools.partial(blocked_causal_attention, block=512), one_chip, *_ATTENTION_SHAPES)
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
+def test_full_width_attention_dispatches_the_fused_kernels(topo, cache_off, monkeypatch):
+    """The same shape through ``causal_attention`` with the gate steered to
+    its TPU branch: the compiled value and gradient hold the forward kernel
+    (the gradient's own forward) and the backward kernel by name, each with
+    the ``lfm2_attention``-style name stack a scope would give it (what
+    ``attn_ms`` joins the trace with), no ``[.., 512, N]`` float32 score
+    fusion of the plain path, and under 1 GiB of temporaries. A fall-back to
+    the plain path at this shape fails here, loudly."""
+    from mpi4dl_tpu.ops import attention_pallas
+    from mpi4dl_tpu.ops.sequence import causal_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def attend(q, k, v):
+        with jax.named_scope("lfm2_attention"):
+            return causal_attention(q, k, v, 512)
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    compiled = _compiled_grad(attend, one_chip, *_ATTENTION_SHAPES)
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in (attention_pallas.FWD_NAME, attention_pallas.BWD_NAME):
+        mine = [line for line in calls if name in line.split(" = ")[0]]
+        assert len(mine) == 1, (name, [line[:80] for line in calls])
+        assert "lfm2_attention" in mine[0].split("op_name=")[1].split('"')[1]
+    assert not re.search(r"f32\[[\d,]*,512,\d+\]", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
 
 
 def test_the_expert_layers_grouped_products_are_the_chips_ragged_dot(topo, cache_off):
