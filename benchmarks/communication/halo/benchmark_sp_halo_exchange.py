@@ -37,13 +37,6 @@ def get_args():
     p.add_argument("--halo-len", type=int, default=1)
     p.add_argument("--iterations", type=int, default=100)
     p.add_argument("--warmup", type=int, default=10)
-    p.add_argument(
-        "--impl",
-        type=str,
-        default="xla",
-        choices=["xla", "pallas"],
-        help="xla = ppermute shifts; pallas = bidirectional remote-DMA kernel",
-    )
     return p.parse_args()
 
 
@@ -83,7 +76,7 @@ def main():
         # Full padded tile: every tile has the same padded shape, so the
         # shard_map output tiles evenly and the validation below can check
         # the ENTIRE halo ring (all four directions + boundary fill).
-        return halo_exchange(x, h, h, "tile_h", "tile_w", impl=args.impl)
+        return halo_exchange(x, h, h, "tile_h", "tile_w")
 
     from halo_common import validate_padded_tiles
 
@@ -104,7 +97,7 @@ def main():
         jax.block_until_ready(out)
         times.append((time.perf_counter() - t0) * 1e3)
     print(
-        f"halo exchange[{args.impl}] {s}x{s} halo={h} {args.slice_method} x{n}: "
+        f"halo exchange {s}x{s} halo={h} {args.slice_method} x{n}: "
         f"mean {statistics.mean(times):.4f} ms  median {statistics.median(times):.4f} ms"
     )
 

@@ -43,7 +43,6 @@ def get_args():
         "--kernel", type=str, default="3x3",
         help="HxW kernel, odd dims; e.g. 3x3, 1x7, 7x1, 5x5",
     )
-    p.add_argument("--impl", type=str, default="xla", choices=["xla", "pallas"])
     p.add_argument(
         "--platform", type=str, default="auto", choices=["auto", "cpu"],
         help="cpu forces host execution (ref ENABLE_GPU=False)",
@@ -112,7 +111,7 @@ def main():
         check_vma=False,
     )
     def dist(x, w):
-        p = halo_exchange(x, hh, hw, "tile_h", "tile_w", impl=args.impl)
+        p = halo_exchange(x, hh, hw, "tile_h", "tile_w")
         y = lax.conv_general_dilated(p, w, (1, 1), "VALID", dimension_numbers=dn)
         # Full padded tile (tiles evenly) so --val-recv covers the whole
         # halo ring: all exchange directions and all boundary fills.

@@ -47,7 +47,6 @@ def get_args():
     p.add_argument("--halo-len", type=int, default=1, help="(kernel-1)/2")
     p.add_argument("--iterations", type=int, default=50)
     p.add_argument("--warmup", type=int, default=5)
-    p.add_argument("--impl", type=str, default="xla", choices=["xla", "pallas"])
     p.add_argument("--skip-validation", action="store_true")
     return p.parse_args()
 
@@ -88,7 +87,7 @@ def main():
         shard_map, mesh=mesh, in_specs=(spec, P()), out_specs=spec, check_vma=False
     )
     def dist_conv(x, w):
-        p = halo_exchange(x, h, h, "tile_h", "tile_w", impl=args.impl)
+        p = halo_exchange(x, h, h, "tile_h", "tile_w")
         return lax.conv_general_dilated(p, w, (1, 1), "VALID", dimension_numbers=dn)
 
     @jax.jit
@@ -126,7 +125,7 @@ def main():
 
     m, md = bench(dist_conv, xs, w)
     print(
-        f"halo+conv[{args.impl}] {s}x{s} k={k} {args.slice_method} x{n}: "
+        f"halo+conv {s}x{s} k={k} {args.slice_method} x{n}: "
         f"mean {m:.4f} ms  median {md:.4f} ms"
     )
     m2, md2 = bench(seq_conv, x, w)

@@ -38,7 +38,6 @@ def get_args():
     p.add_argument("--num-spatial-parts", type=int, default=4)
     p.add_argument("--slice-method", type=str, default="square")
     p.add_argument("--halo-len", type=int, default=1, help="(kernel-1)/2")
-    p.add_argument("--impl", type=str, default="xla", choices=["xla", "pallas"])
     return p.parse_args()
 
 
@@ -87,7 +86,7 @@ def main():
         check_vma=False,
     )
     def dist_conv_and_padded(x, w, bias):
-        p = halo_exchange(x, h, h, "tile_h", "tile_w", impl=args.impl)
+        p = halo_exchange(x, h, h, "tile_h", "tile_w")
         y = (
             lax.conv_general_dilated(p, w, (1, 1), "VALID", dimension_numbers=dn)
             + bias

@@ -77,16 +77,9 @@ def kernel_table():
     """kernel name in the compiled text -> (module, the function that holds
     its one ``pallas_call``): a call of it while the step is traced is one
     ``tpu_custom_call`` the compiled step must hold."""
-    from mpi4dl_tpu.ops import (
-        dot1x1_pallas, halo_pallas, pool_pallas, wgrad_pallas,
-    )
+    from mpi4dl_tpu.ops import pool_pallas
 
-    return {
-        pool_pallas.KERNEL_NAME: (pool_pallas, "_bwd_padded"),
-        wgrad_pallas.KERNEL_NAME: (wgrad_pallas, "wgrad"),
-        dot1x1_pallas.KERNEL_NAME: (dot1x1_pallas, "_bwd_impl"),
-        halo_pallas.KERNEL_NAME: (halo_pallas, "_swap_call"),
-    }
+    return {pool_pallas.KERNEL_NAME: (pool_pallas, "_bwd_padded")}
 
 
 @contextlib.contextmanager

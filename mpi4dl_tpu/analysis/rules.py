@@ -157,7 +157,7 @@ def _rule_halo_permute_count(ctx: LintContext) -> list[Finding]:
             + (f" + a pipeline permute budget of {exp.extra_permutes} "
                "stage-boundary shifts" if exp.extra_permutes else "")
             + "): exchanges were elided or moved off the permute path "
-            "(Pallas DMA halo? wrong mesh? a dropped pipeline wire?)."
+            "(wrong mesh? a dropped pipeline wire?)."
         )
     else:
         msg = (

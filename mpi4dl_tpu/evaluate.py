@@ -383,9 +383,6 @@ def make_spatial_eval_step(trainer):
     from jax.sharding import PartitionSpec as P
 
     def local(params, batch_stats, x, y):
-        from mpi4dl_tpu.ops.halo_pallas import reset_collective_ids
-
-        reset_collective_ids()
         with bn_stats_mode("running"):
             logits, _ = _spatial_apply(trainer, params, batch_stats, x, False)
         ce, cc = _spatial_metrics(trainer, logits, y)
@@ -439,9 +436,6 @@ def aot_compile_spatial_predict(
     mesh = trainer.mesh
 
     def local(p, s, x):
-        from mpi4dl_tpu.ops.halo_pallas import reset_collective_ids
-
-        reset_collective_ids()
         with bn_stats_mode("running"):
             logits, _ = _spatial_apply(trainer, p, s, x, False)
         return logits
@@ -496,9 +490,6 @@ def spatial_collect_batch_stats(trainer, params, batches) -> list:
     from jax.sharding import PartitionSpec as P
 
     def local_first(params, x):
-        from mpi4dl_tpu.ops.halo_pallas import reset_collective_ids
-
-        reset_collective_ids()
         with bn_stats_mode("collect"):
             _, stats = _spatial_apply(
                 trainer, params, [{}] * len(trainer.cells), x, True
@@ -506,9 +497,6 @@ def spatial_collect_batch_stats(trainer, params, batches) -> list:
         return stats
 
     def local_rest(params, stats, x):
-        from mpi4dl_tpu.ops.halo_pallas import reset_collective_ids
-
-        reset_collective_ids()
         with bn_stats_mode("collect"):
             _, stats = _spatial_apply(trainer, params, stats, x, True)
         return stats

@@ -97,30 +97,6 @@ def conv_impl() -> str:
     return impl
 
 
-def _wgrad_impl_allows(c: int) -> bool:
-    """Pallas-wgrad dispatch policy. ``MPI4DL_TPU_WGRAD_IMPL`` = ``xla``
-    (default; never dispatch the kernel) | ``pallas`` (dispatch wherever
-    the kernel's shape gate admits, bounded by
-    ``MPI4DL_TPU_WGRAD_CMAX`` input channels). Read at trace time so
-    benchmark processes can A/B the dispatch without code edits.
-
-    Default is XLA's backward-filter conv because it wins END TO END:
-    standalone the Pallas kernel is 3-9x faster (docs/PERF.md round-2
-    table), but in the full train step XLA chooses operand layouts
-    globally and fuses the wgrad with its neighbors, and the measured
-    bench is 2.296 img/s (xla) vs 2.252 (pallas C<=16) vs 2.117 (pallas
-    everywhere). Standalone microbenchmarks mislead on this device."""
-    impl = os.environ.get("MPI4DL_TPU_WGRAD_IMPL", "xla")
-    if impl not in ("pallas", "xla"):
-        raise ValueError(
-            f"MPI4DL_TPU_WGRAD_IMPL must be pallas|xla, got {impl!r}"
-        )
-    if impl == "xla":
-        return False
-    cmax = int(os.environ.get("MPI4DL_TPU_WGRAD_CMAX", "1024"))
-    return c <= cmax
-
-
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
@@ -237,20 +213,6 @@ def _conv2d_s1_bwd(padding, res, dy):
     kh, kw, _, _ = w.shape
     (ph0, ph1), (pw0, pw1) = padding
 
-    if kh == 1 and kw == 1 and max(ph0, ph1, pw0, pw1) == 0:
-        # Fused one-pass Pallas backward where the dispatch admits it:
-        # dx and dw from ONE dy read (stock AD's two dots stream dy from
-        # HBM twice — the dominant HBM-bound cost class of the AmoebaNet
-        # step, docs/PERF.md round 5).
-        from mpi4dl_tpu.ops import dot1x1_pallas
-
-        if dot1x1_pallas.dispatchable(x, dy):
-            c, o = x.shape[-1], dy.shape[-1]
-            dx, dw = dot1x1_pallas.bwd_1x1(
-                x, dy, w.reshape(c, o)
-            )
-            return dx.astype(x.dtype), dw.reshape(1, 1, c, o).astype(w.dtype)
-
     big = (
         not (kh == 1 and kw == 1)  # the 1x1 dx IS the layout-safe 4-D dot
         and _wgrad_taps_profitable(
@@ -291,22 +253,10 @@ def _conv2d_s1_bwd(padding, res, dy):
             ((0, 0, 0), (ph0, ph1, 0), (pw0, pw1, 0), (0, 0, 0)),
         )
 
-    # k x k: the Pallas streaming kernel on TPU when the dispatch policy
-    # admits the shape (see wgrad_impl_allows); fallback: the canonical
-    # "CHWN" backward-filter conv, row-folded when the plain form would
-    # materialize pathologically-padded operand copies (see wgrad_folded).
-    from mpi4dl_tpu.ops import wgrad_pallas
-
-    if (
-        _on_tpu()
-        and _wgrad_impl_allows(x.shape[-1])
-        and wgrad_pallas.supported(
-            xt.shape, dy.shape, kh, kw, xt.dtype.itemsize, dy.dtype.itemsize
-        )
-    ):
-        dw = wgrad_pallas.wgrad(xt, dy, kh, kw)
-    else:
-        dw = wgrad_folded(xt, dy, kh, kw)
+    # k x k: the canonical "CHWN" backward-filter conv, per-tap dots where
+    # that form would materialize pathologically-padded operand copies
+    # (see wgrad_folded).
+    dw = wgrad_folded(xt, dy, kh, kw)
     return dx.astype(x.dtype), dw.astype(w.dtype)
 
 

@@ -31,12 +31,10 @@ Semantics parity notes:
 from __future__ import annotations
 
 import contextlib
-import functools
 import math
 from typing import Any, Callable, Sequence
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -237,73 +235,14 @@ def bn_stats_mode(mode: str):
         _BN_MODE[0] = prev
 
 
-def _bn_moments_plain(x):
-    """Stock-AD variant of :func:`bn_moments` (``MPI4DL_TPU_BN_BWD=xla``)
-    for A/B isolation; numerics identical modulo where rounding lands."""
+def bn_moments(x):
+    """Per-channel mean and mean of squares over every axis but the last,
+    accumulated in float32."""
     red = tuple(range(x.ndim - 1))
     n = math.prod(x.shape[a] for a in red)
     mean = jnp.sum(x, red, dtype=jnp.float32) / n
     mean_sq = jnp.sum(jnp.square(x.astype(jnp.float32)), red) / n
     return mean, mean_sq
-
-
-def bn_bwd_impl() -> str:
-    """BN-moments backward selector: "xla" (default — stock AD) or
-    "fused" (the convert-free custom VJP below). Measured on one v5e
-    (docs/PERF.md round 4): fused is NEUTRAL at @2048 (1.271 vs 1.273)
-    and @1024 (6.311 vs 6.371) — the convert_element_type self-time it
-    removes from the jaxpr was already fused traffic. Kept as an A/B
-    lever; gradcheck-verified equal to stock AD."""
-    import os
-
-    impl = os.environ.get("MPI4DL_TPU_BN_BWD", "xla")
-    if impl not in ("fused", "xla"):
-        raise ValueError(f"MPI4DL_TPU_BN_BWD must be fused|xla, got {impl!r}")
-    return impl
-
-
-def bn_moments(x):
-    """Dispatch: stock AD (default) or the convert-free custom backward
-    (``MPI4DL_TPU_BN_BWD=fused`` — see :func:`bn_bwd_impl` for the
-    measured-neutral verdict that set the default)."""
-    if bn_bwd_impl() == "fused":
-        return _bn_moments_fused(x)
-    return _bn_moments_plain(x)
-
-
-@jax.custom_vjp
-def _bn_moments_fused(x):
-    """:func:`_bn_moments_plain` with a hand-written backward that never
-    materializes a full-resolution f32 cotangent.
-
-    Motivation: the stock AD of ``sum(square(x.astype(f32)))`` computes
-    ``2x·ct`` in f32 and converts it down — traced as full-res
-    convert_element_type + f32-width mul traffic. The cotangents of
-    per-channel SUMS are per-channel scalars, so the backward here stays
-    entirely in the input dtype: ``dx = x * (2·ct_sq/n) + ct_mean/n``.
-    Same formula stock AD computes, modulo where the bf16 rounding lands;
-    gradcheck-verified equal. Measured NEUTRAL end to end on one v5e
-    (the traced converts were already fused traffic — see
-    :func:`bn_bwd_impl`), so this is the ``fused`` A/B lever, not the
-    default.
-    """
-    return _bn_moments_plain(x)
-
-
-def _bn_moments_fwd(x):
-    return _bn_moments_plain(x), x
-
-
-def _bn_moments_bwd(x, cts):
-    ct_mean, ct_sq = cts  # [C], f32
-    red = tuple(range(x.ndim - 1))
-    n = math.prod(x.shape[a] for a in red)
-    scale = ((2.0 / n) * ct_sq).astype(x.dtype)
-    shift = (ct_mean / n).astype(x.dtype)
-    return (x * scale + shift,)
-
-
-_bn_moments_fused.defvjp(_bn_moments_fwd, _bn_moments_bwd)
 
 
 class TrainBatchNorm(nn.Module):
@@ -349,10 +288,9 @@ class TrainBatchNorm(nn.Module):
             stat_src = stat_src[:, ih:-ih, :, :]
         if iw:
             stat_src = stat_src[:, :, iw:-iw, :]
-        # Statistics in f32 with the upcast fused into the reductions, the
-        # squaring AFTER the upcast (E[x^2]-E[x]^2 cancels catastrophically
-        # if x^2 is rounded to bf16 first), and a custom backward that never
-        # materializes a full-res f32 cotangent (see bn_moments). The
+        # Statistics in f32 with the upcast fused into the reductions and
+        # the squaring AFTER the upcast (E[x^2]-E[x]^2 cancels
+        # catastrophically if x^2 is rounded to bf16 first). The
         # normalize below stays in the input dtype, which profiling showed
         # otherwise costs ~12% of a bf16 train step in converts alone.
         mean, mean_sq = bn_moments(stat_src)
@@ -502,104 +440,6 @@ class Conv2d(nn.Module):
         return conv(x)
 
 
-def pool_bwd_impl() -> str:
-    """Strided-max-pool backward selector: "xla" (default — reduce_window's
-    ``select_and_scatter`` transpose) or "decomposed" (the first-match mask
-    decomposition below). ``MPI4DL_TPU_POOL_BWD`` overrides for A/B runs.
-
-    Measured (AmoebaNet-D @2048 bs1, one v5e, docs/PERF.md round 4): the
-    decomposition REGRESSED 1.273 → 0.871 img/s despite select_and_scatter
-    profiling at 10.5% of the step — its kh*kw interior-padded scatter
-    terms materialize ~9 input-resolution tensors (1.7 GB each at the
-    reduction cells' widths) where select_and_scatter makes one pass. The
-    implementation stays (semantics proven bit-equal in
-    tests/test_spatial_layers.py) as the A/B lever, default off."""
-    import os
-
-    impl = os.environ.get("MPI4DL_TPU_POOL_BWD", "xla")
-    if impl not in ("decomposed", "xla"):
-        raise ValueError(
-            f"MPI4DL_TPU_POOL_BWD must be decomposed|xla, got {impl!r}"
-        )
-    return impl
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
-def max_pool_strided(x, kh, kw, sh, sw, ph, pw):
-    """Strided max pool (−inf edge padding — torch ``MaxPool2d`` parity)
-    with a decomposed backward.
-
-    Forward: stock ``reduce_window`` max (fast everywhere). Backward: XLA's
-    transpose rule emits ``select_and_scatter``, whose sequential window
-    walk profiled at 10.5% of the AmoebaNet@2048 train step on TPU (the
-    REDUCTION cells' stride-2 pools — docs/PERF.md round 4). Here the
-    gradient routes through kh*kw strided window views instead: visiting
-    window positions in row-major order, a position claims the gradient
-    where it equals the pooled max AND no earlier position claimed it —
-    bit-identical semantics to ``select_and_scatter``'s first-max-wins GE
-    select (tests/test_spatial_layers.py proves equality on tie-heavy
-    data), so golden comparisons cannot tell the implementations apart.
-    Each step is elementwise compare/select at output resolution plus an
-    interior-padded scatter-add — ops XLA fuses well on TPU.
-    """
-    return _max_pool_fwd_val(x, kh, kw, sh, sw, ph, pw)
-
-
-def _max_pool_fwd_val(x, kh, kw, sh, sw, ph, pw):
-    neg = jnp.asarray(-jnp.inf, x.dtype)
-    xp = lax.pad(x, neg, ((0, 0, 0), (ph, ph, 0), (pw, pw, 0), (0, 0, 0)))
-    return lax.reduce_window(
-        xp, neg, lax.max, (1, kh, kw, 1), (1, sh, sw, 1), "valid"
-    )
-
-
-def _max_pool_strided_fwd(x, kh, kw, sh, sw, ph, pw):
-    y = _max_pool_fwd_val(x, kh, kw, sh, sw, ph, pw)
-    return y, (x, y)
-
-
-def _max_pool_strided_bwd(kh, kw, sh, sw, ph, pw, res, dy):
-    x, y = res
-    b, h, w, c = x.shape
-    ho, wo = y.shape[1], y.shape[2]
-    neg = jnp.asarray(-jnp.inf, x.dtype)
-    xp = lax.pad(x, neg, ((0, 0, 0), (ph, ph, 0), (pw, pw, 0), (0, 0, 0)))
-    hp, wp = h + 2 * ph, w + 2 * pw
-    claimed = jnp.zeros(y.shape, jnp.bool_)
-    zero = jnp.zeros((), dy.dtype)
-    dxp = None
-    for u in range(kh):
-        for v in range(kw):
-            # This window position's view of the input, one value per window.
-            x_uv = lax.slice(
-                xp,
-                (0, u, v, 0),
-                (b, u + (ho - 1) * sh + 1, v + (wo - 1) * sw + 1, c),
-                (1, sh, sw, 1),
-            )
-            eq = (x_uv == y) & ~claimed
-            claimed = claimed | eq
-            contrib = jnp.where(eq, dy, zero)
-            # Scatter back: output (i, j) wrote input (i*sh + u, j*sw + v)
-            # in padded coordinates — an interior pad places every value.
-            term = lax.pad(
-                contrib,
-                zero,
-                (
-                    (0, 0, 0),
-                    (u, hp - (u + (ho - 1) * sh + 1), sh - 1),
-                    (v, wp - (v + (wo - 1) * sw + 1), sw - 1),
-                    (0, 0, 0),
-                ),
-            )
-            dxp = term if dxp is None else dxp + term
-    dx = dxp[:, ph : ph + h, pw : pw + w, :]
-    return (dx,)
-
-
-max_pool_strided.defvjp(_max_pool_strided_fwd, _max_pool_strided_bwd)
-
-
 def max_pool_s1_valid(x, kh: int, kw: int):
     """Stride-1 VALID max pool as a tree of shifted ``jnp.maximum``s.
 
@@ -716,14 +556,6 @@ class Pool(nn.Module):
                             ((0, 0, 0), (*pad[0], 0), (*pad[1], 0), (0, 0, 0)),
                         )
                     return max_pool_s1_valid(t, kh, kw)
-                if pool_bwd_impl() == "decomposed":
-                    # A/B lever only (default "xla" — see pool_bwd_impl for
-                    # the measured negative result): reduce_window forward +
-                    # the first-match mask backward, bit-matching the XLA
-                    # path in both directions.
-                    return max_pool_strided(
-                        t, kh, kw, sh, sw, pad[0][0], pad[1][0]
-                    )
                 return nn.max_pool(t, (kh, kw), strides=(sh, sw), padding=pad)
             if self.kind == "avg":
                 return nn.avg_pool(

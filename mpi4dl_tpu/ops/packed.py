@@ -354,8 +354,8 @@ class PackedTrainBatchNorm(nn.Module):
             w = (lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
             b = (bias - mean * lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
             return x * jnp.tile(w, self.pack) + jnp.tile(b, self.pack)
-        # Moments over the leading axes per PACKED channel (convert-free
-        # backward — layers.bn_moments), then averaged over the pack groups
+        # Moments over the leading axes per PACKED channel
+        # (layers.bn_moments), then averaged over the pack groups
         # (equal group sizes: mean of group means == pooled mean).
         from mpi4dl_tpu.ops.layers import bn_moments
 

@@ -322,13 +322,11 @@ def dispatchable(x, kh, kw, ph, pw) -> bool:
     micro-batched front vmaps the cell stack; a batched ``pallas_call``
     compiles through an added grid dimension only sometimes, and the
     shape gate (which plans the UN-batched shape) cannot vouch for
-    it — so batched contexts keep the XLA/tree backward, exactly like the
-    halo kernel's policy (``parallel/halo.py:124-146``). The sniffs are
-    shared with that policy: the pipeline front's ``xla_halo_only``
-    context, plus a direct batch-tracer check."""
-    from mpi4dl_tpu.parallel.halo import _is_batch_tracer, _xla_only_active
+    it — so batched contexts keep the XLA/tree backward: the pipeline
+    front's ``batched_trace`` context, plus a direct batch-tracer check."""
+    from mpi4dl_tpu.parallel.halo import _in_batched_trace, _is_batch_tracer
 
-    if _DISABLED[0] or _xla_only_active() or _is_batch_tracer(x):
+    if _DISABLED[0] or _in_batched_trace() or _is_batch_tracer(x):
         return False
     return usable(x, kh, kw, ph, pw)
 

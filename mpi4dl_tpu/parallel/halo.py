@@ -30,40 +30,31 @@ from jax import lax
 
 from jax.lax import axis_size
 
-# -- Pallas-impl safety plumbing (see halo_exchange's impl dispatch) ---------
+# -- batched traces: where no Pallas kernel may be dispatched -----------------
 
-_XLA_ONLY_DEPTH = [0]
+_BATCHED_TRACE_DEPTH = [0]
 
 
 @contextlib.contextmanager
-def xla_halo_only():
-    """Force the XLA halo implementation while tracing the enclosed region.
+def batched_trace():
+    """Declare that the enclosed region is traced under ``vmap`` (the
+    pipeline's micro-batched front): the Pallas kernels' dispatch gates
+    (``ops/pool_pallas.dispatchable``) then keep the XLA path.
 
-    Batched callers (the pipeline's vmapped front) MUST wrap their tracing
-    in this: the Pallas remote-DMA kernel deadlocks under vmap batching,
-    and tracer sniffing cannot see a vmap through initial-style transforms
-    (checkpoint, scan)."""
-    _XLA_ONLY_DEPTH[0] += 1
+    A batched ``pallas_call`` compiles through an added grid dimension only
+    sometimes, and the kernels' shape gates plan the UN-batched shape. The
+    gates also sniff the tracer (:func:`_is_batch_tracer`), but initial-style
+    transforms (checkpoint, scan) between the ``vmap`` and the kernel hide
+    it, so a batched caller says so here."""
+    _BATCHED_TRACE_DEPTH[0] += 1
     try:
         yield
     finally:
-        _XLA_ONLY_DEPTH[0] -= 1
+        _BATCHED_TRACE_DEPTH[0] -= 1
 
 
-def _xla_only_active() -> bool:
-    return _XLA_ONLY_DEPTH[0] > 0
-
-
-# Explicit-impl downgrade warns ONCE per process: the hazard (a caller who
-# typed impl="pallas" silently running XLA) needs one loud line, not one
-# per traced layer — a 54-cell model would emit hundreds of identical
-# warnings per trace. Env-selected pallas downgrades silently by design.
-_PALLAS_DOWNGRADE_WARNED = [False]
-
-
-def _reset_pallas_downgrade_warning() -> None:
-    """Test hook: re-arm the once-per-process downgrade warning."""
-    _PALLAS_DOWNGRADE_WARNED[0] = False
+def _in_batched_trace() -> bool:
+    return _BATCHED_TRACE_DEPTH[0] > 0
 
 
 def _is_batch_tracer(x) -> bool:
@@ -133,7 +124,6 @@ def halo_exchange(
     axis_h: str = "tile_h",
     axis_w: str = "tile_w",
     fill_value: float = 0.0,
-    impl: str | None = None,
 ):
     """Return the local tile padded with ``halo_h``/``halo_w`` rows/cols of
     neighbor data (``fill_value`` at the global image boundary).
@@ -150,42 +140,7 @@ def halo_exchange(
     pool matches single-device max pooling exactly (the reference zero-pads
     its distributed max pool, silently diverging from torch's -inf-padded
     ``MaxPool2d`` for negative boundary activations — we fix that).
-
-    ``impl``: ``"xla"`` (ppermute shifts, default) or ``"pallas"`` (one
-    bidirectional remote-DMA kernel per axis —
-    :mod:`mpi4dl_tpu.ops.halo_pallas`); unset → ``MPI4DL_TPU_HALO_IMPL``.
     """
-    from mpi4dl_tpu.ops.halo_pallas import default_impl, halo_exchange_pallas
-
-    explicit = impl is not None
-    if impl is None:
-        impl = default_impl()
-    if impl == "pallas":
-        # The remote-DMA kernel is only safe UN-batched: under vmap (the
-        # pipeline's micro-batched front) the batching rule adds a grid
-        # dimension whose per-step DMAs interleave across devices and
-        # deadlock (reproduced on the 8-device interpreter mesh). Batched
-        # callers declare themselves with :func:`xla_halo_only` (the
-        # pipeline front does); a tracer sniff backs that up for direct
-        # vmap use, but initial-style transforms (checkpoint/scan) between
-        # the vmap and this call hide the batch tracer — the context
-        # manager is the reliable mechanism.
-        if not _xla_only_active() and not _is_batch_tracer(x):
-            return halo_exchange_pallas(
-                x, halo_h, halo_w, axis_h, axis_w, fill_value
-            )
-        if explicit and not _PALLAS_DOWNGRADE_WARNED[0]:
-            import warnings
-
-            _PALLAS_DOWNGRADE_WARNED[0] = True
-            warnings.warn(
-                "halo_exchange(impl='pallas') downgraded to the XLA path: "
-                "the Pallas remote-DMA kernel deadlocks under batched "
-                "(vmapped) tracing"
-            )
-        impl = "xla"
-    if impl != "xla":
-        raise ValueError(f"halo impl must be 'xla' or 'pallas', got {impl!r}")
     b, h, w, c = x.shape
 
     def _edge_fill(strip, axis_name, at_index):

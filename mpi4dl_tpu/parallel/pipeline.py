@@ -467,9 +467,9 @@ class PipelineTrainer:
             )
         else:
             my = x
-        from mpi4dl_tpu.parallel.halo import xla_halo_only
+        from mpi4dl_tpu.parallel.halo import batched_trace
 
-        with xla_halo_only():  # Pallas halo deadlocks under vmap batching
+        with batched_trace():  # the cells' Pallas kernels stay off under vmap
             out = jax.vmap(one_microbatch)(my)
         if shard_over_pipe:
             out = jax.tree.map(
@@ -912,10 +912,6 @@ class PipelineTrainer:
         return fn(params, x, y)
 
     def _train_step(self, state: TrainState, x, y):
-        from mpi4dl_tpu.ops.halo_pallas import reset_collective_ids
-
-        reset_collective_ids()  # deterministic per-program ids (see there)
-
         def loss_fn(params):
             return self._sharded_loss(params, x, y)
 
@@ -928,9 +924,7 @@ class PipelineTrainer:
         )
 
     def train_step(self, state: TrainState, x, y):
-        from mpi4dl_tpu.train import call_with_halo_hint
-
-        return call_with_halo_hint(self._jit_step, state, x, y)
+        return self._jit_step(state, x, y)
 
     def shard_batch(self, x, y):
         """[B, H, W, C] → micro-batched [parts, mb, H, W, C] placed on the

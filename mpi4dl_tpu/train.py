@@ -959,10 +959,7 @@ class Trainer:
 
     # -- step ----------------------------------------------------------------
     def _train_step(self, state: TrainState, x, y):
-        from mpi4dl_tpu.ops.halo_pallas import reset_collective_ids
         from mpi4dl_tpu.ops.sequence import step_counters
-
-        reset_collective_ids()  # deterministic per-program ids (see there)
 
         counted = {}
         if self.grad_accum == 1:
@@ -1184,19 +1181,15 @@ class Trainer:
                 # taps_min_mb.
                 stack.enter_context(wgrad_taps_threshold(256))
             if self.config.image_size >= 2048:
-                # Keep the Pallas pool + fused-1x1 backwards out of
-                # large-image programs: their VMEM-stack-allocated
-                # results fail the compile against the HBM ceiling
-                # (measured: AmoebaNet@2048 bs1 compiles with them off,
-                # fails with them on — pool_pallas.disable docstring;
-                # re-validated round 5 via MPI4DL_TPU_POOL_PALLAS=on).
-                from mpi4dl_tpu.ops import dot1x1_pallas
-
+                # Keep the Pallas pool backward out of large-image
+                # programs: its VMEM-stack-allocated results fail the
+                # compile against the HBM ceiling (measured:
+                # AmoebaNet@2048 bs1 compiles with it off, fails with
+                # it on — pool_pallas.disable docstring; re-validated
+                # round 5 via MPI4DL_TPU_POOL_PALLAS=on).
                 stack.enter_context(pool_pallas.disable())
-                stack.enter_context(dot1x1_pallas.disable())
             try:
-                state, self.last_metrics = call_with_halo_hint(
-                    self._jit_step, state, x, y)
+                state, self.last_metrics = self._jit_step(state, x, y)
                 return state, self.last_metrics
             except Exception as e:
                 # OOM forensics (telemetry/memory.py): a RESOURCE_EXHAUSTED
@@ -1249,20 +1242,6 @@ class Trainer:
         if ledger is None:
             ledger = FootprintLedger(registry=registry)
         return ledger.record_lowered(program, self._jit_step, state, x, y)
-
-
-def call_with_halo_hint(fn, *args):
-    """Invoke a jitted step, annotating compile errors that look like
-    Pallas collective-id-space exhaustion with the operator hint
-    (:func:`mpi4dl_tpu.ops.halo_pallas.annotate_id_space_error`). Shared by
-    both trainers so the caught-type/hint logic cannot drift."""
-    try:
-        return fn(*args)
-    except jax.errors.JaxRuntimeError as e:
-        from mpi4dl_tpu.ops.halo_pallas import annotate_id_space_error
-
-        annotate_id_space_error(e)  # operator hint; no-op off-pallas
-        raise
 
 
 def single_device_step(cells: Sequence[Any], learning_rate=0.001, momentum=0.9, parts=1):

@@ -71,15 +71,31 @@ def test_unknown_impl_rejected(monkeypatch):
         fastconv.conv2d(x, w, (1, 1), ((0, 0), (0, 0)))
 
 
-@pytest.mark.parametrize("k,pad", [(3, 1), (3, 0), (1, 0), (5, 2), (3, 3)])
-def test_custom_vjp_grads_match(k, pad, monkeypatch):
+@pytest.mark.parametrize(
+    "x_shape,o,k,pad",
+    [
+        ((2, 8, 16, 5), 7, 3, 1),
+        ((2, 8, 16, 5), 7, 3, 0),
+        ((2, 8, 16, 5), 7, 1, 0),
+        ((2, 8, 16, 5), 7, 5, 2),
+        ((2, 8, 16, 5), 7, 3, 3),
+        # k x k weight gradients: wide, batch 1, and 5x5 on a tall image
+        ((2, 18, 18, 5), 7, 3, 0),
+        ((1, 10, 26, 4), 4, 3, 0),
+        ((2, 36, 12, 3), 5, 5, 0),
+        # 1x1 at AmoebaNet-class widths: the two plain dots of the backward
+        ((2, 16, 16, 104), 208, 1, 0),
+        ((1, 8, 8, 128), 128, 1, 0),
+        ((2, 4, 8, 416), 104, 1, 0),  # more inputs than outputs
+    ],
+)
+def test_custom_vjp_grads_match(x_shape, o, k, pad, monkeypatch):
     monkeypatch.setenv("MPI4DL_TPU_CONV_IMPL", "packed")
-    x = _rand((2, 8, 16, 5))
-    w = _rand((k, k, 5, 7), seed=1) * 0.3
+    b, h, wd, c = x_shape
+    x = _rand(x_shape)
+    w = _rand((k, k, c, o), seed=1) * 0.3
     padding = ((pad, pad), (pad, pad))
-    cot = _rand(
-        (2, 8 + 2 * pad - k + 1, 16 + 2 * pad - k + 1, 7), seed=2
-    )
+    cot = _rand((b, h + 2 * pad - k + 1, wd + 2 * pad - k + 1, o), seed=2)
 
     def loss_fast(x, w):
         return jnp.sum(fastconv.conv2d(x, w, (1, 1), padding) * cot)

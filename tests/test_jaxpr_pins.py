@@ -1,0 +1,170 @@
+"""The traced step of every trainer, remat policy and evaluator, pinned.
+
+Each program is traced on the CPU mesh at a tiny size (nothing runs) and the
+sha256 of its jaxpr's text is compared with what ``c0a7bc1`` traced: a change
+to ``ops/``, ``parallel/halo.py`` or a step's prologue that is meant to leave
+the programs alone shows here that it did. A change that means to alter a
+program replaces that program's hash, in the same PR and by name. A file of
+its own so that ``--dist loadfile`` runs its ~100 s beside ``test_lfm2.py``'s,
+not after them.
+"""
+
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from test_lfm2 import BATCH, LENGTH, MODEL, _trainer  # the tiny LFM2 of that file
+
+from mpi4dl_tpu.config import ParallelConfig
+from mpi4dl_tpu.train import Trainer
+
+
+def _image_step(model, remat, spatial=False):
+    """``Trainer._train_step`` of a tiny AmoebaNet-D or ResNet-v2 on one
+    device, or with every cell but the head on 2x2 tiles (where AmoebaNet's
+    last cells need 256 px to keep a tile wider than their halo)."""
+    from mpi4dl_tpu.models.amoebanet import amoebanetd
+    from mpi4dl_tpu.models.resnet import get_resnet_v2
+    from mpi4dl_tpu.utils import get_depth
+
+    size = 256 if spatial and model == "amoebanet" else 64
+    cfg = ParallelConfig(
+        batch_size=2, split_size=1, spatial_size=int(spatial), image_size=size,
+        num_classes=10, **(dict(num_spatial_parts=(4,), slice_method="square")
+                           if spatial else {}))
+    if model == "amoebanet":
+        build, kw = amoebanetd, dict(num_classes=10, num_layers=3, num_filters=32)
+    else:
+        build, kw = get_resnet_v2, dict(
+            depth=get_depth(2, 2), num_classes=10, pool_kernel=size // 4)
+    plain = build(dtype=jnp.float32, **kw)
+    n_sp = len(plain) - 1 if spatial else 0
+    cells = build(dtype=jnp.bfloat16, **(dict(spatial_cells=n_sp) if spatial else {}), **kw)
+    trainer = Trainer(cells, n_sp, cfg, plain_cells=plain, remat=remat)
+    state = jax.eval_shape(lambda: trainer.init(jax.random.PRNGKey(0), (2, size, size, 3)))
+    return trainer._train_step, (
+        state, jax.ShapeDtypeStruct((2, size, size, 3), jnp.float32),
+        jax.ShapeDtypeStruct((2,), jnp.int32))
+
+
+def _token_step():
+    trainer = _trainer(MODEL, LENGTH)
+    state = jax.eval_shape(lambda: trainer.init(
+        jax.random.PRNGKey(0), (BATCH, LENGTH), jnp.int32))
+    ids = jax.ShapeDtypeStruct((BATCH, LENGTH), jnp.int32)
+    return trainer._train_step, (state, ids, ids)
+
+
+def _pipeline_step(kind):
+    """Two stages of a ResNet-v1: a spatial front on 2x2 tiles ahead of the
+    fill-drain schedule (the front is traced under ``vmap``), the 1F1B ring,
+    and the two-directional GEMS schedule."""
+    from mpi4dl_tpu.models.resnet import get_resnet_v1
+    from mpi4dl_tpu.parallel.pipeline import GemsMasterTrainer, PipelineTrainer
+
+    spatial = kind == "gpipe"
+    cfg = ParallelConfig(
+        batch_size=2 if spatial else 4, parts=2, split_size=2, image_size=32,
+        spatial_size=int(spatial), **(dict(num_spatial_parts=(4,), slice_method="square")
+                                      if spatial else {}))
+    plain = get_resnet_v1(depth=8)
+    if kind == "gpipe":
+        n_sp = PipelineTrainer.spatial_cell_count(len(plain), cfg)
+        trainer = PipelineTrainer(
+            get_resnet_v1(depth=8, spatial_cells=n_sp), cfg, plain_cells=plain)
+    elif kind == "1f1b":
+        trainer = PipelineTrainer(plain, cfg, schedule="1f1b", virtual_stages=2)
+    else:  # "gems"
+        trainer = GemsMasterTrainer(plain, cfg)
+    state = trainer.init(jax.random.PRNGKey(0))
+    batch = getattr(trainer, "chunks", 1) * cfg.batch_size
+    x, y = trainer.shard_batch(
+        jnp.zeros((batch, 32, 32, 3), jnp.float32), jnp.zeros((batch,), jnp.int32))
+    return trainer._train_step, (state, x, y)
+
+
+def _eval_step(spatial):
+    """``evaluate``'s frozen-statistics step on a tiny ResNet-v2: the plain
+    cells on one device, or a spatial ``Trainer``'s cells on 2x2 tiles."""
+    from mpi4dl_tpu import evaluate
+    from mpi4dl_tpu.models.resnet import get_resnet_v2
+    from mpi4dl_tpu.parallel.partition import init_cells
+    from mpi4dl_tpu.utils import get_depth
+
+    kw = dict(depth=get_depth(2, 1), num_classes=10, pool_kernel=8)
+    plain = get_resnet_v2(**kw)
+    x = jnp.zeros((4, 32, 32, 3), jnp.float32)
+    y = jnp.zeros((4,), jnp.int32)
+    params = init_cells(plain, jax.random.PRNGKey(3), x)
+    stats = evaluate.collect_batch_stats(plain, params, [x])
+    if not spatial:
+        return evaluate.make_eval_step(plain), (params, stats, x, y)
+    cfg = ParallelConfig(
+        batch_size=4, split_size=1, spatial_size=1, num_spatial_parts=(4,),
+        slice_method="square", image_size=32)
+    trainer = Trainer(get_resnet_v2(spatial_cells=len(plain) - 1, **kw),
+                      num_spatial_cells=len(plain) - 1, config=cfg, plain_cells=plain)
+    return evaluate.make_spatial_eval_step(trainer), (params, stats, x, y)
+
+
+# Each program with the sha256 of its jaxpr as ``c0a7bc1`` traced it: every
+# trainer, remat policy and evaluator that shares ``ops/``, ``parallel/halo.py``
+# and the step's prologue, so that a change there which is meant to leave the
+# programs alone can show that it did. The two AmoebaNet hashes under False and
+# "cell" date from fc8bfe1 (before ``Trainer`` learned the token family).
+TRACED_AT_C0A7BC1 = {
+    "amoebanet-False": (lambda: _image_step("amoebanet", False),
+        "eb4431aae24c350019f855dfaac178d4cda883b9657eacc6eb69e7a5f24b0cb7"),
+    "amoebanet-cell": (lambda: _image_step("amoebanet", "cell"),
+        "44145fc81dac99cf450982142c2e4a3c703f113ca23e9bd2d1574f2a1a803455"),
+    "amoebanet-scan": (lambda: _image_step("amoebanet", "scan"),
+        "6e1f31140a639a956a961a6f3c76f938569a4c791bdab3d7d8687ebe12f1ba7d"),
+    "amoebanet-scanlog": (lambda: _image_step("amoebanet", "scanlog"),
+        "ce42cdfe204422a00795ceb17a1a14a6893e4d152c03db61cbe3945b105e310e"),
+    "amoebanet-scanq": (lambda: _image_step("amoebanet", "scanq"),
+        "6e1f31140a639a956a961a6f3c76f938569a4c791bdab3d7d8687ebe12f1ba7d"),
+    "resnet-False": (lambda: _image_step("resnet", False),
+        "ff3be412231a80692827220172917bdea737cf551f89eb98edeb2fef26a38a08"),
+    "resnet-cell": (lambda: _image_step("resnet", "cell"),
+        "6b2484188b6745ace6d30af397b93f29e2ace557b6494e0556b080fa4feaffa4"),
+    "resnet-scan": (lambda: _image_step("resnet", "scan"),
+        "dc77ab9f667588033eed0c7198c0f9c10e6973c5fc0be584d15cc197a9816abb"),
+    "resnet-scanlog": (lambda: _image_step("resnet", "scanlog"),
+        "e09e10e2cac45b1d860ada0bf126b3c84240ee996d701f10138819cd74ab6182"),
+    "resnet-scanq": (lambda: _image_step("resnet", "scanq"),
+        "dc77ab9f667588033eed0c7198c0f9c10e6973c5fc0be584d15cc197a9816abb"),
+    "amoebanet_sp2x2-False": (lambda: _image_step("amoebanet", False, spatial=True),
+        "d72ad564bf7a3dc4ee4ff31b066f7876ebc5b26a538c807f00851d2247a5f345"),
+    "amoebanet_sp2x2-scan": (lambda: _image_step("amoebanet", "scan", spatial=True),
+        "07da054167a4e273e675c41edab3470747abe833ff223bb2df4e7618019d5ef9"),
+    "resnet_sp2x2-False": (lambda: _image_step("resnet", False, spatial=True),
+        "582955a93c82f69620d9339ee98fcab6e0697e852dd188397155f592c01fe753"),
+    "lfm2-cell": (_token_step,
+        "9510765a4ddc7ee2aba895c31fa81f2df18458467523385c1ff1573b2a4d4057"),
+    "pipeline-gpipe": (lambda: _pipeline_step("gpipe"),
+        "278d206dbf04f5ddd34d0b3bfb01274ea8b7e5d47f0c870c9caa6d9ee90b5b01"),
+    "pipeline-1f1b": (lambda: _pipeline_step("1f1b"),
+        "eb0590081d6cd25c36861a6550941d0a79e3afdb28c5ea0f124b2a032aecfe40"),
+    "gems_master": (lambda: _pipeline_step("gems"),
+        "fe1513d07782fc05b84f98e676b4383dbe716e295493c0ca7743f39ccf2c37c6"),
+    "eval_step": (lambda: _eval_step(spatial=False),
+        "32555030fa4bbf6a01fb3fc1dfc0416b55e85e8e48c266ae61717de40ba3d949"),
+    "spatial_eval_step": (lambda: _eval_step(spatial=True),
+        "8ccde965a604441acfe0e67f5f2a51f8d0705b743dea73a1c2c752b4e4d49a67"),
+}
+
+
+@pytest.mark.parametrize("case", list(TRACED_AT_C0A7BC1))
+def test_the_traced_step_is_what_it_was(case):
+    build, sha256 = TRACED_AT_C0A7BC1[case]
+    fn, args = build()
+    text = str(jax.make_jaxpr(fn)(*args))
+    # a frozenset prints in hash order, which differs from process to process
+    text = re.sub(
+        r"frozenset\(\{([^}]*)\}\)",
+        lambda m: "frozenset({" + ", ".join(sorted(
+            s.strip() for s in m.group(1).split(","))) + "})", text)
+    assert hashlib.sha256(text.encode()).hexdigest() == sha256

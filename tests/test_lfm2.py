@@ -4,12 +4,11 @@ The program (``mpi4dl_tpu/models/lfm2.py``, ``ops/sequence.py``, ``Trainer``'s
 token family, ``data.SyntheticTokens``, the entry script) against the
 benchmark's plain float32 reference (``chipbench/reference/lfm2_moe.py``,
 which imports nothing of the program) on seeded weights; the 8-of-32 cut
-tied to the whole layer; no token dropped; causality; the benchmark's own
-run on the tiny cell; and the image path's traced step pinned to what it
-was before ``Trainer`` learned the token family.
+tied to the whole layer; no token dropped; causality; and the benchmark's
+own run on the tiny cell. (Its traced step, with the image models', is
+pinned in ``tests/test_jaxpr_pins.py``.)
 """
 
-import hashlib
 import json
 import os
 import re
@@ -392,33 +391,3 @@ def test_the_entry_script_trains_the_tiny_cut(monkeypatch, capsys):
     losses = [float(m) for m in re.findall(r"loss (\d+\.\d+)", out)]
     assert len(losses) == 3 and all(np.isfinite(losses))
     assert "benchmark_lfm2_lp: Mean" in out and "seq/s" in out
-
-
-# sha256 of the tiny AmoebaNet-D step's jaxpr at the parent commit (fc8bfe1,
-# before ``Trainer`` learned the token family): remat False is the accepted
-# cells' policy, "cell" the one the token model shares with them.
-IMAGE_STEP_JAXPR = {
-    False: "eb4431aae24c350019f855dfaac178d4cda883b9657eacc6eb69e7a5f24b0cb7",
-    "cell": "44145fc81dac99cf450982142c2e4a3c703f113ca23e9bd2d1574f2a1a803455",
-}
-
-
-@pytest.mark.parametrize("remat", [False, "cell"])
-def test_the_image_paths_traced_step_is_what_it_was(remat):
-    from mpi4dl_tpu.models.amoebanet import amoebanetd
-
-    cfg = ParallelConfig(batch_size=2, split_size=1, spatial_size=0, image_size=64,
-                         num_classes=10)
-    kw = dict(num_classes=10, num_layers=3, num_filters=32)
-    trainer = Trainer(amoebanetd(dtype=jnp.bfloat16, **kw), 0, cfg,
-                      plain_cells=amoebanetd(dtype=jnp.float32, **kw), remat=remat)
-    state = jax.eval_shape(lambda: trainer.init(jax.random.PRNGKey(0), (2, 64, 64, 3)))
-    text = str(jax.make_jaxpr(trainer._train_step)(
-        state, jax.ShapeDtypeStruct((2, 64, 64, 3), jnp.float32),
-        jax.ShapeDtypeStruct((2,), jnp.int32)))
-    # a frozenset prints in hash order, which differs from process to process
-    text = re.sub(
-        r"frozenset\(\{([^}]*)\}\)",
-        lambda m: "frozenset({" + ", ".join(sorted(
-            s.strip() for s in m.group(1).split(","))) + "})", text)
-    assert hashlib.sha256(text.encode()).hexdigest() == IMAGE_STEP_JAXPR[remat]

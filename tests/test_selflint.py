@@ -125,6 +125,42 @@ def test_repo_lints_clean():
     )
 
 
+# The environment switches the hot path reads: each is a second path someone
+# must keep compiling and measure, so a new one is a reviewed line here.
+HOT_PATH_SWITCHES = {
+    # ops/: which conv form, which weight-gradient form, the pool kernel
+    "MPI4DL_TPU_CONV_IMPL", "MPI4DL_TPU_WGRAD_TAPS", "MPI4DL_TPU_WGRAD_TAPS_MIN_MB",
+    "MPI4DL_TPU_CONV_OVERLAP", "MPI4DL_TPU_POOL_PALLAS", "MPI4DL_TPU_COUNTING_FLOPS",
+    # train.py: the remat ladder's budgets
+    "MPI4DL_TPU_GROUP_SIZE", "MPI4DL_TPU_NOCKPT_BUDGET_MB", "MPI4DL_TPU_SAVE_BUDGET_MB",
+    "MPI4DL_TPU_SAVE_ORDER", "MPI4DL_TPU_SCAN2_OFFLOAD", "MPI4DL_TPU_SCAN2_UNROLL",
+    "MPI4DL_TPU_SCANQ_STORE_MB", "MPI4DL_TPU_SCAN_UNROLL",
+}
+
+
+def test_the_hot_paths_environment_switches_are_the_listed_ones():
+    """Every ``MPI4DL_TPU_*`` name that code under ``mpi4dl_tpu/ops/``,
+    ``mpi4dl_tpu/parallel/`` and ``mpi4dl_tpu/train.py`` holds as a string
+    of its own (what an ``os.environ`` read is given; prose is not)."""
+    import ast
+    import re
+
+    paths = [os.path.join(REPO, "mpi4dl_tpu", "train.py")]
+    for sub in ("ops", "parallel"):
+        folder = os.path.join(REPO, "mpi4dl_tpu", sub)
+        paths += [os.path.join(folder, f) for f in sorted(os.listdir(folder))
+                  if f.endswith(".py")]
+    read = set()
+    for path in paths:
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        read |= {
+            node.value for node in ast.walk(tree)
+            if isinstance(node, ast.Constant) and isinstance(node.value, str)
+            and re.fullmatch(r"MPI4DL_TPU_[A-Z0-9_]+", node.value)}
+    assert read == HOT_PATH_SWITCHES
+
+
 def test_cli_exit_codes_and_json(tmp_path):
     """Exit 0 + summary on the clean repo; exit 1 + findings on a dirty
     tree; --json emits a machine-readable array. Runs the script as a

@@ -106,34 +106,31 @@ def test_spatial_pool_matches_sequential(kind, kernel, stride, padding):
 def test_max_pool_strided_backward_matches_select_and_scatter(
     kernel, stride, padding, shape
 ):
-    """The decomposed strided-pool backward (ops/layers.py
-    ``max_pool_strided``) claims BIT-IDENTICAL semantics to XLA's
-    ``select_and_scatter`` (first max in row-major window order wins the
-    gradient). Proven here on tie-HEAVY data — small integers, so most
-    windows contain duplicated maxima and any tie-breaking difference
-    shows up immediately."""
-    from mpi4dl_tpu.ops.layers import max_pool_strided
+    """The ``Pool`` module's strided max pool (its own -inf edge padding
+    ahead of the window op) against ``flax.linen.max_pool``, whose backward
+    is XLA's ``select_and_scatter``: the first max in row-major window order
+    wins the gradient. On tie-HEAVY data (small integers, so most windows
+    hold duplicated maxima) any other tie-breaking shows at once. This is
+    the oracle a kernel for the strided pools' backward has to meet."""
+    import flax.linen as nn
 
-    kh, kw = kernel
-    sh, sw = stride
-    ph, pw = padding
     rng = np.random.default_rng(7)
     # Integer values 0..3: ties everywhere.
     x = jnp.asarray(rng.integers(0, 4, size=shape), jnp.float32)
+    pool = Pool(kind="max", kernel_size=kernel, strides=stride, padding=padding)
+    (ph, pw) = padding
 
-    def via_decomposed(x):
-        y = max_pool_strided(x, kh, kw, sh, sw, ph, pw)
+    def weighted(y):
         return jnp.sum(y * jnp.cos(jnp.arange(y.size, dtype=y.dtype)).reshape(y.shape))
+
+    def via_module(x):
+        return weighted(pool.apply({}, x))
 
     def via_xla(x):
-        import flax.linen as nn
+        return weighted(nn.max_pool(
+            x, kernel, strides=stride, padding=((ph, ph), (pw, pw))))
 
-        y = nn.max_pool(
-            x, (kh, kw), strides=(sh, sw), padding=((ph, ph), (pw, pw))
-        )
-        return jnp.sum(y * jnp.cos(jnp.arange(y.size, dtype=y.dtype)).reshape(y.shape))
-
-    v1, g1 = jax.value_and_grad(via_decomposed)(x)
+    v1, g1 = jax.value_and_grad(via_module)(x)
     v2, g2 = jax.value_and_grad(via_xla)(x)
     np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
     # Gradient ROUTING must be identical; the only tolerated difference is
@@ -145,102 +142,79 @@ def test_max_pool_strided_backward_matches_select_and_scatter(
     )
 
 
-@pytest.mark.parametrize("spatial", [False, True])
-def test_pool_decomposed_backward_dispatch(spatial, monkeypatch):
-    """MPI4DL_TPU_POOL_BWD=decomposed through the Pool MODULE (the pad
-    plumbing and the spatial halo-exchange + trim composition, which the
-    direct max_pool_strided parity test bypasses): value AND input
-    gradient must match the default-impl Pool exactly."""
-    from mpi4dl_tpu.ops.layers import Pool
-
+@pytest.mark.parametrize("strides", [2, 1])
+def test_spatial_max_pool_value_and_gradient_match_plain(strides):
+    """The spatial ``Pool`` on 2x2 tiles (halo exchange with the -inf fill,
+    the window op, the trim) against the plain ``Pool`` on the whole image,
+    on the same tie-heavy integers: value AND input gradient, strided
+    (``select_and_scatter``) and stride 1 (the tree of maxima)."""
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.integers(0, 4, size=(2, 16, 16, 3)), jnp.float32)
-    pool_kw = dict(kind="max", kernel_size=3, strides=2, padding=1)
-    mesh = _mesh(2, 2) if spatial else None
+    pool_kw = dict(kind="max", kernel_size=3, strides=strides, padding=1)
+    plain, sp = Pool(**pool_kw), Pool(**pool_kw, spatial=True)
+    mesh = _mesh(2, 2)
+    out = (2, 16 // strides, 16 // strides, 3)
+    # Position-dependent weights of the WHOLE output, cut into tiles like
+    # it: a mis-padded or mis-trimmed backward would route gradient to the
+    # wrong inputs and diverge from the plain module at once.
+    w = jnp.cos(jnp.arange(np.prod(out), dtype=jnp.float32)).reshape(out)
 
-    def run(impl):
-        monkeypatch.setenv("MPI4DL_TPU_POOL_BWD", impl)
-        plain = Pool(**pool_kw)
-        params = plain.init(jax.random.PRNGKey(0), x)
-        if not spatial:
-            def loss(x):
-                y = plain.apply(params, x)
-                return jnp.sum(y * jnp.cos(
-                    jnp.arange(y.size, dtype=y.dtype)).reshape(y.shape))
+    def loss_plain(x):
+        return jnp.sum(plain.apply({}, x) * w)
 
-            return jax.value_and_grad(loss)(x)
+    @jax.jit
+    def loss_spatial(x):
+        def local(xt, wt):
+            return jax.lax.psum(
+                jnp.sum(sp.apply({}, xt) * wt), ("tile_h", "tile_w"))
 
-        sp = Pool(**pool_kw, spatial=True)
+        return shard_map(
+            local, mesh=mesh, in_specs=(SPEC, SPEC), out_specs=P(),
+            check_vma=False,
+        )(x, w)
 
-        @jax.jit
-        def loss(x):
-            from jax import shard_map
-            from jax.sharding import PartitionSpec
-
-            def local(xt):
-                y = sp.apply(params, xt)
-                # Position-dependent weights: a mis-padded/mis-trimmed
-                # backward would route gradient to the wrong inputs and
-                # diverge from the default impl immediately.
-                w = jnp.cos(jnp.arange(y.size, dtype=y.dtype)).reshape(y.shape)
-                return jax.lax.psum(jnp.sum(y * w), ("tile_h", "tile_w"))
-
-            f = shard_map(
-                local, mesh=mesh,
-                in_specs=SPEC, out_specs=PartitionSpec(),
-                check_vma=False,
-            )
-            return f(x)
-
-        return jax.value_and_grad(loss)(x)
-
-    v_dec, g_dec = run("decomposed")
-    v_xla, g_xla = run("xla")
-    np.testing.assert_array_equal(np.asarray(v_dec), np.asarray(v_xla))
+    v_sp, g_sp = jax.value_and_grad(loss_spatial)(x)
+    v_pl, g_pl = jax.value_and_grad(loss_plain)(x)
+    # the sum is taken tile by tile: f32 summation order, nothing more
+    np.testing.assert_allclose(float(v_sp), float(v_pl), rtol=1e-5)
     np.testing.assert_allclose(
-        np.asarray(g_dec), np.asarray(g_xla), rtol=1e-6, atol=1e-6
+        np.asarray(g_sp), np.asarray(g_pl), rtol=1e-6, atol=1e-6
     )
 
 
-def test_bn_fused_backward_matches_stock_ad(monkeypatch):
-    """The MPI4DL_TPU_BN_BWD=fused lever's hand-derived backward
-    (``dx = x·(2·ct_sq/n) + ct_mean/n``) must equal stock AD — checked
-    through a full TrainBatchNorm apply (scale/bias gradients included),
-    which is how every model reaches bn_moments."""
+def test_batchnorm_gradients_match_the_closed_form():
+    """``TrainBatchNorm``'s input, scale and bias gradients (through
+    ``bn_moments``, as every model reaches it) against the closed form of
+    batch normalization's backward, worked in float64 NumPy."""
     from mpi4dl_tpu.ops.layers import TrainBatchNorm
 
     rng = np.random.default_rng(5)
-    x = jnp.asarray(rng.standard_normal((2, 8, 8, 5)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((2, 8, 8, 5)) * 2.0 + 0.5, jnp.float32)
     bn = TrainBatchNorm()
-    params = bn.init(jax.random.PRNGKey(0), x)
+    params = {"params": {
+        "scale": jnp.asarray(rng.uniform(0.5, 1.5, 5), jnp.float32),
+        "bias": jnp.asarray(rng.standard_normal(5), jnp.float32),
+    }}
+    w = np.cos(np.arange(x.size, dtype=np.float64)).reshape(x.shape)
 
-    def grads(impl):
-        monkeypatch.setenv("MPI4DL_TPU_BN_BWD", impl)
+    def loss(params, x):
+        return jnp.sum(bn.apply(params, x) * jnp.asarray(w, jnp.float32))
 
-        def loss(params, x):
-            y = bn.apply(params, x)
-            w = jnp.cos(jnp.arange(y.size, dtype=y.dtype)).reshape(y.shape)
-            return jnp.sum(y * w)
+    gp, gx = jax.grad(loss, argnums=(0, 1))(params, x)
 
-        (v, gx), gp = (
-            jax.value_and_grad(loss, argnums=1)(params, x),
-            jax.grad(loss, argnums=0)(params, x),
-        )
-        return v, gx, gp
-
-    v_f, gx_f, gp_f = grads("fused")
-    v_x, gx_x, gp_x = grads("xla")
-    np.testing.assert_allclose(float(v_f), float(v_x), rtol=1e-6)
+    x64 = np.asarray(x, np.float64)
+    scale = np.asarray(params["params"]["scale"], np.float64)
+    mean = x64.mean((0, 1, 2))
+    inv = 1.0 / np.sqrt(x64.var((0, 1, 2)) + bn.eps)
+    xhat = (x64 - mean) * inv
+    want_x = scale * inv * (
+        w - w.mean((0, 1, 2)) - xhat * (w * xhat).mean((0, 1, 2)))
+    np.testing.assert_allclose(np.asarray(gx), want_x, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(
-        np.asarray(gx_f), np.asarray(gx_x), rtol=1e-5, atol=1e-6
-    )
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
-        ),
-        gp_f,
-        gp_x,
-    )
+        np.asarray(gp["params"]["scale"]), (w * xhat).sum((0, 1, 2)),
+        rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(
+        np.asarray(gp["params"]["bias"]), w.sum((0, 1, 2)), rtol=1e-4, atol=1e-4)
 
 
 def test_spatial_window_coverage_check():

@@ -11,10 +11,10 @@ to hide it any more.
 
 The shapes are not guessed: the train step of each model is traced
 abstractly (``jax.eval_shape``) with the dispatch gates steered to their
-TPU branch and the opt-in kernels switched on, and every kernel call is
-recorded. Models: AmoebaNet-D 18/416 @1024 bs2 bf16 on one chip (what
-``chip_smoke.py`` runs), the same under SP 2x2 (its ``--chips 4`` path, 512 px
-tiles plus halos), and ResNet-110 @1024 bs2 bf16.
+TPU branch, and every kernel call is recorded. Models: AmoebaNet-D 18/416
+@1024 bs2 bf16 on one chip (what ``chip_smoke.py`` runs), the same under
+SP 2x2 (its ``--chips 4`` path, 512 px tiles plus halos), and ResNet-110
+@1024 bs2 bf16.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every xdist worker imports this file.
@@ -27,23 +27,17 @@ import re
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
-from jax import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+from jax.sharding import SingleDeviceSharding
 
 from mpi4dl_tpu.config import ParallelConfig
-from mpi4dl_tpu.ops import dot1x1_pallas, halo_pallas, pool_pallas, wgrad_pallas
+from mpi4dl_tpu.ops import pool_pallas
 from mpi4dl_tpu.train import Trainer, default_remat
 
 MODELS = ("amoebanet", "amoebanet_sp2x2", "resnet110")
-KERNELS = ("pool", "wgrad", "dot1x1")
-# (model, kernel) pairs whose gates admit nothing: ResNet has no max pool,
-# and wgrad_pallas.supported() turns every AmoebaNet conv down (its k x k
-# convs are at >= 104 channels, past the kernel's VMEM estimate).
-EMPTY = {
-    ("resnet110", "pool"), ("amoebanet", "wgrad"), ("amoebanet_sp2x2", "wgrad"),
-}
+KERNELS = ("pool",)
+# (model, kernel) pairs whose gates admit nothing: ResNet has no max pool.
+EMPTY = {("resnet110", "pool")}
 
 
 @pytest.fixture(scope="module")
@@ -122,30 +116,10 @@ def dispatched(topo):
         sig = (xp.shape, dy.shape, xp.dtype.name, kh, kw)
         return record("pool", sig, jnp.zeros(xp.shape, dy.dtype))
 
-    def wgrad(xp, dy, kh, kw, interpret=False):
-        sig = (xp.shape, dy.shape, xp.dtype.name, dy.dtype.name, kh, kw)
-        c, o = xp.shape[-1], dy.shape[-1]
-        return record("wgrad", sig, jnp.zeros((kh, kw, c, o), jnp.float32))
-
-    def dot1x1(x, dy, w2, interpret=False):
-        sig = (x.shape, dy.shape, x.dtype.name, w2.dtype.name)
-        out = (jnp.zeros(x.shape, x.dtype), jnp.zeros(w2.shape, jnp.float32))
-        return record("dot1x1", sig, out)
-
-    def swap(a, b, axis_name):
-        sig = (a.shape, b.shape, a.dtype.name, axis_name)
-        return record("halo", sig, (jnp.zeros_like(a), jnp.zeros_like(b)))
-
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
         mp.setenv("MPI4DL_TPU_CONV_IMPL", "auto")  # conftest pins "xla"
-        mp.setenv("MPI4DL_TPU_WGRAD_IMPL", "pallas")  # opt-in kernels on
-        mp.setenv("MPI4DL_TPU_DOT1X1", "auto")
-        mp.setenv("MPI4DL_TPU_HALO_IMPL", "pallas")
         mp.setattr(pool_pallas, "_bwd_padded", pool)
-        mp.setattr(wgrad_pallas, "wgrad", wgrad)
-        mp.setattr(dot1x1_pallas, "_bwd_impl", dot1x1)
-        mp.setattr(halo_pallas, "_swap_call", swap)
         for model in MODELS:
             current = seen[model] = {}
             n = 4 if model.endswith("sp2x2") else 1
@@ -172,29 +146,6 @@ def _compile_pool(one_chip, sig):
     jax.jit(fn).lower(_on(one_chip, xp, dtype), _on(one_chip, dy, dtype)).compile()
 
 
-def _compile_wgrad(one_chip, sig):
-    xp, dy, x_dtype, dy_dtype, kh, kw = sig
-    assert wgrad_pallas.supported(
-        xp, dy, kh, kw, jnp.dtype(x_dtype).itemsize, jnp.dtype(dy_dtype).itemsize
-    ), sig
-    fn = functools.partial(wgrad_pallas.wgrad, kh=kh, kw=kw)
-    jax.jit(fn).lower(
-        _on(one_chip, xp, x_dtype), _on(one_chip, dy, dy_dtype)
-    ).compile()
-
-
-def _compile_dot1x1(one_chip, sig):
-    x, dy, dtype, w_dtype = sig
-    assert dot1x1_pallas.supported(x, dy[-1], jnp.dtype(dtype).itemsize), sig
-    jax.jit(dot1x1_pallas._bwd_impl).lower(
-        _on(one_chip, x, dtype), _on(one_chip, dy, dtype),
-        _on(one_chip, (x[-1], dy[-1]), w_dtype),
-    ).compile()
-
-
-_COMPILE = {"pool": _compile_pool, "wgrad": _compile_wgrad, "dot1x1": _compile_dot1x1}
-
-
 @pytest.mark.parametrize("kernel", KERNELS)
 @pytest.mark.parametrize("model", MODELS)
 def test_admitted_shapes_compile_for_v5e(topo, cache_off, dispatched, model, kernel):
@@ -209,7 +160,7 @@ def test_admitted_shapes_compile_for_v5e(topo, cache_off, dispatched, model, ker
     one_chip = SingleDeviceSharding(topo.devices[0])
     for sig in sigs:
         try:
-            _COMPILE[kernel](one_chip, sig)
+            _compile_pool(one_chip, sig)
         except Exception as e:
             e.add_note(f"{kernel} kernel, {model}, admitted signature {sig}")
             raise
@@ -234,38 +185,19 @@ def test_the_step_dispatches_its_forty_pool_backwards(dispatched, model, padded_
     assert dispatched[model]["pool"] == want
 
 
-def test_halo_swap_compiles_for_v5e_2x2(topo, cache_off, dispatched):
-    """The opt-in halo kernel (remote DMA between chips) at the strips the
-    SP 2x2 model exchanges, on a mesh of the four described chips."""
-    sigs = sorted(dispatched["amoebanet_sp2x2"].get("halo", ()))
-    assert sigs, "no halo strip swap was traced under SP 2x2"
-    assert not dispatched["amoebanet"].get("halo")
-    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("tile_h", "tile_w"))
-    spec = P(None, "tile_h", "tile_w", None)
-    for a, b, dtype, axis in sigs:
-        assert a == b
-        fn = shard_map(
-            functools.partial(halo_pallas._swap_call, axis_name=axis),
-            mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec),
-            check_vma=False,
-        )
-        whole = _on(NamedSharding(mesh, spec), (a[0], 2 * a[1], 2 * a[2], a[3]), dtype)
-        halo_pallas.reset_collective_ids()
-        jax.jit(fn).lower(whole, whole).compile()
-
-
 def test_default_on_kernels_are_the_ones_the_chip_smoke_expects(monkeypatch):
-    """With no opt-in switch set, the pool kernel is the only one whose gate
-    opens — what ``chip_smoke.py`` then demands of the compiled step."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setenv("MPI4DL_TPU_CONV_IMPL", "auto")
-    x = jax.ShapeDtypeStruct((2, 256, 256, 208), jnp.bfloat16)
-    assert pool_pallas.dispatchable(x, 3, 3, 0, 0)
-    assert not dot1x1_pallas.dispatchable(x, x)
-    from mpi4dl_tpu.ops import fastconv
+    """The pool kernel's gate opens on a TPU at a shape of the model (what
+    ``chip_smoke.py`` then demands of the compiled step), stays shut on the
+    CPU, and shuts where a caller declares a batched trace."""
+    from mpi4dl_tpu.parallel.halo import batched_trace
 
-    assert not fastconv._wgrad_impl_allows(208)
-    assert halo_pallas.default_impl() == "xla"
+    x = jax.ShapeDtypeStruct((2, 256, 256, 208), jnp.bfloat16)
+    assert not pool_pallas.dispatchable(x, 3, 3, 0, 0)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pool_pallas.dispatchable(x, 3, 3, 0, 0)
+    with batched_trace():
+        assert not pool_pallas.dispatchable(x, 3, 3, 0, 0)
+    assert pool_pallas.dispatchable(x, 3, 3, 0, 0)
 
 
 def _compiled_grad(fn, one_chip, *shapes):
