@@ -4,7 +4,9 @@
 BatchNorm); this file holds RMSNorm, the rotary embedding, a causal
 depthwise conv1d, causal grouped-query attention and an expert layer that
 holds a share of the experts. The grouped matrix products are
-``jax.lax.ragged_dot`` over the token-expert pairs sorted by expert. The
+``jax.lax.ragged_dot`` over the token-expert pairs sorted by expert, at the
+width of a prefix of the sorted rows that follows from the share held; the
+rows past it run only in a step whose routing overflows it. The
 ``S x S`` scores of a long sequence never exist at once (32 heads x 8192^2
 floats are 8.6 GB): on a TPU, at the shapes ``ops/attention_pallas.py``
 takes, ``causal_attention`` is that module's fused kernels, which keep a
@@ -257,35 +259,146 @@ class SwiGLU(nn.Module):
             gate * linear(self.width, self.dtype, "w3")(x))
 
 
-@jax.custom_vjp
-def _permute(x, order, inverse):
-    """``x[order]`` for a permutation ``order`` of the rows, ``inverse`` its
-    inverse: both passes are gathers (a gather's own transpose is a
-    scatter-add, which the chip serialises)."""
-    return x[order]
-
-
-_permute.defvjp(
-    lambda x, order, inverse: (x[order], inverse),
-    lambda inverse, ct: (ct[inverse], None, None),
-)
+def _in_token_order(a, inverse, lo: int):
+    """For every token-expert pair in token order its row of ``a``, which
+    holds the rows of the sorted pairs ``lo ... lo + len(a)``; zeros for a
+    pair sorted outside them (``inverse[j]`` is where pair ``j`` was sorted
+    to). A gather."""
+    at = inverse - lo
+    if a.shape[0] == inverse.shape[0]:  # every pair's row is here
+        return a[at]
+    inside = (at >= 0) & (at < a.shape[0])
+    return jnp.where(inside[:, None], a[jnp.clip(at, 0, a.shape[0] - 1)], 0)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _pair_rows(x, order, inverse, k: int):
+def _unsort(y, order, inverse, lo: int):
+    """``_in_token_order`` of a range's results ``y``, ``order`` the range's
+    part of the sort's permutation. Both passes are gathers (a gather's own
+    transpose is a scatter-add, which the chip serialises)."""
+    return _in_token_order(y, inverse, lo)
+
+
+_unsort.defvjp(
+    lambda y, order, inverse, lo: (_in_token_order(y, inverse, lo), order),
+    lambda lo, order, ct: (ct[order], None, None),
+)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _pair_rows(x, order, inverse, lo: int, k: int):
     """Row ``order[i] // k`` of ``x`` for every i: the tokens' rows in the
-    order of their sorted token-expert pairs (pair ``j`` belongs to token
-    ``j // k``). Backward: the pairs' cotangents back in token order, summed
-    over a token's ``k`` pairs; gathers both ways."""
+    order of a range of their sorted token-expert pairs (pair ``j`` belongs
+    to token ``j // k``; ``order`` is the range's part of the permutation,
+    from sorted row ``lo`` on). Backward: the pairs' cotangents back in
+    token order, summed over a token's ``k`` pairs; gathers both ways."""
     return x[order // k]
 
 
 _pair_rows.defvjp(
-    lambda x, order, inverse, k: (x[order // k], (inverse, x.shape)),
-    lambda k, res, ct: (
-        ct[res[0]].reshape(res[1][0], k, res[1][1]).sum(axis=1, dtype=jnp.float32)
-        .astype(ct.dtype), None, None),
+    lambda x, order, inverse, lo, k: (x[order // k], (inverse, x.shape)),
+    lambda lo, k, res, ct: (
+        _in_token_order(ct, res[0], lo).reshape(res[1][0], k, res[1][1])
+        .sum(axis=1, dtype=jnp.float32).astype(ct.dtype), None, None),
 )
+
+
+def _prefix_rows(pairs: int, held: int, experts: int) -> int:
+    """How many of the ``pairs`` sorted token-expert pair rows the expert
+    layer always computes: twice the even share of a chip that holds
+    ``held`` of ``experts``, all of them where that is half or more."""
+    return min(pairs, 2 * pairs * held // experts)
+
+
+def _range_ffn(bounds, x, weights, w1, w3, w2, order, inverse, sizes):
+    """What the sorted pair rows ``[lo, hi) = bounds`` add to the expert
+    layer's result, ``[tokens, hidden]`` float32: their tokens' rows of
+    ``x``, the three grouped products over the part of each expert's group
+    that lies in the range, and the weighted sum over each token's pairs
+    (a pair outside the range adds zero). ``sizes [held]`` are the groups,
+    so the held pairs are the sorted rows ``[0, sum(sizes))``."""
+    lo, hi = bounds
+    ends = jnp.cumsum(sizes)
+    groups = jnp.clip(ends, lo, hi) - jnp.clip(ends - sizes, lo, hi)
+    # rows past the last group are not computed: keep what they hold out of
+    # both passes
+    held = (lo + jnp.arange(hi - lo) < ends[-1])[:, None]
+    rows = jnp.where(
+        held, _pair_rows(x, order[lo:hi], inverse, lo, weights.shape[1]), 0)
+    gate = nn.silu(lax.ragged_dot(rows, w1, groups))
+    y = lax.ragged_dot(gate * lax.ragged_dot(rows, w3, groups), w2, groups)
+    y = _unsort(jnp.where(held, y, 0), order[lo:hi], inverse, lo)
+    y = y.reshape(*weights.shape, -1).astype(jnp.float32)
+    return jnp.sum(y * weights[..., None], axis=1)
+
+
+def _add_overflow(prefix, out, operands):
+    """``out`` plus what the sorted pair rows from ``prefix`` on add to it,
+    where the held pairs (``sum(sizes)`` of them) reach past ``prefix``;
+    else ``out`` as it is. Decided on the device. Behind a barrier: without
+    it the compiler moves what reads the result (a cast) into both
+    branches, and the branch not taken is no longer free."""
+    *_, order, _, sizes = operands
+    rest = (prefix, order.shape[0])
+    return lax.optimization_barrier(lax.cond(
+        jnp.sum(sizes) > prefix,
+        lambda out, *operands: out + _range_ffn(rest, *operands),
+        lambda out, *operands: out, out, *operands))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _two_ranges(prefix, x, weights, w1, w3, w2, order, inverse, sizes):
+    """The expert layer's result from its sorted pair rows in two ranges:
+    ``[0, prefix)`` always, the rest only in a step whose held pairs
+    overflow the prefix; every held pair is computed exactly once either
+    way. The backward is the prefix's own (``jax.vjp``, its residuals at
+    the prefix's width), then a second conditional that adds the other
+    range's gradients, whose forward it runs once more (the rare step
+    pays that; it is the step that cost the whole width before).
+
+    Why both conditionals hand a running sum through (the result, then the
+    five gradients) where the plain shape would be ``prefix + cond(rest,
+    zeros)``: compiled for a v5e chip at LFM2-8B-A1B's widths, the branch
+    not taken is then its bare parameter, no copy and nothing written. With
+    zeros it writes 67 MB of result and 210 MB of gradients a layer and
+    reads them back to add them: 5 ms a step of 320 on the chip, and 0.4
+    GiB more of temporaries (PERF.md section 6, PR 36). The barriers after
+    both conditionals keep it so: left free, the compiler moved the casts
+    that read the gradients into the branches, where the branch not taken
+    then wrote them out in float32 (14 ms a step). And why a backward of
+    its own at all: ``lax.cond`` differentiated by JAX makes the branch
+    taken write zeros for every residual of the other, 1.04 GB a layer."""
+    operands = (x, weights, w1, w3, w2, order, inverse, sizes)
+    return _add_overflow(prefix, _range_ffn((0, prefix), *operands), operands)
+
+
+def _ranges_fwd(prefix, *operands):
+    floats, ints = operands[:5], operands[5:]
+    out, pull = jax.vjp(lambda *f: _range_ffn((0, prefix), *f, *ints), *floats)
+    return _add_overflow(prefix, out, operands), (pull, operands)
+
+
+def _ranges_bwd(prefix, residuals, ct):
+    pull, operands = residuals
+    floats, ints = operands[:5], operands[5:]
+    order, _, sizes = ints
+    rest = (prefix, order.shape[0])
+
+    def add_overflow(grads, floats, ct):
+        more = jax.vjp(lambda *f: _range_ffn(rest, *f, *ints), *floats)[1](ct)
+        return jax.tree.map(jnp.add, grads, more)
+
+    grads = lax.optimization_barrier(lax.cond(
+        jnp.sum(sizes) > prefix, add_overflow,
+        lambda grads, floats, ct: grads, pull(ct), floats, ct))
+    return (*grads, None, None, None)
+
+
+_two_ranges.defvjp(_ranges_fwd, _ranges_bwd)
+# under ``jit`` a model's expert layers of one shape are traced,
+# differentiated and lowered once, not once each: without it the token
+# cell's first step took 3.8 s longer than with one range (PERF.md, PR 36)
+_ranges_ffn = jax.jit(_two_ranges, static_argnums=0)
 
 
 class _ExpertWeights(nn.Module):
@@ -309,17 +422,29 @@ class ExpertFFN(nn.Module):
     weights the scores themselves, normalised to sum 1 and scaled) and
     computes the part of the result that the ``held`` experts from ``first``
     on give. What the other experts would add is left out: on the chips
-    that share this layer it is their part of the sum. No token-expert pair
-    on a held expert is ever dropped: the pairs are sorted by expert, the
-    held ones first, and ``jax.lax.ragged_dot`` multiplies each group by its
-    expert (on a TPU a grouped-matmul kernel that skips the rows past the
-    last group), so a step in which every token picks ``per_token`` held
-    experts is computed in full, and one in which few do costs little.
+    that share this layer it is their part of the sum.
+
+    No token-expert pair on a held expert is ever dropped. The ``P = tokens
+    x per_token`` pairs are sorted by expert, the held ones first, so the
+    ``n`` held pairs are the sorted rows ``[0, n)``, and ``jax.lax.
+    ragged_dot`` multiplies each group by its expert (on a TPU a
+    grouped-matmul kernel that skips the rows past the last group). The
+    rows are computed in two ranges by one function (``_range_ffn``): the
+    prefix ``[0, C)`` always, and ``[C, P)`` only in a step whose own count
+    says held pairs lie there (``n > C``, decided on the device:
+    ``_ranges_ffn``). ``C = min(P, 2 P held / experts)`` (``_prefix_rows``),
+    twice the even share, so a chip whose experts draw up to twice their
+    share of the routing gathers, selects, multiplies and casts ``C`` rows
+    and not ``P``; a step in which every token picks ``per_token`` held
+    experts is still computed in full. A layer that holds half or all of
+    its experts has ``C == P``: one range, no conditional.
 
     The router (scores, choice, weights) is float32: a near-tie in the
     top-k that fell otherwise in bfloat16 would move a whole token's
-    output. Sows ``expert_pairs [held]`` into the ``counters`` collection:
-    the token-expert pairs each held expert computed."""
+    output; the rows and products are ``dtype``, the weighted sum over a
+    token's pairs float32. Sows into the ``counters`` collection
+    ``expert_pairs [held]``, the token-expert pairs each held expert
+    computed, and ``prefix_alone``, 1 where ``n <= C``."""
 
     hidden: int
     width: int
@@ -357,24 +482,23 @@ class ExpertFFN(nn.Module):
             group = jnp.where((local >= 0) & (local < self.held), local, self.held)
             sizes = jnp.sum(
                 group[..., None] == jnp.arange(self.held), axis=(0, 1), dtype=jnp.int32)
+            pairs = tokens * k
+            prefix = _prefix_rows(pairs, self.held, self.experts)
             if not self.is_initializing():  # ``init`` returns parameters alone
                 self.sow(COUNTERS, "expert_pairs", sizes)
-            sorted_group, order = lax.sort_key_val(
-                group.reshape(-1), jnp.arange(tokens * k, dtype=jnp.int32))
+                self.sow(COUNTERS, "prefix_alone",
+                         (jnp.sum(sizes) <= prefix).astype(jnp.int32))
+            _, order = lax.sort_key_val(
+                group.reshape(-1), jnp.arange(pairs, dtype=jnp.int32))
             inverse = jnp.argsort(order)
-            held_sorted = (sorted_group < self.held)[:, None]
 
             w1, w3, w2 = (w.astype(self.dtype) for w in _ExpertWeights(
                 self.held, self.hidden, self.width, name="experts")())
-            # rows past the last group are not computed: keep what they hold
-            # out of both passes
-            rows = jnp.where(
-                held_sorted, _pair_rows(x.astype(self.dtype), order, inverse, k), 0)
-            gate = nn.silu(lax.ragged_dot(rows, w1, sizes))
-            y = lax.ragged_dot(gate * lax.ragged_dot(rows, w3, sizes), w2, sizes)
-            y = _permute(jnp.where(held_sorted, y, 0), inverse, order)
-            y = y.reshape(tokens, k, self.hidden).astype(jnp.float32)
-            out = jnp.sum(y * weights[..., None], axis=1)
+            operands = (x.astype(self.dtype), weights, w1, w3, w2, order, inverse, sizes)
+            if prefix < pairs:
+                out = _ranges_ffn(prefix, *operands)
+            else:
+                out = _range_ffn((0, prefix), *operands)
             return out.astype(self.dtype).reshape(shape)
 
 
@@ -384,8 +508,11 @@ def step_counters(counted: dict) -> dict:
     from the expert layers' ``expert_pairs``, ``moe_pairs`` (token-expert
     pairs computed on held experts, all expert layers together) and
     ``moe_max_share`` (the busiest held expert's share of its own layer's
-    pairs; even routing over ``held`` experts reads ``1 / held``). Device
-    scalars: the step never reads them on the host."""
+    pairs; even routing over ``held`` experts reads ``1 / held``); from
+    their ``prefix_alone``, ``moe_narrow_layers`` (the expert layers that
+    computed the prefix of their sorted rows alone; each data shard decides
+    for its own tokens and counts for itself). Device scalars: the step
+    never reads them on the host."""
     if "expert_pairs" not in counted:
         return {}
     pairs = jnp.stack(counted["expert_pairs"]).astype(jnp.float32)  # [layers, held]
@@ -393,4 +520,5 @@ def step_counters(counted: dict) -> dict:
     return {
         "moe_pairs": jnp.sum(pairs),
         "moe_max_share": jnp.max(pairs / jnp.maximum(per_layer, 1.0)),
+        "moe_narrow_layers": jnp.sum(jnp.stack(counted["prefix_alone"]).astype(jnp.float32)),
     }

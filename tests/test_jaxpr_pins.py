@@ -114,7 +114,8 @@ def _eval_step(spatial):
 # trainer, remat policy and evaluator that shares ``ops/``, ``parallel/halo.py``
 # and the step's prologue, so that a change there which is meant to leave the
 # programs alone can show that it did. The two AmoebaNet hashes under False and
-# "cell" date from fc8bfe1 (before ``Trainer`` learned the token family).
+# "cell" date from fc8bfe1 (before ``Trainer`` learned the token family);
+# "lfm2-cell" is PR 36's.
 TRACED_AT_C0A7BC1 = {
     "amoebanet-False": (lambda: _image_step("amoebanet", False),
         "eb4431aae24c350019f855dfaac178d4cda883b9657eacc6eb69e7a5f24b0cb7"),
@@ -142,8 +143,11 @@ TRACED_AT_C0A7BC1 = {
         "07da054167a4e273e675c41edab3470747abe833ff223bb2df4e7618019d5ef9"),
     "resnet_sp2x2-False": (lambda: _image_step("resnet", False, spatial=True),
         "582955a93c82f69620d9339ee98fcab6e0697e852dd188397155f592c01fe753"),
+    # replaced by PR 36, which meant to alter this program and no other: the
+    # expert layer computes its sorted pair rows in two ranges and sows
+    # ``prefix_alone`` (c0a7bc1 traced 9510765a4ddc7ee2...)
     "lfm2-cell": (_token_step,
-        "9510765a4ddc7ee2aba895c31fa81f2df18458467523385c1ff1573b2a4d4057"),
+        "b56dc89de99b9a7431447c1f8a4374db57103933ebde21adfa49ecdcb491d3a4"),
     "pipeline-gpipe": (lambda: _pipeline_step("gpipe"),
         "278d206dbf04f5ddd34d0b3bfb01274ea8b7e5d47f0c870c9caa6d9ee90b5b01"),
     "pipeline-1f1b": (lambda: _pipeline_step("1f1b"),

@@ -207,8 +207,9 @@ def test_the_four_shares_add_up_to_the_uncut_reference_layer():
                          ids=["one_held_expert", "every_pick_held", "none_held"])
 def test_no_token_expert_pair_on_a_held_expert_is_dropped(favoured, pairs_per_token):
     """A bias that sends every token to the same two experts: all to one
-    held expert (a quarter of the rows ``ragged_dot`` is given, all in one
-    group), both picks held (every row used: the worst case the layer is
+    held expert (all in one group, and exactly the prefix of the sorted rows
+    that a holder of 2 of 8 experts always computes), both picks held (every
+    row used, so the rows past the prefix run: the worst case the layer is
     sized for), or none held (an all-zero part)."""
     s, params, shares = _whole_and_shares()
     model, held = shares[1]  # experts 2 and 3
@@ -221,6 +222,9 @@ def test_no_token_expert_pair_on_a_held_expert_is_dropped(favoured, pairs_per_to
     tokens = BATCH * LENGTH
     assert counted.sum() == pairs_per_token * tokens
     assert list(counted) == [tokens * (2 + e in favoured) for e in range(2)]
+    # 96 sorted pair rows, the prefix 2 * 96 * 2 / 8 = 48 of them
+    assert sequence._prefix_rows(2 * tokens, 2, 8) == tokens
+    assert int(sown[sequence.COUNTERS]["prefix_alone"][0]) == (pairs_per_token < 2)
     if pairs_per_token:
         assert check.relative_l2(got, want) < 1e-6
         assert float(jnp.min(jnp.linalg.norm(got, axis=-1))) > 0  # every token served
@@ -229,6 +233,87 @@ def test_no_token_expert_pair_on_a_held_expert_is_dropped(favoured, pairs_per_to
     # the gradient passes the rows that were not computed by
     grads = jax.grad(lambda v: jnp.sum(_program_ffn(model).apply({"params": v}, x) ** 2))(held)
     assert all(np.all(np.isfinite(np.asarray(g))) for g in jax.tree.leaves(grads))
+
+
+def _bias_for_held_pairs(held_pairs, params, x):
+    """An ``expert_bias`` under which experts 2 and 3 (the share's) compute
+    exactly ``held_pairs`` of the 96 token-expert pairs of ``x``."""
+    tokens = BATCH * LENGTH
+    if held_pairs is None:  # no bias: the router alone, near even, about 24
+        return jnp.zeros((8,))
+    bias = jnp.zeros((8,)).at[2].set(10.0).at[7].set(5.0)  # every token: 2, then 7
+    extra = held_pairs - tokens  # tokens whose second pick is expert 3
+    if extra == tokens:
+        return bias.at[3].set(7.0)
+    if extra:
+        scores = jax.nn.sigmoid(jnp.matmul(
+            x.reshape(tokens, -1), params["gate"]["kernel"], precision="highest"))
+        lead = np.sort(np.asarray(scores[:, 3] - scores[:, 7]))[::-1]
+        bias = bias.at[3].set(5.0 - (lead[extra - 1] + lead[extra]) / 2)
+    return bias
+
+
+@pytest.mark.parametrize("held_pairs", [None, 48, 49, 96],
+                         ids=["below_prefix", "exactly_prefix", "one_past_prefix", "every_pair"])
+def test_the_two_row_ranges_are_the_one_range_layer(held_pairs, monkeypatch):
+    """A share of 2 of 8 experts, 2 a token: 96 sorted pair rows of which the
+    first 48 always run and the other 48 only when held pairs lie there.
+    Value against the float32 reference; value and every gradient (input,
+    router, the three expert arrays) against the same layer made to compute
+    all 96 rows as one range."""
+    s, params, shares = _whole_and_shares()
+    model, held = shares[1]  # experts 2 and 3
+    x = jax.random.normal(jax.random.PRNGKey(3), (BATCH, LENGTH, s.hidden))
+    held = dict(held, expert_bias=_bias_for_held_pairs(held_pairs, held, x))
+    ffn = _program_ffn(model)
+    ct = jax.random.normal(jax.random.PRNGKey(4), x.shape)
+
+    def loss(v, x_):
+        y, sown = ffn.apply({"params": v}, x_, mutable=[sequence.COUNTERS])
+        return jnp.sum(y * ct), (y, sown[sequence.COUNTERS])
+
+    def value_and_grads():
+        (_, (y, counted)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(held, x)
+        return y, counted, grads
+
+    y, counted, (dv, dx) = value_and_grads()
+    n = int(counted["expert_pairs"][0].sum())
+    assert n == (held_pairs or n) and (held_pairs or 10 < n < 40)
+    assert int(counted["prefix_alone"][0]) == (n <= 48)
+    assert check.relative_l2(y, ref.expert_ffn(plain.Scope(held), x, ref.sizes(model))) < 1e-6
+    monkeypatch.setattr(sequence, "_prefix_rows", lambda pairs, held, experts: pairs)
+    y1, counted1, (dv1, dx1) = value_and_grads()
+    assert int(counted1["prefix_alone"][0]) == 1  # one range: nothing past it
+    assert check.relative_l2(y, y1) < 1e-6 and check.relative_l2(dx, dx1) < 1e-6
+    for got, want in ((dv["gate"]["kernel"], dv1["gate"]["kernel"]),
+                      *((dv["experts"][w], dv1["experts"][w]) for w in ("w1", "w3", "w2"))):
+        assert np.any(np.asarray(want)) and check.relative_l2(got, want) < 1e-6
+
+
+def test_the_step_counts_the_expert_layers_that_stayed_on_the_prefix(forced):
+    """``moe_narrow_layers`` through ``Trainer``: the tiny model's three
+    expert layers hold 2 of 8 experts (a prefix of half the rows); seeded
+    weights route evenly and all three stay on it, a bias that crowds the
+    held experts in two of them leaves one."""
+    _, params, _, (x, y) = forced
+    trainer = _trainer(MODEL, LENGTH)
+
+    def narrow_layers(params):
+        params = jax.tree.map(jnp.copy, params)  # the step donates its state
+        state = TrainState(params=params, opt_state=trainer.tx.init(params),
+                           step=jnp.zeros((), jnp.int32))
+        _, metrics = trainer.train_step(state, *trainer.shard_batch(jnp.asarray(x), jnp.asarray(y)))
+        return float(metrics["moe_narrow_layers"]), float(metrics["moe_pairs"])
+
+    even, pairs = narrow_layers(params)
+    assert even == 3.0 and pairs < 3 * BATCH * LENGTH
+    crowded = jax.tree.map(jnp.copy, params)
+    for index in (3, 4):  # the two conv expert layers; the attention one stays even
+        ffn = crowded[index]["params"]["feed_forward"]
+        ffn["expert_bias"] = ffn["expert_bias"].at[jnp.asarray([2, 3])].set(10.0)
+    fewer, pairs = narrow_layers(crowded)
+    assert fewer == 1.0 and pairs > 2 * 2 * BATCH * LENGTH
 
 
 def _plain_attention(q, k, v):
@@ -340,6 +425,11 @@ def test_the_benchmarks_run_passes_the_program_and_fails_the_fp8_control(tmp_pat
     per_token = spec.metric_reader("layer_metrics", "moe_pairs_per_token")(context)
     # 2 expert layers; 2 experts a token of which 4 of 8 are held: 1.0 expected
     assert 0.7 < per_token < 1.3
+    # the tiny cut holds 4 of 8 experts: the prefix is every row, so both of
+    # its expert layers count as narrow
+    narrow = spec.metric_reader("layer_metrics", "moe_narrow_layers")
+    assert narrow(context) == 2.0
+    assert narrow(dict(context, trainer=object())) is None  # a program that does not count it
     readers = {name: spec.metric_reader("layer_metrics", name)
                for name in ("moe_ms", "attn_ms", "shortconv_ms")}
     assert all(read(context) is None for read in readers.values())  # not traced

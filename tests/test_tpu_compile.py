@@ -260,7 +260,13 @@ def test_the_expert_layers_grouped_products_are_the_chips_ragged_dot(topo, cache
     widths 2048 / 1792) on 8,192 tokens, forward and backward: each
     ``jax.lax.ragged_dot`` must reach the chip as its grouped-matmul custom
     call (which skips the rows past the last group); expanded into one dense
-    product an expert it would multiply all 32,768 rows by all 8 experts."""
+    product an expert it would multiply every row by all 8 experts. The
+    layer computes its 32,768 sorted pair rows as two ranges of 16,384 (PR
+    36): the first always, the second under a conditional. The step that
+    stays on the first must not pay for the second: the conditional's other
+    branch hands its operands through (no zero-filled gradient the size of
+    the expert arrays), and the layer's temporaries stay within a fifth of
+    the one-range layer's."""
     from mpi4dl_tpu.ops.sequence import ExpertFFN
 
     layer = ExpertFFN(2048, 1792, 32, 8, 0, 4)
@@ -269,12 +275,39 @@ def test_the_expert_layers_grouped_products_are_the_chips_ragged_dot(topo, cache
     one_chip = SingleDeviceSharding(topo.devices[0])
     shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), (variables, x))
-    grad = jax.jit(jax.grad(
-        lambda v, x_: jnp.sum(layer.apply(v, x_).astype(jnp.float32)), argnums=(0, 1)))
-    text = grad.lower(*shapes).compile().as_text()
-    products = [line for line in text.splitlines()
-                if "custom-call(" in line and "%ragged-dot" in line.split(" = ")[0]
-                and "metadata" not in line.split(" = ")[0]]
-    # forward: three; backward: an input and a weight gradient for each
-    assert len([l for l in products if "ragged-dot-metadata" not in l.split(" = ")[0]]) == 9
-    assert all('custom_call_target="tpu_custom_call"' in line for line in products)
+
+    def products(text):
+        calls = [line for line in text.splitlines()
+                 if "custom-call(" in line and "%ragged-dot" in line.split(" = ")[0]
+                 and "metadata" not in line.split(" = ")[0]]
+        assert all('custom_call_target="tpu_custom_call"' in line for line in calls)
+        return [re.search(r"= \w+\[([\d,]+)\]", line).group(1) for line in calls]
+
+    def value(v, x_):
+        return jnp.sum(layer.apply(v, x_).astype(jnp.float32))
+
+    # forward: three products a range, every one over 16,384 rows
+    forward = jax.jit(value).lower(*shapes).compile().as_text()
+    assert sorted(products(forward)) == 2 * ["16384,1792"] * 2 + 2 * ["16384,2048"]
+    assert forward.count(" conditional(") == 1
+    # the gradient (whose value nothing reads: the forward's conditional goes).
+    # First range: three forward, an input and a weight gradient for each.
+    # Second range, all inside the backward's one conditional: its forward
+    # once more and the same six.
+    compiled = jax.jit(jax.grad(value, argnums=(0, 1))).lower(*shapes).compile()
+    text = compiled.as_text()
+    shapes_of = products(text)
+    assert len(shapes_of) == 18
+    assert len([s for s in shapes_of if s.startswith("8,")]) == 6  # weight gradients
+    assert all(s.startswith(("8,", "16384,")) for s in shapes_of)
+    assert text.count(" conditional(") == 1
+    # zeros for the gradients of a range that did not run would be
+    # broadcasts the size of the expert arrays (3 x 59 MB a layer, and as
+    # much again to add them). Temporaries: the one-range layer of c32c8c2
+    # compiled to 818,508,288 bytes, this one to 935,816,704 (877,847,040
+    # without the ``jit`` around the two ranges: the compiler's prefetches
+    # fall otherwise); the branch that runs the second range holds the first
+    # range's gradients beside its own rows. A zero-filled residual set
+    # reads 2.59e9.
+    assert not re.search(r"bf16\[8,(2048,1792|1792,2048)\]\S* broadcast\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2 * 818_508_288
