@@ -125,12 +125,12 @@ def build_amoebanet(args, cfg, spatial_cells=0):
     )
 
 
-def lfm2_args(argv, model: "dict | None" = None):
-    """The parsed flags of an LFM2 run: the shared CLI plus ``--model-config``
-    (a JSON file of the model's ``config.json`` keys, read into ``args.model``
-    unless ``model`` hands them over) and ``--sequence-length``. There is no
-    image (``--image-size`` 0) and ``--num-classes`` is the vocabulary the
-    model holds."""
+def token_model_args(argv, model: "dict | None" = None):
+    """The parsed flags of a token model's run: the shared CLI plus
+    ``--model-config`` (a JSON file of the model's ``config.json`` keys, read
+    into ``args.model`` unless ``model`` hands them over) and
+    ``--sequence-length``. There is no image (``--image-size`` 0) and
+    ``--num-classes`` is the vocabulary the model holds."""
     import json
 
     from mpi4dl_tpu.parser import get_parser
@@ -140,7 +140,7 @@ def lfm2_args(argv, model: "dict | None" = None):
         "--model-config",
         help="JSON file of the model's published config.json keys; a chip's "
         "share of a deployment counts what it holds and states the "
-        "published values under `cut` (mpi4dl_tpu/models/lfm2.py)")
+        "published values under `cut` (mpi4dl_tpu/models/lfm2.py, qwen3_next.py)")
     parser.add_argument(
         "--sequence-length", type=int, default=8192,
         help="Tokens in a sequence (one document a sequence)")
@@ -156,33 +156,55 @@ def lfm2_args(argv, model: "dict | None" = None):
     return args
 
 
-def build_lfm2(args, cfg, spatial_cells=0):
-    """(cells, float32 twin) of the LFM2 model ``args.model`` describes."""
+def _token_cells(args, model, spatial_cells):
+    """(cells, float32 twin) of a token model: ``model(config, dtype)``."""
     import jax.numpy as jnp
-
-    from mpi4dl_tpu.models.lfm2 import LFM2Config, lfm2
 
     if spatial_cells:
         raise ValueError("a token model has no spatial stages")
-    config = LFM2Config.from_dict(args.model)
     dtype = jnp.bfloat16 if args.precision == "bf16" else jnp.float32
-    return lfm2(config, dtype), lfm2(config, jnp.float32)
+    return model(args.model, dtype), model(args.model, jnp.float32)
 
 
-def lfm2_trainer(config: dict, batch_size: int):
-    """``(trainer, cfg)`` of a benchmark configuration of LFM2 (its file as a
-    dict: the model's keys and ``entry_point.argv``), built as
-    ``benchmarks/layer_parallelism/benchmark_lfm2_lp.py`` builds it: the
-    builder such a configuration names (``entry_point.build_trainer``)."""
+def build_lfm2(args, cfg, spatial_cells=0):
+    """(cells, float32 twin) of the LFM2 model ``args.model`` describes."""
+    from mpi4dl_tpu.models.lfm2 import lfm2
+
+    return _token_cells(args, lfm2, spatial_cells)
+
+
+def build_qwen3_next(args, cfg, spatial_cells=0):
+    """(cells, float32 twin) of the Qwen3-Next model ``args.model`` describes."""
+    from mpi4dl_tpu.models.qwen3_next import qwen3_next
+
+    return _token_cells(args, qwen3_next, spatial_cells)
+
+
+def _token_trainer(config: dict, batch_size: int, build_model):
+    """``(trainer, cfg)`` of a benchmark configuration of a token model (its
+    file as a dict: the model's keys and ``entry_point.argv``), built as its
+    entry script builds it."""
     argv = list(config["entry_point"]["argv"]) + ["--batch-size", str(batch_size)]
-    args = lfm2_args(argv, model=config)
+    args = token_model_args(argv, model=config)
     cfg = build_config(args, spatial=False)
-    cells, plain = build_lfm2(args, cfg)
+    cells, plain = build_model(args, cfg)
     trainer, _ = make_trainer(args, cfg, cells, plain)
     return trainer, cfg
 
 
-def lfm2_input_stream(cfg, traffic: dict, seed: int):
+def lfm2_trainer(config: dict, batch_size: int):
+    """As ``benchmarks/layer_parallelism/benchmark_lfm2_lp.py`` builds it: the
+    builder such a configuration names (``entry_point.build_trainer``)."""
+    return _token_trainer(config, batch_size, build_lfm2)
+
+
+def qwen3_next_trainer(config: dict, batch_size: int):
+    """As ``benchmarks/layer_parallelism/benchmark_qwen3_next_lp.py`` builds
+    it (``entry_point.build_trainer``)."""
+    return _token_trainer(config, batch_size, build_qwen3_next)
+
+
+def token_input_stream(cfg, traffic: dict, seed: int):
     """The program's token pipeline under a benchmark's traffic mix, seeded
     by the run (``entry_point.input_stream``)."""
     from mpi4dl_tpu.data import SyntheticTokens
@@ -190,6 +212,9 @@ def lfm2_input_stream(cfg, traffic: dict, seed: int):
     return SyntheticTokens(
         int(traffic["batch_size"]), int(traffic["sequence_length"]),
         cfg.num_classes, seed=seed, prefetch=bool(traffic["prefetch"]))
+
+
+lfm2_input_stream = token_input_stream  # the name the LFM2 configuration gives
 
 
 def make_trainer(args, cfg, cells, plain_cells, gems: bool = False, n_spatial=None):

@@ -24,11 +24,11 @@ sys.path.insert(
     0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "..")
 )
 
-from common import build_config, build_lfm2, lfm2_args, make_trainer, run_training
+from common import build_config, build_lfm2, make_trainer, run_training, token_model_args
 
 
 def main():
-    args = lfm2_args(sys.argv[1:])
+    args = token_model_args(sys.argv[1:])
     cfg = build_config(args, spatial=False)
     cells, plain = build_lfm2(args, cfg)
     trainer, _ = make_trainer(args, cfg, cells, plain)

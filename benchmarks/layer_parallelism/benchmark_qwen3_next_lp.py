@@ -1,0 +1,41 @@
+"""Qwen3-Next training benchmark (token sequences, one program per chip)
+
+The sibling of ``benchmark_lfm2_lp.py`` for the hybrid of Gated DeltaNet and
+gated attention layers with a 512-expert layer and its shared expert
+(``mpi4dl_tpu/models/qwen3_next.py``): the shared CLI plus ``--model-config``
+(the model's published ``config.json`` keys, or a chip's share of a
+deployment of it) and ``--sequence-length``; the same ``build_config`` /
+``make_trainer`` / ``run_training`` walk. A sample is one sequence, so the
+rates printed are sequences a second.
+
+    # the tiny cut, on the CPU
+    JAX_PLATFORMS=cpu python benchmarks/layer_parallelism/benchmark_qwen3_next_lp.py \
+        --model-config benchmarks/layer_parallelism/qwen3_next_tiny.json \
+        --sequence-length 160 --batch-size 2 --max-steps 3 -v
+    # one chip's share of Qwen3-Next-80B-A3B over sixteen chips, on the chip
+    python benchmarks/layer_parallelism/benchmark_qwen3_next_lp.py \
+        --model-config chipbench/configs/qwen3_next_80b_a3b_share16.json \
+        --batch-size 2 --precision bf16 --max-steps 20 -v
+"""
+
+import os
+import sys
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
+sys.path.insert(
+    0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "..")
+)
+
+from common import build_config, build_qwen3_next, make_trainer, run_training, token_model_args
+
+
+def main():
+    args = token_model_args(sys.argv[1:])
+    cfg = build_config(args, spatial=False)
+    cells, plain = build_qwen3_next(args, cfg)
+    trainer, _ = make_trainer(args, cfg, cells, plain)
+    run_training(args, trainer, tag="benchmark_qwen3_next_lp")
+
+
+if __name__ == "__main__":
+    main()

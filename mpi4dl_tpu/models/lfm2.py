@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from mpi4dl_tpu.ops.sequence import (
     COUNTERS,
     Attention,
+    Embedding,
     ExpertFFN,
     RMSNorm,
     ShortConv,
@@ -92,17 +93,6 @@ class LFM2Config:
         )
 
 
-class _Table(nn.Module):
-    vocab: int
-    hidden: int
-
-    @nn.compact
-    def __call__(self, ids):
-        table = self.param(
-            "embedding", nn.initializers.normal(1.0), (self.vocab, self.hidden))
-        return jnp.take(table, ids, axis=0)
-
-
 class LFM2Embed(nn.Module):
     """Token ids ``[batch, positions]`` -> ``[batch, positions, hidden]``."""
 
@@ -112,7 +102,7 @@ class LFM2Embed(nn.Module):
     @nn.compact
     def __call__(self, ids):
         c = self.config
-        return _Table(c.vocab_size, c.hidden_size, name="embed_tokens")(ids).astype(self.dtype)
+        return Embedding(c.vocab_size, c.hidden_size, name="embed_tokens")(ids).astype(self.dtype)
 
 
 class LFM2Layer(nn.Module):

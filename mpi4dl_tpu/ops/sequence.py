@@ -2,8 +2,10 @@
 
 ``ops/layers.py`` holds the image classifiers' operators (NHWC convs, pools,
 BatchNorm); this file holds RMSNorm, the rotary embedding, a causal
-depthwise conv1d, causal grouped-query attention and an expert layer that
-holds a share of the experts. The grouped matrix products are
+depthwise conv1d, causal grouped-query attention, the Gated DeltaNet mixer
+(a per-head state carried along the sequence, computed a chunk of positions
+at a time) and an expert layer that holds a share of the experts. The
+grouped matrix products are
 ``jax.lax.ragged_dot`` over the token-expert pairs sorted by expert, at the
 width of a prefix of the sorted rows that follows from the share held; the
 rows past it run only in a step whose routing overflows it. The
@@ -19,9 +21,12 @@ Activations are ``[batch, positions, features]``. Each module computes in
 its ``dtype`` (bfloat16 on the chip) with float32 parameters, float32
 normalisation statistics and a float32 router.
 
-The device trace finds the three mechanisms by ``jax.named_scope``:
-``lfm2_moe`` (router, top-k, sort, grouped products, combine),
-``lfm2_attention`` and ``lfm2_shortconv``.
+The device trace finds the mechanisms by ``jax.named_scope``:
+``lfm2_moe`` (router, top-k, sort, grouped products, combine; inside it
+``shared_expert`` where the layer has one), ``lfm2_attention``,
+``lfm2_shortconv`` and ``gated_delta`` (inside it ``gated_delta_rule``, the
+chunked rule without the projections). The shared modules keep the names
+their first model gave them: the benchmark's readers find them by name.
 """
 
 from __future__ import annotations
@@ -37,17 +42,24 @@ from jax import lax
 from mpi4dl_tpu.ops import attention_pallas
 
 COUNTERS = "counters"  # the flax collection an expert layer sows its counts into
+RULE_CHUNK = 64        # positions the gated delta rule takes as one triangular system
 
 
 class RMSNorm(nn.Module):
     """``x / rms(x) * scale`` over the last axis, in float32; the result is
-    float32 too (the router reads it so; a matrix product casts it)."""
+    float32 too (the router reads it so; a matrix product casts it).
+    ``zero_centred``: the scale is ``1 + w`` with ``w`` from 0 (Qwen3-Next's
+    convention), else ``w`` from 1."""
 
     eps: float
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        if self.zero_centred:
+            scale = 1.0 + self.param("scale", nn.initializers.zeros, (x.shape[-1],))
+        else:
+            scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
         x = x.astype(jnp.float32)
         return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps) * scale
 
@@ -57,17 +69,36 @@ def linear(features: int, dtype, name: str) -> nn.Dense:
     return nn.Dense(features, use_bias=False, dtype=dtype, name=name)
 
 
-def rope(x, theta: float):
+class Embedding(nn.Module):
+    """A table ``[vocab, hidden]`` (normal, standard deviation 1); token ids
+    pick its rows."""
+
+    vocab: int
+    hidden: int
+
+    @nn.compact
+    def __call__(self, ids):
+        table = self.param(
+            "embedding", nn.initializers.normal(1.0), (self.vocab, self.hidden))
+        return jnp.take(table, ids, axis=0)
+
+
+def rope(x, theta: float, rotary: int = 0):
     """Rotary embedding of ``x [batch, positions, heads, dim]``, half-split
     pairing, positions 0..S-1 in every row (one document a sequence);
-    angles and rotation in float32."""
-    half = x.shape[-1] // 2
+    angles and rotation in float32. ``rotary``: the leading dims that turn
+    (a partial rotary factor; the rest pass as they are), 0 for all."""
+    rotary = rotary or x.shape[-1]
+    half = rotary // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    x1, x2 = x[..., :half], x[..., half:rotary]
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if rotary < x.shape[-1]:
+        parts.append(x[..., rotary:])
+    return jnp.concatenate(parts, axis=-1)
 
 
 def causal_depthwise_conv1d(x, kernel):
@@ -107,6 +138,202 @@ class _Kernel(nn.Module):
     @nn.compact
     def __call__(self):
         return self.param("kernel", nn.initializers.lecun_normal(), self.shape)
+
+
+# -- the gated delta rule ----------------------------------------------------
+
+
+_exact = functools.partial(jnp.matmul, precision=lax.Precision.HIGHEST)
+
+
+@jax.custom_vjp
+def _nilpotent_inverse(lower):
+    """``(I - L)^-1`` of strictly lower triangular ``L [..., C, C]``, float32
+    at precision "highest": ``L^C = 0``, so the inverse is the finite series
+    ``I + L + ... + L^(C-1) = (I + L)(I + L^2)(I + L^4)...``, about
+    ``log2 C`` pairs of batched products and no row-by-row substitution. The
+    backward keeps the inverse alone: ``dL = T^T dT T^T``."""
+    size = lower.shape[-1]
+    inverse = jnp.eye(size, dtype=lower.dtype) + lower
+    power, reach = lower, 2  # ``inverse`` holds the powers below ``reach``
+    while reach < size:
+        power = _exact(power, power)
+        inverse = inverse + _exact(inverse, power)
+        reach *= 2
+    return inverse
+
+
+def _inverse_bwd(inverse, ct):
+    transposed = jnp.swapaxes(inverse, -1, -2)
+    return (_exact(_exact(transposed, ct), transposed),)
+
+
+_nilpotent_inverse.defvjp(lambda lower: 2 * (_nilpotent_inverse(lower),), _inverse_bwd)
+
+
+@jax.checkpoint
+def _chunk_terms(q, k, v, g, beta):
+    """What the gated delta rule needs of every chunk before any state is
+    known, all chunks at once: ``q, k [b n c h d]``, ``v [b n c h r e]``,
+    ``g, beta [b n c h r]`` (b batch, n chunk, c / m positions in the chunk,
+    h key head, r value head of the key head, d key dim, e value dim) ->
+    ``w, u`` (the WY pair: the corrected values are ``u - w S_0``), ``k``
+    decayed to the chunk's end, the chunk's whole decay, ``q k^T`` under the
+    decay and ``q`` decayed from the chunk's start. Recomputed in the
+    backward pass: its many chunk-by-chunk squares are not kept."""
+    dtype, chunk = v.dtype, g.shape[2]
+    f32 = dict(preferred_element_type=jnp.float32)
+    total = jnp.cumsum(g, axis=2)                              # G [b n c h r]
+    at = jnp.arange(chunk)
+    gap = total[:, :, :, None] - total[:, :, None]             # [b n c m h r]
+    decay = jnp.exp(jnp.where(
+        (at[:, None] >= at[None, :])[:, :, None, None], gap, -jnp.inf))
+    decay = jnp.moveaxis(decay, (2, 3), (-2, -1))              # [b n h r c m]
+    kk = jnp.einsum("bnchd,bnmhd->bnhcm", k, k, **f32)
+    qk = jnp.einsum("bnchd,bnmhd->bnhcm", q, k, **f32)
+    beta_c = jnp.moveaxis(beta, 2, -1)[..., None]              # [b n h r c 1]
+    strictly = at[:, None] > at[None, :]
+    system = jnp.where(strictly, beta_c * kk[:, :, :, None] * decay, 0.0)
+    solve = _nilpotent_inverse(-system).astype(dtype)          # T [b n h r c m]
+    within = (qk[:, :, :, None] * decay).astype(dtype)         # incl. the diagonal
+
+    grown = jnp.exp(total)                                     # exp(G_c)
+    to_end = jnp.exp(total[:, :, -1:] - total)                 # exp(G_C - G_c)
+    v_beta = (v * beta[..., None]).astype(dtype)
+    k_beta = (k[:, :, :, :, None] * (beta * grown)[..., None]).astype(dtype)
+    u = jnp.einsum("bnhrcm,bnmhre->bnhrce", solve, v_beta, **f32)
+    w = jnp.einsum("bnhrcm,bnmhrd->bnhrcd", solve, k_beta).astype(dtype)
+    k_end = jnp.moveaxis(
+        k[:, :, :, :, None] * to_end[..., None], 2, 4).astype(dtype)  # [b n h r c d]
+    end = jnp.exp(total[:, :, -1])[..., None, None]            # [b n h r 1 1]
+    q_grown = (q[:, :, :, :, None] * grown[..., None]).astype(dtype)  # [b n c h r d]
+    return w, u, k_end, end, within, q_grown
+
+
+@jax.checkpoint
+def _chunked_rule(q, k, v, g, beta):
+    batch, length, heads, key_dim = k.shape
+    dtype, chunk = v.dtype, RULE_CHUNK
+    pad = -length % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in (q, k, v, g, beta))
+    chunks = (length + pad) // chunk
+    w, u, k_end, end, within, q_grown = _chunk_terms(*(
+        a.reshape(batch, chunks, chunk, *a.shape[2:]) for a in (q, k, v, g, beta)))
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    def hand_on(state, chunk_):
+        w_n, u_n, k_n, end_n = chunk_
+        start = state.astype(dtype)
+        fresh = (u_n - jnp.einsum("bhrcd,bhrde->bhrce", w_n, start, **f32)).astype(dtype)
+        state = state * end_n + jnp.einsum("bhrcd,bhrce->bhrde", k_n, fresh, **f32)
+        return state, (start, fresh)
+
+    zero = jnp.zeros((batch, heads, v.shape[3], key_dim, v.shape[4]), jnp.float32)
+    _, (starts, fresh) = lax.scan(
+        hand_on, zero, tuple(jnp.moveaxis(a, 1, 0) for a in (w, u, k_end, end)))
+    out = jnp.einsum("bnchrd,nbhrde->bnchre", q_grown, starts, **f32) \
+        + jnp.einsum("bnhrcm,nbhrme->bnchre", within, fresh, **f32)
+    out = out.reshape(batch, chunks * chunk, *out.shape[3:])
+    return out[:, :length].astype(dtype)
+
+
+def gated_delta_rule(q, k, v, g, beta):
+    """The gated delta rule over a sequence, a chunk of ``RULE_CHUNK``
+    positions at a time.
+
+    Per value head, with a state ``S [Dk, Dv]`` that is zero before position
+    0: ``S <- exp(g_t) S; r = v_t - S^T k_t; S <- S + beta_t k_t r^T;
+    o_t = S^T q_t``. ``q, k [B, S, H, Dk]`` (already normalised and scaled),
+    ``v [B, S, H, R, Dv]`` (``R`` value heads share a key head's ``q, k``),
+    ``g, beta [B, S, H, R]`` float32 (``g <= 0`` the log of the decay)
+    -> ``[B, S, H, R, Dv]`` in ``v``'s dtype.
+
+    Within a chunk of ``C`` positions the ``C`` updates are one triangular
+    system: with ``G`` the running sum of ``g`` inside the chunk and
+    ``A[c, m] = beta_c (k_c . k_m) exp(G_c - G_m)`` for ``m < c``, the
+    corrected values are ``(I + A)^-1 (beta v - (beta exp(G) k) S_0)`` (the
+    WY form: ``T = (I + A)^-1`` once, then ``u = T beta v`` and
+    ``w = T beta exp(G) k``), so the chunk needs the state ``S_0`` it starts
+    from and no other (``_chunk_terms``, all chunks at once). Between chunks
+    that state is handed on by a ``lax.scan`` whose step is two products;
+    the outputs are again two products over all the chunks at once.
+
+    The backward pass is JAX's own through the scan, so it keeps one state
+    a chunk (not one a position); the rule as a whole and the chunks' terms
+    inside it are each recomputed there (``jax.checkpoint``), so that a
+    layer's backward holds the rule's five inputs while its other parts are
+    differentiated, and the chunks' squares only while they are.
+
+    Matrix products take operands in ``v``'s dtype and accumulate in
+    float32; ``G``, the decays, ``A``, its inverse and the carried state are
+    float32. Every exponent is of a difference ``<= 0``: a strong decay
+    underflows to the 0 it is, nothing overflows. A length that is not whole
+    chunks is padded at the end (``k, v, beta, g`` zero there change no
+    state) and cut again."""
+    with jax.named_scope("gated_delta_rule"):
+        return _chunked_rule(q, k, v, g, beta)
+
+
+class GatedDeltaNet(nn.Module):
+    """Qwen3-Next's linear-attention mixer: ``q, k, v, z = split(x W_qkvz)``,
+    ``b, a = split(x W_ba)``; a causal depthwise convolution and a SiLU over
+    ``concat(q, k, v)``; ``beta = sigmoid(b)`` and
+    ``g = -exp(A_log) softplus(a + dt_bias)`` in float32; ``q, k``
+    L2-normalised over a head's dims, ``q`` times ``key_dim ** -0.5``, each
+    key head's pair used by ``value_heads / key_heads`` value heads; the
+    gated delta rule (``gated_delta_rule``); then per head
+    ``RMSNorm(o) * silu(z)`` (one scale of ``value_dim``, from 1) and
+    ``out_proj``. The columns of ``W_qkvz`` are ``[q | k | v | z]`` and of
+    ``W_ba`` ``[b | a]``, each in head order."""
+
+    hidden: int
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    taps: int
+    eps: float
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        with jax.named_scope("gated_delta"):
+            batch, length, _ = x.shape
+            heads, per_key = self.key_heads, self.value_heads // self.key_heads
+            keys, values = heads * self.key_dim, self.value_heads * self.value_dim
+            x = x.astype(self.dtype)
+            qkv, z = jnp.split(
+                linear(2 * keys + 2 * values, self.dtype, "in_proj_qkvz")(x),
+                [2 * keys + values], axis=-1)
+            w_ba = _Kernel((self.hidden, 2 * self.value_heads), name="in_proj_ba")()
+            b, a = jnp.split(jnp.matmul(
+                x, w_ba.astype(self.dtype), preferred_element_type=jnp.float32), 2, axis=-1)
+            kernel = _Kernel((self.taps, 2 * keys + values), name="conv")()
+            q, k, v = jnp.split(
+                nn.silu(causal_depthwise_conv1d(qkv, kernel.astype(self.dtype))),
+                [keys, 2 * keys], axis=-1)
+
+            a_log = self.param("A_log", nn.initializers.normal(2.0), (self.value_heads,))
+            dt_bias = self.param("dt_bias", nn.initializers.ones, (self.value_heads,))
+            g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
+            beta = jax.nn.sigmoid(b)
+
+            def unit(t):  # a head's dims to length 1, in float32
+                t = t.reshape(batch, length, heads, self.key_dim).astype(jnp.float32)
+                return t * lax.rsqrt(jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+
+            q = (unit(q) * self.key_dim ** -0.5).astype(self.dtype)
+            by_head = (batch, length, heads, per_key)
+            out = gated_delta_rule(
+                q, unit(k).astype(self.dtype), v.reshape(*by_head, self.value_dim),
+                g.reshape(by_head), beta.reshape(by_head))
+            out = RMSNorm(self.eps, name="norm")(out) * nn.silu(
+                z.reshape(out.shape).astype(jnp.float32))
+            return linear(self.hidden, self.dtype, "out_proj")(
+                out.astype(self.dtype).reshape(batch, length, values))
 
 
 # -- attention ---------------------------------------------------------------
@@ -210,7 +437,12 @@ blocked_causal_attention.defvjp(_attention_fwd, _attention_bwd)
 
 class Attention(nn.Module):
     """Causal grouped-query attention with an RMSNorm over each head's dims
-    of q and of k (learned scale) before the rotary embedding."""
+    of q and of k (learned scale) before the rotary embedding. From the
+    model's configuration: ``head_dim`` (0: ``hidden // heads``),
+    ``rotary_dim`` (the leading dims of a head that the rotary embedding
+    turns, 0: all), ``output_gate`` (``q_proj`` is twice as wide, each
+    head's second half a gate: the attention's output times its sigmoid,
+    before ``out_proj``) and ``zero_centred_norms`` (``RMSNorm``)."""
 
     hidden: int
     heads: int
@@ -219,25 +451,36 @@ class Attention(nn.Module):
     rope_theta: float
     block: int = 512
     dtype: Any = jnp.bfloat16
+    head_dim: int = 0
+    rotary_dim: int = 0
+    output_gate: bool = False
+    zero_centred_norms: bool = False
 
     @nn.compact
     def __call__(self, x):
         with jax.named_scope("lfm2_attention"):
             batch, length, _ = x.shape
-            d = self.hidden // self.heads
+            d = self.head_dim or self.hidden // self.heads
             x = x.astype(self.dtype)
-            q = linear(self.heads * d, self.dtype, "q_proj")(x)
+            q = linear(self.heads * d * (1 + self.output_gate), self.dtype, "q_proj")(x)
             k = linear(self.kv_heads * d, self.dtype, "k_proj")(x)
             v = linear(self.kv_heads * d, self.dtype, "v_proj")(x)
-            q = q.reshape(batch, length, self.heads, d)
+            if self.output_gate:
+                q, gate = jnp.split(
+                    q.reshape(batch, length, self.heads, 2 * d), 2, axis=-1)
+            else:
+                q = q.reshape(batch, length, self.heads, d)
             k = k.reshape(batch, length, self.kv_heads, d)
             v = v.reshape(batch, length, self.kv_heads, d)
-            q = rope(RMSNorm(self.eps, name="q_layernorm")(q), self.rope_theta)
-            k = rope(RMSNorm(self.eps, name="k_layernorm")(k), self.rope_theta)
+            norm = functools.partial(RMSNorm, self.eps, self.zero_centred_norms)
+            q = rope(norm(name="q_layernorm")(q), self.rope_theta, self.rotary_dim)
+            k = rope(norm(name="k_layernorm")(k), self.rope_theta, self.rotary_dim)
             q = q.astype(self.dtype).reshape(
                 batch, length, self.kv_heads, self.heads // self.kv_heads, d)
             out = causal_attention(q, k.astype(self.dtype), v, self.block)
             out = out.reshape(batch, length, self.heads * d)
+            if self.output_gate:
+                out = out * jax.nn.sigmoid(gate.reshape(out.shape))
             return linear(self.hidden, self.dtype, "out_proj")(out)
 
 
@@ -271,36 +514,94 @@ def _in_token_order(a, inverse, lo: int):
     return jnp.where(inside[:, None], a[jnp.clip(at, 0, a.shape[0] - 1)], 0)
 
 
+@jax.custom_vjp
+def _pair_weights(weights, pairs, inverse, lo):
+    """``weights [P, 1]`` (a column, token order) at the pairs ``pairs`` of
+    a range of the sorted rows from ``lo`` on. Both passes are gathers (a
+    gather's own transpose is a scatter-add, which the chip serialises); the
+    backward is as wide as all the pairs, of one column."""
+    return weights[pairs]
+
+
+_pair_weights.defvjp(
+    lambda weights, pairs, inverse, lo: (weights[pairs], (inverse, lo)),
+    lambda res, ct: (_in_token_order(ct, *res), None, None, None),
+)
+
+
+def _by_token(pairs, inverse, lo, k: int):
+    """How a range's rows lie in token order, for ``_sum_by_token``:
+    ``(token of every row in that order, the permutation that puts the rows
+    in it, every token's first row there, whether it has one)``. ``pairs``
+    are the range's token-expert pairs (sorted rows ``lo ...``; pair ``j`` is
+    token ``j // k``'s), so their rising order is token order, and a token's
+    first row is the count of the range's pairs of the tokens before it:
+    a running sum over what ``inverse`` says of each pair, no search."""
+    width = pairs.shape[0]
+    sorted_pairs, order = lax.sort_key_val(pairs, jnp.arange(width, dtype=jnp.int32))
+    at = inverse - lo
+    count = jnp.sum(((at >= 0) & (at < width)).reshape(-1, k), axis=1, dtype=jnp.int32)
+    return sorted_pairs // k, order, jnp.cumsum(count) - count, count > 0
+
+
+def _sum_by_token(rows, scale, by_token, k: int):
+    """``[tokens, features]`` float32: for every token the sum of its rows
+    among ``rows [n, features]``, each times its entry of ``scale [n, 1]``
+    (float32; None: as they are). The rows are those of ``n`` token-expert
+    pairs; ``by_token`` is ``_by_token``'s. At the width of the rows, not of
+    all the pairs: the rows are put in token order, where a token's rows
+    (``k`` at most) lie side by side; ``k`` shifted adds give each row the
+    sum from itself to its token's last; every token then reads its first
+    row's. No scatter: two gathers, of ``n`` and of ``tokens`` rows."""
+    token, order, first, any_row = by_token
+    n = rows.shape[0]
+    ordered = jnp.pad(rows[order], ((0, k - 1), (0, 0)))
+    weight = None if scale is None else jnp.pad(scale[order], ((0, k - 1), (0, 0)))
+    after = jnp.pad(token, (0, k - 1), constant_values=-1)
+
+    def shifted(d):
+        term = ordered[d:d + n].astype(jnp.float32)
+        if weight is not None:
+            term = term * weight[d:d + n]
+        return jnp.where((after[d:d + n] == token)[:, None], term, 0) if d else term
+
+    run = functools.reduce(jnp.add, map(shifted, range(k)))
+    return jnp.where(any_row[:, None], run[jnp.minimum(first, n - 1)], 0)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _unsort(y, order, inverse, lo: int):
-    """``_in_token_order`` of a range's results ``y``, ``order`` the range's
-    part of the sort's permutation. Both passes are gathers (a gather's own
-    transpose is a scatter-add, which the chip serialises)."""
-    return _in_token_order(y, inverse, lo)
+def _token_rows(x, pairs, by_token, k: int):
+    """Row ``pairs[i] // k`` of ``x`` for every i: the tokens' rows for a
+    range of their token-expert pairs. Backward: each token's sum of its
+    pairs' cotangents (``_sum_by_token``)."""
+    return x[pairs // k]
 
 
-_unsort.defvjp(
-    lambda y, order, inverse, lo: (_in_token_order(y, inverse, lo), order),
-    lambda lo, order, ct: (ct[order], None, None),
+_token_rows.defvjp(
+    lambda x, pairs, by_token, k: (x[pairs // k], by_token),
+    lambda k, by_token, ct: (
+        _sum_by_token(ct, None, by_token, k).astype(ct.dtype), None, None),
 )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _pair_rows(x, order, inverse, lo: int, k: int):
-    """Row ``order[i] // k`` of ``x`` for every i: the tokens' rows in the
-    order of a range of their sorted token-expert pairs (pair ``j`` belongs
-    to token ``j // k``; ``order`` is the range's part of the permutation,
-    from sorted row ``lo`` on). Backward: the pairs' cotangents back in
-    token order, summed over a token's ``k`` pairs; gathers both ways."""
-    return x[order // k]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _token_sums(rows, scale, pairs, by_token, k: int):
+    """``_sum_by_token`` of a range's rows, each times its pair's weight;
+    backward: every row its token's cotangent, a gather."""
+    return _sum_by_token(rows, scale, by_token, k)
 
 
-_pair_rows.defvjp(
-    lambda x, order, inverse, lo, k: (x[order // k], (inverse, x.shape)),
-    lambda lo, k, res, ct: (
-        _in_token_order(ct, res[0], lo).reshape(res[1][0], k, res[1][1])
-        .sum(axis=1, dtype=jnp.float32).astype(ct.dtype), None, None),
-)
+def _token_sums_bwd(k, residuals, ct):
+    rows, scale, pairs = residuals
+    ct = ct[pairs // k]
+    return ((ct * scale).astype(rows.dtype),
+            jnp.sum(ct * rows.astype(jnp.float32), axis=-1, keepdims=True), None, None)
+
+
+_token_sums.defvjp(
+    lambda rows, scale, pairs, by_token, k: (
+        _sum_by_token(rows, scale, by_token, k), (rows, scale, pairs)),
+    _token_sums_bwd)
 
 
 def _prefix_rows(pairs: int, held: int, experts: int) -> int:
@@ -311,25 +612,52 @@ def _prefix_rows(pairs: int, held: int, experts: int) -> int:
 
 
 def _range_ffn(bounds, x, weights, w1, w3, w2, order, inverse, sizes):
-    """What the sorted pair rows ``[lo, hi) = bounds`` add to the expert
-    layer's result, ``[tokens, hidden]`` float32: their tokens' rows of
-    ``x``, the three grouped products over the part of each expert's group
-    that lies in the range, and the weighted sum over each token's pairs
-    (a pair outside the range adds zero). ``sizes [held]`` are the groups,
-    so the held pairs are the sorted rows ``[0, sum(sizes))``."""
-    lo, hi = bounds
+    """What a range of the sorted pair rows adds to the expert layer's
+    result, ``[tokens, hidden]`` float32. ``bounds = (lo, width, own)``: the
+    rows ``[lo, lo + width)`` are computed and those from ``own`` on are the
+    range's (``own > lo`` where the last of several ranges of one width is
+    moved back to end with the rows; ``lo`` and ``own`` may be traced). For
+    them: their tokens' rows of ``x``, the three grouped products over the
+    part of each expert's group that lies in the range, each row times its
+    pair's weight, and every token's sum of its rows. All of it at the width
+    of the range: nothing here is as wide as all the pairs but one column of
+    weights. ``sizes [held]`` are the groups, so the held pairs are the
+    sorted rows ``[0, sum(sizes))``."""
+    lo, width, own = bounds
+    k = weights.shape[1]
     ends = jnp.cumsum(sizes)
-    groups = jnp.clip(ends, lo, hi) - jnp.clip(ends - sizes, lo, hi)
+    groups = jnp.clip(ends, lo, lo + width) - jnp.clip(ends - sizes, lo, lo + width)
     # rows past the last group are not computed: keep what they hold out of
     # both passes
-    held = (lo + jnp.arange(hi - lo) < ends[-1])[:, None]
-    rows = jnp.where(
-        held, _pair_rows(x, order[lo:hi], inverse, lo, weights.shape[1]), 0)
+    at = lo + jnp.arange(width)
+    held = ((at >= own) & (at < ends[-1]))[:, None]
+    pairs = lax.dynamic_slice_in_dim(order, lo, width)
+    by_token = _by_token(pairs, inverse, lo, k)
+    rows = jnp.where(held, _token_rows(x, pairs, by_token, k), 0)
     gate = nn.silu(lax.ragged_dot(rows, w1, groups))
     y = lax.ragged_dot(gate * lax.ragged_dot(rows, w3, groups), w2, groups)
-    y = _unsort(jnp.where(held, y, 0), order[lo:hi], inverse, lo)
-    y = y.reshape(*weights.shape, -1).astype(jnp.float32)
-    return jnp.sum(y * weights[..., None], axis=1)
+    scale = _pair_weights(weights.reshape(-1, 1), pairs, inverse, lo)
+    return _token_sums(jnp.where(held, y, 0), scale, pairs, by_token, k)
+
+
+def _over_the_rest(prefix, pairs, held_pairs, step, carry):
+    """``carry`` after ``step(bounds, carry)`` for every range of the sorted
+    pair rows past ``prefix`` that holds held pairs (there are
+    ``held_pairs > prefix`` of them, known on the device): ranges as wide as
+    the prefix (the rest itself where that is narrower), the last moved back
+    to end with the rows. One range: called once. More: a loop of as many
+    trips as the held pairs reach, so that a layer that holds a sixteenth of
+    its experts does not keep buffers for seven eighths of its rows."""
+    width = min(prefix, pairs - prefix)
+    most = -(-(pairs - prefix) // width)
+    if most == 1:
+        return step((prefix, width, prefix), carry)
+
+    def one(i, carry):
+        own = prefix + i * width
+        return step((jnp.minimum(own, pairs - width), width, own), carry)
+
+    return lax.fori_loop(0, (held_pairs - prefix + width - 1) // width, one, carry)
 
 
 def _add_overflow(prefix, out, operands):
@@ -339,21 +667,24 @@ def _add_overflow(prefix, out, operands):
     it the compiler moves what reads the result (a cast) into both
     branches, and the branch not taken is no longer free."""
     *_, order, _, sizes = operands
-    rest = (prefix, order.shape[0])
+    held_pairs = jnp.sum(sizes)
     return lax.optimization_barrier(lax.cond(
-        jnp.sum(sizes) > prefix,
-        lambda out, *operands: out + _range_ffn(rest, *operands),
+        held_pairs > prefix,
+        lambda out, *operands: _over_the_rest(
+            prefix, order.shape[0], held_pairs,
+            lambda bounds, out: out + _range_ffn(bounds, *operands), out),
         lambda out, *operands: out, out, *operands))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _two_ranges(prefix, x, weights, w1, w3, w2, order, inverse, sizes):
-    """The expert layer's result from its sorted pair rows in two ranges:
-    ``[0, prefix)`` always, the rest only in a step whose held pairs
-    overflow the prefix; every held pair is computed exactly once either
-    way. The backward is the prefix's own (``jax.vjp``, its residuals at
-    the prefix's width), then a second conditional that adds the other
-    range's gradients, whose forward it runs once more (the rare step
+    """The expert layer's result from its sorted pair rows in ranges:
+    ``[0, prefix)`` always, the rest (``_over_the_rest``: one range as wide
+    as the prefix or narrower, or a loop of them) only in a step whose held
+    pairs overflow the prefix; every held pair is computed exactly once
+    either way. The backward is the prefix's own (``jax.vjp``, its residuals
+    at the prefix's width), then a second conditional that adds the other
+    ranges' gradients, whose forward it runs once more (the rare step
     pays that; it is the step that cost the whole width before).
 
     Why both conditionals hand a running sum through (the result, then the
@@ -369,12 +700,12 @@ def _two_ranges(prefix, x, weights, w1, w3, w2, order, inverse, sizes):
     its own at all: ``lax.cond`` differentiated by JAX makes the branch
     taken write zeros for every residual of the other, 1.04 GB a layer."""
     operands = (x, weights, w1, w3, w2, order, inverse, sizes)
-    return _add_overflow(prefix, _range_ffn((0, prefix), *operands), operands)
+    return _add_overflow(prefix, _range_ffn((0, prefix, 0), *operands), operands)
 
 
 def _ranges_fwd(prefix, *operands):
     floats, ints = operands[:5], operands[5:]
-    out, pull = jax.vjp(lambda *f: _range_ffn((0, prefix), *f, *ints), *floats)
+    out, pull = jax.vjp(lambda *f: _range_ffn((0, prefix, 0), *f, *ints), *floats)
     return _add_overflow(prefix, out, operands), (pull, operands)
 
 
@@ -382,14 +713,17 @@ def _ranges_bwd(prefix, residuals, ct):
     pull, operands = residuals
     floats, ints = operands[:5], operands[5:]
     order, _, sizes = ints
-    rest = (prefix, order.shape[0])
+    held_pairs = jnp.sum(sizes)
 
     def add_overflow(grads, floats, ct):
-        more = jax.vjp(lambda *f: _range_ffn(rest, *f, *ints), *floats)[1](ct)
-        return jax.tree.map(jnp.add, grads, more)
+        def add(bounds, grads):
+            more = jax.vjp(lambda *f: _range_ffn(bounds, *f, *ints), *floats)[1](ct)
+            return jax.tree.map(jnp.add, grads, more)
+
+        return _over_the_rest(prefix, order.shape[0], held_pairs, add, grads)
 
     grads = lax.optimization_barrier(lax.cond(
-        jnp.sum(sizes) > prefix, add_overflow,
+        held_pairs > prefix, add_overflow,
         lambda grads, floats, ct: grads, pull(ct), floats, ct))
     return (*grads, None, None, None)
 
@@ -418,26 +752,33 @@ class _ExpertWeights(nn.Module):
 
 class ExpertFFN(nn.Module):
     """A share of an expert layer: routes every token over ALL ``experts``
-    (sigmoid scores; the top ``per_token`` chosen on score + ``expert_bias``;
+    (``scoring`` "sigmoid": sigmoid scores, or "softmax": a softmax over all
+    the experts; the top ``per_token`` chosen on score + ``expert_bias``;
     weights the scores themselves, normalised to sum 1 and scaled) and
     computes the part of the result that the ``held`` experts from ``first``
     on give. What the other experts would add is left out: on the chips
-    that share this layer it is their part of the sum.
+    that share this layer it is their part of the sum. ``shared_width``
+    not 0: a shared expert that every token takes, a SwiGLU of that width
+    times the sigmoid of a gate of its own (``x w_s``); every chip that
+    shares the layer computes it alike, and it is added once here.
 
     No token-expert pair on a held expert is ever dropped. The ``P = tokens
     x per_token`` pairs are sorted by expert, the held ones first, so the
     ``n`` held pairs are the sorted rows ``[0, n)``, and ``jax.lax.
     ragged_dot`` multiplies each group by its expert (on a TPU a
     grouped-matmul kernel that skips the rows past the last group). The
-    rows are computed in two ranges by one function (``_range_ffn``): the
-    prefix ``[0, C)`` always, and ``[C, P)`` only in a step whose own count
-    says held pairs lie there (``n > C``, decided on the device:
-    ``_ranges_ffn``). ``C = min(P, 2 P held / experts)`` (``_prefix_rows``),
-    twice the even share, so a chip whose experts draw up to twice their
-    share of the routing gathers, selects, multiplies and casts ``C`` rows
-    and not ``P``; a step in which every token picks ``per_token`` held
-    experts is still computed in full. A layer that holds half or all of
-    its experts has ``C == P``: one range, no conditional.
+    rows are computed in ranges by one function (``_range_ffn``, which
+    gathers, multiplies, weights and sums back by token at the width of its
+    range): the prefix ``[0, C)`` always, and the rows from ``C`` on only in
+    a step whose own count says held pairs lie there (``n > C``, decided on
+    the device: ``_ranges_ffn``), in ranges of ``C`` rows, as many as the
+    held pairs reach (LFM2's quarter share: one; a sixteenth of 512 experts:
+    up to seven, one loop). ``C = min(P, 2 P held / experts)``
+    (``_prefix_rows``), twice the even share, so a chip whose experts draw
+    up to twice their share of the routing works on ``C`` rows and not
+    ``P``; a step in which every token picks ``per_token`` held experts is
+    still computed in full. A layer that holds half or all of its experts
+    has ``C == P``: one range, no conditional.
 
     The router (scores, choice, weights) is float32: a near-tie in the
     top-k that fell otherwise in bfloat16 would move a whole token's
@@ -456,6 +797,8 @@ class ExpertFFN(nn.Module):
     scaling: float = 1.0
     expert_bias: bool = True
     dtype: Any = jnp.bfloat16
+    scoring: str = "sigmoid"
+    shared_width: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -465,7 +808,8 @@ class ExpertFFN(nn.Module):
             x = x.reshape(-1, self.hidden)
             tokens, k = x.shape[0], self.per_token
             router = _Kernel((self.hidden, self.experts), name="gate")()
-            scores = jax.nn.sigmoid(
+            score = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[self.scoring]
+            scores = score(
                 jnp.matmul(x, router, precision=lax.Precision.HIGHEST))
             choose_on = scores
             if self.expert_bias:
@@ -498,8 +842,16 @@ class ExpertFFN(nn.Module):
             if prefix < pairs:
                 out = _ranges_ffn(prefix, *operands)
             else:
-                out = _range_ffn((0, prefix), *operands)
-            return out.astype(self.dtype).reshape(shape)
+                out = _range_ffn((0, prefix, 0), *operands)
+            out = out.astype(self.dtype)
+            if self.shared_width:
+                with jax.named_scope("shared_expert"):
+                    gate = jax.nn.sigmoid(
+                        linear(1, self.dtype, "shared_expert_gate")(operands[0]))
+                    out = out + gate * SwiGLU(
+                        self.hidden, self.shared_width, self.dtype,
+                        name="shared_expert")(operands[0])
+            return out.reshape(shape)
 
 
 def step_counters(counted: dict) -> dict:

@@ -115,7 +115,7 @@ def _eval_step(spatial):
 # and the step's prologue, so that a change there which is meant to leave the
 # programs alone can show that it did. The two AmoebaNet hashes under False and
 # "cell" date from fc8bfe1 (before ``Trainer`` learned the token family);
-# "lfm2-cell" is PR 36's.
+# "lfm2-cell" is PR 37's.
 TRACED_AT_C0A7BC1 = {
     "amoebanet-False": (lambda: _image_step("amoebanet", False),
         "eb4431aae24c350019f855dfaac178d4cda883b9657eacc6eb69e7a5f24b0cb7"),
@@ -143,11 +143,16 @@ TRACED_AT_C0A7BC1 = {
         "07da054167a4e273e675c41edab3470747abe833ff223bb2df4e7618019d5ef9"),
     "resnet_sp2x2-False": (lambda: _image_step("resnet", False, spatial=True),
         "582955a93c82f69620d9339ee98fcab6e0697e852dd188397155f592c01fe753"),
-    # replaced by PR 36, which meant to alter this program and no other: the
-    # expert layer computes its sorted pair rows in two ranges and sows
-    # ``prefix_alone`` (c0a7bc1 traced 9510765a4ddc7ee2...)
+    # replaced by PR 37, which meant to alter the expert layer and nothing
+    # else of this program: the combine (and the row gather's backward) run at
+    # the width of a range's rows and not of all the pairs, and the rows past
+    # the prefix in ranges of the prefix's width (one range here, as before).
+    # LFM2's embedding, convolution, attention, dense SwiGLU and head trace to
+    # the byte-same jaxpr as at 4ea348d in float32 and bfloat16 (checked on a
+    # model of dense layers alone). PR 36 traced b56dc89de99b9a74..., c0a7bc1
+    # 9510765a4ddc7ee2...
     "lfm2-cell": (_token_step,
-        "b56dc89de99b9a7431447c1f8a4374db57103933ebde21adfa49ecdcb491d3a4"),
+        "3f717aa8fd99b95449d4dfd7e20790fbfa942cff9fd78c3b74cd57d53344af76"),
     "pipeline-gpipe": (lambda: _pipeline_step("gpipe"),
         "278d206dbf04f5ddd34d0b3bfb01274ea8b7e5d47f0c870c9caa6d9ee90b5b01"),
     "pipeline-1f1b": (lambda: _pipeline_step("1f1b"),

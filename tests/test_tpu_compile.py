@@ -311,3 +311,105 @@ def test_the_expert_layers_grouped_products_are_the_chips_ragged_dot(topo, cache
     # reads 2.59e9.
     assert not re.search(r"bf16\[8,(2048,1792|1792,2048)\]\S* broadcast\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2 * 818_508_288
+
+
+# -- Qwen3-Next's layers at full width (PR 37) --------------------------------
+
+
+def _layer_grad(layer, x, one_chip):
+    """``(compiled value, compiled gradient over parameters and input)`` of
+    ``sum(layer(x))`` for the described chip, and the shapes compiled on."""
+    variables = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype)))
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), (variables, x))
+
+    def value(v, x_):
+        return jnp.sum(layer.apply(v, x_).astype(jnp.float32))
+
+    return (jax.jit(value).lower(*shapes).compile(),
+            jax.jit(jax.grad(value, argnums=(0, 1))).lower(*shapes).compile())
+
+
+def _op_names(compiled):
+    return re.findall(r'op_name="([^"]*)"', compiled.as_text())
+
+
+def _assert_scope_in_both_passes(forward, gradient, scope):
+    """What the per-layer readers join the chip's trace with: the scope in
+    the name stack of forward instructions and of transposed ones."""
+    assert sum(scope in name for name in _op_names(forward)) > 3, scope
+    backward = [name for name in _op_names(gradient) if "transpose(" in name]
+    assert sum(scope in name for name in backward) > 3, scope
+
+
+def test_the_gated_delta_layer_compiles_at_full_width_under_its_scopes(topo, cache_off):
+    """Qwen3-Next-80B-A3B's Gated DeltaNet mixer (16 key / 32 value heads of
+    128, a convolution of 4 taps over 8,192 channels) on two sequences of
+    8,192 positions in bfloat16, forward and backward, for one described
+    chip: the chunked rule (128 chunks of 64, the chunks' float32 squares,
+    the scan that hands the state on) compiles, ``gated_delta`` and
+    ``gated_delta_rule`` reach the compiled text of both passes, and the
+    backward keeps the rule's inputs and not its chunk-by-chunk squares
+    (4.64 GiB of temporaries as compiled here; 7.92 before the rule and its
+    chunk terms were recomputed there)."""
+    from mpi4dl_tpu.ops.sequence import GatedDeltaNet
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    forward, gradient = _layer_grad(
+        GatedDeltaNet(2048, 16, 32, 128, 128, 4, 1e-6),
+        jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16), one_chip)
+    for scope in ("gated_delta", "gated_delta_rule"):
+        _assert_scope_in_both_passes(forward, gradient, scope)
+    assert " while(" in gradient.as_text()  # the state's hand-on is a loop, not 128 copies
+    assert gradient.memory_analysis().temp_size_in_bytes < 5.5 * 2**30
+
+
+def test_the_gated_attention_layer_at_head_dim_256_takes_the_blocked_path(
+        topo, cache_off, monkeypatch):
+    """Qwen3-Next's attention layer (2 key-value heads x 8 query heads of
+    256, rotary embedding on 64 dims, the output gate) at 2 x 8,192
+    positions with the kernels' gate steered to its TPU branch: head dim 256
+    is not the kernels' 64, so no fused kernel is in the compiled text and
+    the blocked plain path runs, one block of scores alive at a time, under
+    ``lfm2_attention`` in both passes."""
+    from mpi4dl_tpu.ops import attention_pallas
+    from mpi4dl_tpu.ops.sequence import Attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    layer = Attention(2048, 16, 2, 1e-6, 1e7, head_dim=256, rotary_dim=64,
+                      output_gate=True, zero_centred_norms=True)
+    forward, gradient = _layer_grad(
+        layer, jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16), one_chip)
+    _assert_scope_in_both_passes(forward, gradient, "lfm2_attention")
+    for name in (attention_pallas.FWD_NAME, attention_pallas.BWD_NAME):
+        assert name not in gradient.as_text()
+    assert gradient.memory_analysis().temp_size_in_bytes < 4.5 * 2**30
+
+
+def test_the_share_of_the_512_expert_layer_keeps_the_width_of_its_prefix(topo, cache_off):
+    """32 of 512 experts, 10 a token, widths 2048 / 512, with the shared
+    expert, on 16,384 tokens: 163,840 sorted pair rows of which the prefix of
+    20,480 always runs. Every grouped product is over 20,480 rows (the rows
+    past the prefix run in a loop of ranges of that width, compiled once);
+    nothing but a column of weights is as wide as all the pairs, so the
+    layer's backward holds 1.31 GiB of temporaries as compiled here (3.23
+    with one range for all the rest, most of it in a branch no step takes);
+    ``shared_expert`` and ``lfm2_moe`` reach both passes."""
+    from mpi4dl_tpu.ops.sequence import ExpertFFN
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    layer = ExpertFFN(2048, 512, 512, 32, 0, 10, True, expert_bias=False,
+                      scoring="softmax", shared_width=512)
+    forward, gradient = _layer_grad(
+        layer, jax.ShapeDtypeStruct((2, 8192, 2048), jnp.float32), one_chip)
+    for scope in ("lfm2_moe", "shared_expert"):
+        _assert_scope_in_both_passes(forward, gradient, scope)
+    text = gradient.as_text()
+    products = [line for line in text.splitlines()
+                if "custom-call(" in line and "%ragged-dot-none" in line.split(" = ")[0]]
+    rows = {re.search(r"= \w+\[(\d+),", line).group(1) for line in products}
+    assert products and rows == {"20480", "32"}, rows  # 32: the weight gradients
+    assert " while(" in text and not re.search(r"\w+\[163840,(2048|512)\]", text)
+    assert gradient.memory_analysis().temp_size_in_bytes < 1.6 * 2**30
