@@ -14,8 +14,12 @@ floats are 8.6 GB): on a TPU, at the shapes ``ops/attention_pallas.py``
 takes, ``causal_attention`` is that module's fused kernels, which keep a
 block's scores in VMEM; everywhere else (the CPU, a vmapped trace, a length
 that is not whole blocks, another head dim) it is plain JAX, a block of
-query rows at a time, each block recomputed in the backward pass. The rest
-is plain JAX everywhere.
+query rows at a time, each block recomputed in the backward pass. The gated
+delta rule goes the same way: on a TPU, at the shapes
+``ops/delta_rule_pallas.py`` takes, ``gated_delta_rule`` is that module's
+kernels, which keep a chunk's squares and the carried state in VMEM;
+everywhere else it is ``_chunked_rule``, plain JAX. The rest is plain JAX
+everywhere.
 
 Activations are ``[batch, positions, features]``. Each module computes in
 its ``dtype`` (bfloat16 on the chip) with float32 parameters, float32
@@ -39,7 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mpi4dl_tpu.ops import attention_pallas
+from mpi4dl_tpu.ops import attention_pallas, delta_rule_pallas
 
 COUNTERS = "counters"  # the flax collection an expert layer sows its counts into
 RULE_CHUNK = 64        # positions the gated delta rule takes as one triangular system
@@ -257,15 +261,22 @@ def gated_delta_rule(q, k, v, g, beta):
     corrected values are ``(I + A)^-1 (beta v - (beta exp(G) k) S_0)`` (the
     WY form: ``T = (I + A)^-1`` once, then ``u = T beta v`` and
     ``w = T beta exp(G) k``), so the chunk needs the state ``S_0`` it starts
-    from and no other (``_chunk_terms``, all chunks at once). Between chunks
-    that state is handed on by a ``lax.scan`` whose step is two products;
-    the outputs are again two products over all the chunks at once.
+    from and no other. Where ``delta_rule_pallas.dispatchable`` says so (TPU
+    backend, not under ``vmap``, bfloat16, key and value dims of whole
+    lanes, a length of whole chunks) that module's kernels compute it, both
+    passes, a chunk's squares and the carried state in VMEM; else
+    ``_chunked_rule``, plain JAX and the same arithmetic: the terms of all
+    chunks at once (``_chunk_terms``), between chunks the state handed on by
+    a ``lax.scan`` whose step is two products, the outputs again two
+    products over all the chunks at once.
 
-    The backward pass is JAX's own through the scan, so it keeps one state
-    a chunk (not one a position); the rule as a whole and the chunks' terms
-    inside it are each recomputed there (``jax.checkpoint``), so that a
-    layer's backward holds the rule's five inputs while its other parts are
-    differentiated, and the chunks' squares only while they are.
+    The plain path's backward pass is JAX's own through the scan, so it
+    keeps one state a chunk (not one a position); the rule as a whole and
+    the chunks' terms inside it are each recomputed there
+    (``jax.checkpoint``), so that a layer's backward holds the rule's five
+    inputs while its other parts are differentiated, and the chunks' squares
+    only while they are. The kernels' backward is their own: a reverse sweep
+    that keeps every chunk's start state and its system's inverse.
 
     Matrix products take operands in ``v``'s dtype and accumulate in
     float32; ``G``, the decays, ``A``, its inverse and the carried state are
@@ -274,6 +285,8 @@ def gated_delta_rule(q, k, v, g, beta):
     chunks is padded at the end (``k, v, beta, g`` zero there change no
     state) and cut again."""
     with jax.named_scope("gated_delta_rule"):
+        if delta_rule_pallas.dispatchable(q, k, v, g, beta, RULE_CHUNK):
+            return delta_rule_pallas.rule(q, k, v, g, beta, RULE_CHUNK)
         return _chunked_rule(q, k, v, g, beta)
 
 

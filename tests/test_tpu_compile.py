@@ -343,26 +343,71 @@ def _assert_scope_in_both_passes(forward, gradient, scope):
     assert sum(scope in name for name in backward) > 3, scope
 
 
-def test_the_gated_delta_layer_compiles_at_full_width_under_its_scopes(topo, cache_off):
+def _rule_kernels(compiled):
+    """``{kernel name: [op_name of each of its custom calls]}`` for the gated
+    delta rule's kernels in a compiled program's text."""
+    from mpi4dl_tpu.ops import delta_rule_pallas
+
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom-call(" in line and "mpi4dl_delta_rule" in line.split(" = ")[0]]
+    return {name: [re.search(r'op_name="([^"]*)"', line).group(1)
+                   for line in calls if name in line.split(" = ")[0]]
+            for name in (delta_rule_pallas.FWD_NAME, delta_rule_pallas.BWD_NAME)}
+
+
+def test_the_gated_delta_layer_compiles_at_full_width_under_its_scopes(
+        topo, cache_off, monkeypatch):
     """Qwen3-Next-80B-A3B's Gated DeltaNet mixer (16 key / 32 value heads of
     128, a convolution of 4 taps over 8,192 channels) on two sequences of
     8,192 positions in bfloat16, forward and backward, for one described
-    chip: the chunked rule (128 chunks of 64, the chunks' float32 squares,
-    the scan that hands the state on) compiles, ``gated_delta`` and
-    ``gated_delta_rule`` reach the compiled text of both passes, and the
-    backward keeps the rule's inputs and not its chunk-by-chunk squares
-    (4.64 GiB of temporaries as compiled here; 7.92 before the rule and its
-    chunk terms were recomputed there)."""
+    chip, the rule's gate steered to its TPU branch: the cell's shape takes
+    the kernels of ``ops/delta_rule_pallas.py`` (128 chunks of 64, a chunk's
+    float32 squares and the carried state in VMEM), ``gated_delta`` and
+    ``gated_delta_rule`` reach the compiled text of both passes, every
+    ``mpi4dl_delta_rule*`` custom call stands under ``gated_delta_rule``
+    (the scope the benchmark's ``delta_rule_ms`` reads), the state's hand-on
+    is no ``while`` any more, and the backward keeps the rule's inputs, the
+    chunks' start states and the systems' inverses: 3.14 GiB of temporaries
+    as compiled here (4.64 on the plain path, which recomputes the rule and
+    its chunk terms; 7.92 before it did)."""
     from mpi4dl_tpu.ops.sequence import GatedDeltaNet
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     one_chip = SingleDeviceSharding(topo.devices[0])
     forward, gradient = _layer_grad(
         GatedDeltaNet(2048, 16, 32, 128, 128, 4, 1e-6),
         jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16), one_chip)
     for scope in ("gated_delta", "gated_delta_rule"):
         _assert_scope_in_both_passes(forward, gradient, scope)
-    assert " while(" in gradient.as_text()  # the state's hand-on is a loop, not 128 copies
-    assert gradient.memory_analysis().temp_size_in_bytes < 5.5 * 2**30
+    found = _rule_kernels(forward)
+    assert len(found["mpi4dl_delta_rule_fwd"]) == 1 and not found["mpi4dl_delta_rule_bwd"]
+    found = {**found, **{k: v + found[k] for k, v in _rule_kernels(gradient).items()}}
+    assert len(found["mpi4dl_delta_rule_fwd"]) == 2 and len(found["mpi4dl_delta_rule_bwd"]) == 1
+    assert all("gated_delta_rule" in name for names in found.values() for name in names), found
+    assert "transpose(" in found["mpi4dl_delta_rule_bwd"][0]
+    assert " while(" not in gradient.as_text()  # the hand-on is the kernels' own
+    assert gradient.memory_analysis().temp_size_in_bytes < 3.5 * 2**30
+
+
+def test_the_tiny_cuts_gated_delta_layer_takes_the_plain_path(topo, cache_off, monkeypatch):
+    """The tiny cut's DeltaNet mixer (``chipbench/tests/tiny/qwen3_next_80b_
+    a3b_share16.json``: hidden 64, 2 key / 4 value heads of 16, 160
+    positions) with the gate steered to its TPU branch: key dim 16 is not
+    whole lanes and 160 positions are not whole chunks, so no
+    ``mpi4dl_delta_rule*`` name is in the compiled text of either pass and
+    the plain chunked rule runs (its hand-on a ``while``) under the same
+    scopes."""
+    from mpi4dl_tpu.ops.sequence import GatedDeltaNet
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    forward, gradient = _layer_grad(
+        GatedDeltaNet(64, 2, 4, 16, 16, 4, 1e-6),
+        jax.ShapeDtypeStruct((2, 160, 64), jnp.bfloat16), one_chip)
+    for scope in ("gated_delta", "gated_delta_rule"):
+        _assert_scope_in_both_passes(forward, gradient, scope)
+    for compiled in (forward, gradient):
+        assert "mpi4dl_delta_rule" not in compiled.as_text()
 
 
 def test_the_gated_attention_layer_at_head_dim_256_takes_the_blocked_path(
