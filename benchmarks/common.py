@@ -140,7 +140,8 @@ def token_model_args(argv, model: "dict | None" = None):
         "--model-config",
         help="JSON file of the model's published config.json keys; a chip's "
         "share of a deployment counts what it holds and states the "
-        "published values under `cut` (mpi4dl_tpu/models/lfm2.py, qwen3_next.py)")
+        "published values under `cut` (mpi4dl_tpu/models/lfm2.py, qwen3_next.py, "
+        "nemotron_h.py)")
     parser.add_argument(
         "--sequence-length", type=int, default=8192,
         help="Tokens in a sequence (one document a sequence)")
@@ -180,6 +181,13 @@ def build_qwen3_next(args, cfg, spatial_cells=0):
     return _token_cells(args, qwen3_next, spatial_cells)
 
 
+def build_nemotron_h(args, cfg, spatial_cells=0):
+    """(cells, float32 twin) of the Nemotron-H model ``args.model`` describes."""
+    from mpi4dl_tpu.models.nemotron_h import nemotron_h
+
+    return _token_cells(args, nemotron_h, spatial_cells)
+
+
 def _token_trainer(config: dict, batch_size: int, build_model):
     """``(trainer, cfg)`` of a benchmark configuration of a token model (its
     file as a dict: the model's keys and ``entry_point.argv``), built as its
@@ -202,6 +210,12 @@ def qwen3_next_trainer(config: dict, batch_size: int):
     """As ``benchmarks/layer_parallelism/benchmark_qwen3_next_lp.py`` builds
     it (``entry_point.build_trainer``)."""
     return _token_trainer(config, batch_size, build_qwen3_next)
+
+
+def nemotron_h_trainer(config: dict, batch_size: int):
+    """As ``benchmarks/layer_parallelism/benchmark_nemotron_h_lp.py`` builds
+    it (``entry_point.build_trainer``)."""
+    return _token_trainer(config, batch_size, build_nemotron_h)
 
 
 def token_input_stream(cfg, traffic: dict, seed: int):

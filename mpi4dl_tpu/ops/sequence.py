@@ -2,9 +2,10 @@
 
 ``ops/layers.py`` holds the image classifiers' operators (NHWC convs, pools,
 BatchNorm); this file holds RMSNorm, the rotary embedding, a causal
-depthwise conv1d, causal grouped-query attention, the Gated DeltaNet mixer
-(a per-head state carried along the sequence, computed a chunk of positions
-at a time) and an expert layer that holds a share of the experts. The
+depthwise conv1d, causal grouped-query attention, the Gated DeltaNet and
+Mamba-2 mixers (each a per-head state carried along the sequence, computed a
+chunk of positions at a time) and an expert layer that holds a share of the
+experts. The
 grouped matrix products are
 ``jax.lax.ragged_dot`` over the token-expert pairs sorted by expert, at the
 width of a prefix of the sorted rows that follows from the share held; the
@@ -18,8 +19,8 @@ query rows at a time, each block recomputed in the backward pass. The gated
 delta rule goes the same way: on a TPU, at the shapes
 ``ops/delta_rule_pallas.py`` takes, ``gated_delta_rule`` is that module's
 kernels, which keep a chunk's squares and the carried state in VMEM;
-everywhere else it is ``_chunked_rule``, plain JAX. The rest is plain JAX
-everywhere.
+everywhere else it is ``_chunked_rule``, plain JAX. The rest, Mamba-2's
+chunked scan (``ssd_scan``) included, is plain JAX everywhere.
 
 Activations are ``[batch, positions, features]``. Each module computes in
 its ``dtype`` (bfloat16 on the chip) with float32 parameters, float32
@@ -28,8 +29,9 @@ normalisation statistics and a float32 router.
 The device trace finds the mechanisms by ``jax.named_scope``:
 ``lfm2_moe`` (router, top-k, sort, grouped products, combine; inside it
 ``shared_expert`` where the layer has one), ``lfm2_attention``,
-``lfm2_shortconv`` and ``gated_delta`` (inside it ``gated_delta_rule``, the
-chunked rule without the projections). The shared modules keep the names
+``lfm2_shortconv``, ``gated_delta`` (inside it ``gated_delta_rule``, the
+chunked rule without the projections) and ``mamba2`` (inside it ``ssd_scan``,
+the chunked scan alone). The shared modules keep the names
 their first model gave them: the benchmark's readers find them by name.
 """
 
@@ -87,12 +89,12 @@ class Embedding(nn.Module):
         return jnp.take(table, ids, axis=0)
 
 
-def rope(x, theta: float, rotary: int = 0):
+def rope(x, theta: float, rotary: "int | None" = None):
     """Rotary embedding of ``x [batch, positions, heads, dim]``, half-split
     pairing, positions 0..S-1 in every row (one document a sequence);
     angles and rotation in float32. ``rotary``: the leading dims that turn
-    (a partial rotary factor; the rest pass as they are), 0 for all."""
-    rotary = rotary or x.shape[-1]
+    (a partial rotary factor; the rest pass as they are), None for all."""
+    rotary = x.shape[-1] if rotary is None else rotary
     half = rotary // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq
@@ -349,6 +351,167 @@ class GatedDeltaNet(nn.Module):
                 out.astype(self.dtype).reshape(batch, length, values))
 
 
+# -- Mamba-2 -----------------------------------------------------------------
+
+
+@functools.partial(jax.checkpoint, static_argnums=(4,))
+def _chunked_scan(x, g, b, c, chunk: int):
+    """``ssd_scan`` of one sequence: ``x [S, G, R, P]``, ``g [S, G, R]``,
+    ``b, c [S, G, N]``."""
+    length, dtype = x.shape[0], x.dtype
+    pad = -length % chunk
+    if pad:
+        x, g, b, c = (jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+                      for a in (x, g, b, c))
+    chunks = (length + pad) // chunk
+    x, g, b, c = (a.reshape(chunks, chunk, *a.shape[1:]) for a in (x, g, b, c))
+    f32 = dict(preferred_element_type=jnp.float32)
+    total = jnp.cumsum(g, axis=1)                              # a [n c g r]
+    at = jnp.arange(chunk)
+    gap = total[:, :, None] - total[:, None]                   # [n i j g r]
+    decay = jnp.exp(jnp.where(
+        (at[:, None] >= at[None, :])[:, :, None, None], gap, -jnp.inf))
+    cb = jnp.einsum("nigs,njgs->nijg", c, b, **f32)
+    within = (cb[..., None] * decay).astype(dtype)             # (C B^T) * L
+    out = jnp.einsum("nijgr,njgrp->nigrp", within, x, **f32)
+
+    to_end = jnp.exp(total[:, -1:] - total)                    # exp(a_end - a_j)
+    own = jnp.einsum("ncgrp,ncgs->ngrps", (x * to_end[..., None]).astype(dtype), b, **f32)
+    end = jnp.exp(total[:, -1])[..., None, None]               # [n g r 1 1]
+
+    def hand_on(state, chunk_):
+        own_n, end_n = chunk_
+        return state * end_n + own_n, state
+
+    _, starts = lax.scan(hand_on, jnp.zeros(own.shape[1:], jnp.float32), (own, end))
+    out = out + jnp.exp(total)[..., None] * jnp.einsum(
+        "ncgs,ngrps->ncgrp", c, starts.astype(dtype), **f32)
+    return out.reshape(chunks * chunk, *out.shape[2:])[:length].astype(dtype)
+
+
+def ssd_scan(x, g, b, c, chunk: int):
+    """Mamba-2's recurrence over a sequence, a chunk of ``chunk`` positions
+    at a time (the "state-space dual" form).
+
+    Per head, with a state ``S [P, N]`` that is zero before position 0:
+    ``S_t = exp(g_t) S_{t-1} + x_t B_t^T; y_t = S_t C_t``. ``x [B, S, G, R,
+    P]`` (the layer's ``dt_t x_t``: ``R`` heads share a group's ``B, C``),
+    ``g [B, S, G, R]`` float32 (``dt_t A <= 0``, the log of the decay),
+    ``b, c [B, S, G, N]`` -> ``[B, S, G, R, P]`` in ``x``'s dtype. A scalar
+    decay a head and position and no correction of the state by what it
+    already holds: not the gated delta rule, and no triangular system.
+
+    With ``a`` the running sum of ``g`` inside a chunk: a chunk's own
+    positions give ``((C B^T) * L) x`` with ``L[i, j] = exp(a_i - a_j)`` for
+    ``i >= j`` (every chunk at once, one ``[chunk, chunk]`` square a chunk
+    and head); the chunk's own state is ``sum_j exp(a_end - a_j) x_j
+    B_j^T``; between chunks ``S_c = exp(a_end) S_{c-1} + own`` is handed on
+    by a ``lax.scan`` whose step is one multiply-add of the state; the
+    state a chunk starts from adds ``exp(a_i) S_{c-1} C_i``.
+
+    Plain JAX on every backend, the sequences of the batch one after the
+    other (nothing here mixes them), each under ``jax.checkpoint``: a
+    layer's backward pass holds the scan's four inputs while the layer's
+    other parts are differentiated, and one sequence's squares and states
+    (at 8,192 positions, 64 heads and chunks of 128 a float32 copy of the
+    squares is 0.27 GB, of the states 0.13) only while that sequence's scan
+    is; JAX's own backward through the ``lax.scan`` keeps one state a chunk.
+
+    Matrix products take operands in ``x``'s dtype and accumulate in
+    float32; ``a``, every decay and the carried state are float32. Every
+    exponent is of a difference ``<= 0``: a strong decay underflows to the 0
+    it is, nothing overflows. A length that is not whole chunks is padded at
+    the end (``x, b, g`` zero there change no state) and cut again."""
+    with jax.named_scope("ssd_scan"):
+        return lax.map(lambda row: _chunked_scan(*row, chunk), (x, g, b, c))
+
+
+def _log_uniform_inverse_softplus(low: float, high: float, floor: float):
+    """Mamba-2's ``dt_bias``: ``dt`` log-uniform over ``[low, high]``, not
+    under ``floor``, through the inverse of the softplus."""
+    def init(key, shape, dtype=jnp.float32):
+        dt = jnp.exp(jax.random.uniform(
+            key, shape, dtype, jnp.log(low), jnp.log(high)))
+        dt = jnp.maximum(dt, floor)
+        return dt + jnp.log(-jnp.expm1(-dt))
+
+    return init
+
+
+@functools.partial(jax.checkpoint, static_argnums=(5,))
+def _gated_group_norm(y, u, z, skip, scale, eps: float):
+    """What follows Mamba-2's scan: ``y + D u`` (``y, u [B, S, G, R, P]``,
+    ``skip [G R]``), times ``silu(z)`` (``z [B, S, G R P]``), an RMSNorm over
+    each group's ``R P`` channels and the learned ``scale``; float32 inside,
+    the inputs' dtype out. Recomputed in the backward pass: a layer keeps its
+    three inputs in their dtype and not the float32 chain between them."""
+    batch, length, groups, per, _ = y.shape
+    y = y.astype(jnp.float32) + skip.reshape(groups, per, 1) * u.astype(jnp.float32)
+    y = y.reshape(batch, length, groups, -1) * nn.silu(
+        z.reshape(batch, length, groups, -1).astype(jnp.float32))
+    y = y * lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + eps)
+    return (y.reshape(batch, length, -1) * scale).astype(z.dtype)
+
+
+class Mamba2(nn.Module):
+    """Nemotron-H's Mamba-2 mixer: ``z, xBC, dt = split(x W_in)`` (widths
+    ``heads x head_dim | heads x head_dim + 2 x groups x state | heads``);
+    ``x, B, C = split(silu(conv(xBC) + b))`` with a causal depthwise
+    convolution; ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)`` a
+    head, float32; the recurrence ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t
+    B_t^T``, ``y_t = S_t C_t + D x_t`` (``ssd_scan``; head ``h`` reads group
+    ``h // (heads / groups)``'s ``B, C``); then ``RMSNorm(y * silu(z)) * w``
+    with the statistics over each group's channels (the gate before the
+    norm) and ``out_proj``. No projection has a bias. ``dt``'s columns of
+    ``W_in`` are multiplied apart from the rest so that they accumulate into
+    float32 and the decays never pass through ``dtype``."""
+
+    hidden: int
+    heads: int
+    head_dim: int
+    groups: int
+    state: int
+    taps: int
+    chunk: int
+    eps: float
+    dt_range: tuple = (0.001, 0.1, 1e-4)   # time_step_min, _max, _floor
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        with jax.named_scope("mamba2"):
+            batch, length, _ = x.shape
+            inner, per = self.heads * self.head_dim, self.heads // self.groups
+            mixed = inner + 2 * self.groups * self.state
+            x = x.astype(self.dtype)
+            w_in = _Kernel((self.hidden, inner + mixed + self.heads), name="in_proj")()
+            w_in = w_in.astype(self.dtype)
+            z, xbc = jnp.split(jnp.matmul(x, w_in[:, :inner + mixed]), [inner], axis=-1)
+            dt = jnp.matmul(x, w_in[:, inner + mixed:], preferred_element_type=jnp.float32)
+            kernel = _Kernel((self.taps, mixed), name="conv")()
+            bias = self.param("conv_bias", nn.initializers.zeros, (mixed,))
+            xbc = nn.silu(causal_depthwise_conv1d(xbc, kernel.astype(self.dtype))
+                          + bias.astype(self.dtype))
+            u, b, c = jnp.split(xbc, [inner, inner + self.groups * self.state], axis=-1)
+
+            by_head = (batch, length, self.groups, per)
+            a_log = self.param(
+                "A_log", lambda key, shape: jnp.log(
+                    jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)), (self.heads,))
+            dt_bias = self.param(
+                "dt_bias", _log_uniform_inverse_softplus(*self.dt_range), (self.heads,))
+            skip = self.param("D", nn.initializers.ones, (self.heads,))
+            scale = self.param("norm_scale", nn.initializers.ones, (inner,))
+            dt = jax.nn.softplus(dt + dt_bias).reshape(by_head)
+            g = dt * -jnp.exp(a_log).reshape(self.groups, per)
+            u = u.reshape(*by_head, self.head_dim)
+            by_group = (batch, length, self.groups, self.state)
+            y = ssd_scan((u * dt[..., None]).astype(self.dtype), g,
+                         b.reshape(by_group), c.reshape(by_group), self.chunk)
+            y = _gated_group_norm(y, u, z, skip, scale, self.eps)
+            return linear(self.hidden, self.dtype, "out_proj")(y)
+
+
 # -- attention ---------------------------------------------------------------
 
 
@@ -453,9 +616,11 @@ class Attention(nn.Module):
     of q and of k (learned scale) before the rotary embedding. From the
     model's configuration: ``head_dim`` (0: ``hidden // heads``),
     ``rotary_dim`` (the leading dims of a head that the rotary embedding
-    turns, 0: all), ``output_gate`` (``q_proj`` is twice as wide, each
-    head's second half a gate: the attention's output times its sigmoid,
-    before ``out_proj``) and ``zero_centred_norms`` (``RMSNorm``)."""
+    turns; None: all; 0: none, a model without a positional embedding),
+    ``qk_norm`` (False: q and k as projected, no norm and no scale of
+    theirs), ``output_gate`` (``q_proj`` is twice as wide, each head's
+    second half a gate: the attention's output times its sigmoid, before
+    ``out_proj``) and ``zero_centred_norms`` (``RMSNorm``)."""
 
     hidden: int
     heads: int
@@ -465,9 +630,10 @@ class Attention(nn.Module):
     block: int = 512
     dtype: Any = jnp.bfloat16
     head_dim: int = 0
-    rotary_dim: int = 0
+    rotary_dim: "int | None" = None
     output_gate: bool = False
     zero_centred_norms: bool = False
+    qk_norm: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -485,9 +651,13 @@ class Attention(nn.Module):
                 q = q.reshape(batch, length, self.heads, d)
             k = k.reshape(batch, length, self.kv_heads, d)
             v = v.reshape(batch, length, self.kv_heads, d)
-            norm = functools.partial(RMSNorm, self.eps, self.zero_centred_norms)
-            q = rope(norm(name="q_layernorm")(q), self.rope_theta, self.rotary_dim)
-            k = rope(norm(name="k_layernorm")(k), self.rope_theta, self.rotary_dim)
+
+            def placed(t, name):  # a head's norm, then its rotation
+                if self.qk_norm:
+                    t = RMSNorm(self.eps, self.zero_centred_norms, name=name)(t)
+                return t if self.rotary_dim == 0 else rope(t, self.rope_theta, self.rotary_dim)
+
+            q, k = placed(q, "q_layernorm"), placed(k, "k_layernorm")
             q = q.astype(self.dtype).reshape(
                 batch, length, self.kv_heads, self.heads // self.kv_heads, d)
             out = causal_attention(q, k.astype(self.dtype), v, self.block)
@@ -513,6 +683,19 @@ class SwiGLU(nn.Module):
         gate = nn.silu(linear(self.width, self.dtype, "w1")(x))
         return linear(self.hidden, self.dtype, "w2")(
             gate * linear(self.width, self.dtype, "w3")(x))
+
+
+class SquaredReLU(nn.Module):
+    """``w2(relu(w1 x)^2)``: Nemotron-H's feed-forward (``relu2``), no gate."""
+
+    hidden: int
+    width: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        up = nn.relu(linear(self.width, self.dtype, "w1")(x.astype(self.dtype)))
+        return linear(self.hidden, self.dtype, "w2")(jnp.square(up))
 
 
 def _in_token_order(a, inverse, lo: int):
@@ -624,16 +807,31 @@ def _prefix_rows(pairs: int, held: int, experts: int) -> int:
     return min(pairs, 2 * pairs * held // experts)
 
 
-def _range_ffn(bounds, x, weights, w1, w3, w2, order, inverse, sizes):
+def _grouped_ffn(rows, experts, groups):
+    """Every row through its group's expert. The arrays an expert has say
+    which feed-forward it is: three (``w1, w3, w2``) the gated SiLU,
+    ``(silu(x w1) * x w3) w2``; two (``w1, w2``) the plain squared ReLU,
+    ``relu(x w1)^2 w2``."""
+    if len(experts) == 3:
+        w1, w3, w2 = experts
+        gate = nn.silu(lax.ragged_dot(rows, w1, groups))
+        return lax.ragged_dot(gate * lax.ragged_dot(rows, w3, groups), w2, groups)
+    w1, w2 = experts
+    return lax.ragged_dot(
+        jnp.square(nn.relu(lax.ragged_dot(rows, w1, groups))), w2, groups)
+
+
+def _range_ffn(bounds, x, weights, experts, order, inverse, sizes):
     """What a range of the sorted pair rows adds to the expert layer's
     result, ``[tokens, hidden]`` float32. ``bounds = (lo, width, own)``: the
     rows ``[lo, lo + width)`` are computed and those from ``own`` on are the
     range's (``own > lo`` where the last of several ranges of one width is
     moved back to end with the rows; ``lo`` and ``own`` may be traced). For
-    them: their tokens' rows of ``x``, the three grouped products over the
-    part of each expert's group that lies in the range, each row times its
-    pair's weight, and every token's sum of its rows. All of it at the width
-    of the range: nothing here is as wide as all the pairs but one column of
+    them: their tokens' rows of ``x``, the grouped products (``_grouped_ffn``
+    over ``experts``, the held experts' stacked arrays) over the part of
+    each expert's group that lies in the range, each row times its pair's
+    weight, and every token's sum of its rows. All of it at the width of
+    the range: nothing here is as wide as all the pairs but one column of
     weights. ``sizes [held]`` are the groups, so the held pairs are the
     sorted rows ``[0, sum(sizes))``."""
     lo, width, own = bounds
@@ -647,8 +845,7 @@ def _range_ffn(bounds, x, weights, w1, w3, w2, order, inverse, sizes):
     pairs = lax.dynamic_slice_in_dim(order, lo, width)
     by_token = _by_token(pairs, inverse, lo, k)
     rows = jnp.where(held, _token_rows(x, pairs, by_token, k), 0)
-    gate = nn.silu(lax.ragged_dot(rows, w1, groups))
-    y = lax.ragged_dot(gate * lax.ragged_dot(rows, w3, groups), w2, groups)
+    y = _grouped_ffn(rows, experts, groups)
     scale = _pair_weights(weights.reshape(-1, 1), pairs, inverse, lo)
     return _token_sums(jnp.where(held, y, 0), scale, pairs, by_token, k)
 
@@ -690,7 +887,7 @@ def _add_overflow(prefix, out, operands):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _two_ranges(prefix, x, weights, w1, w3, w2, order, inverse, sizes):
+def _two_ranges(prefix, x, weights, experts, order, inverse, sizes):
     """The expert layer's result from its sorted pair rows in ranges:
     ``[0, prefix)`` always, the rest (``_over_the_rest``: one range as wide
     as the prefix or narrower, or a loop of them) only in a step whose held
@@ -701,7 +898,7 @@ def _two_ranges(prefix, x, weights, w1, w3, w2, order, inverse, sizes):
     pays that; it is the step that cost the whole width before).
 
     Why both conditionals hand a running sum through (the result, then the
-    five gradients) where the plain shape would be ``prefix + cond(rest,
+    gradients) where the plain shape would be ``prefix + cond(rest,
     zeros)``: compiled for a v5e chip at LFM2-8B-A1B's widths, the branch
     not taken is then its bare parameter, no copy and nothing written. With
     zeros it writes 67 MB of result and 210 MB of gradients a layer and
@@ -712,19 +909,19 @@ def _two_ranges(prefix, x, weights, w1, w3, w2, order, inverse, sizes):
     then wrote them out in float32 (14 ms a step). And why a backward of
     its own at all: ``lax.cond`` differentiated by JAX makes the branch
     taken write zeros for every residual of the other, 1.04 GB a layer."""
-    operands = (x, weights, w1, w3, w2, order, inverse, sizes)
+    operands = (x, weights, experts, order, inverse, sizes)
     return _add_overflow(prefix, _range_ffn((0, prefix, 0), *operands), operands)
 
 
 def _ranges_fwd(prefix, *operands):
-    floats, ints = operands[:5], operands[5:]
+    floats, ints = operands[:3], operands[3:]
     out, pull = jax.vjp(lambda *f: _range_ffn((0, prefix, 0), *f, *ints), *floats)
     return _add_overflow(prefix, out, operands), (pull, operands)
 
 
 def _ranges_bwd(prefix, residuals, ct):
     pull, operands = residuals
-    floats, ints = operands[:5], operands[5:]
+    floats, ints = operands[:3], operands[3:]
     order, _, sizes = ints
     held_pairs = jnp.sum(sizes)
 
@@ -748,19 +945,44 @@ _two_ranges.defvjp(_ranges_fwd, _ranges_bwd)
 _ranges_ffn = jax.jit(_two_ranges, static_argnums=0)
 
 
+def _whole_tiles(experts, tile: int = 256):
+    """The held experts' arrays with the experts' width padded by zeros to
+    whole ``tile``s: columns of ``w1`` (and ``w3``), rows of ``w2``, which
+    add nothing to the result (``relu(0)^2`` and ``silu(0) * 0`` are 0) and
+    whose gradients the padding's own backward drops. The chip's grouped
+    products run on whole tiles of 256 columns: at 8 experts x 768 rows and
+    hidden 2688 a width of 1856 (Nemotron-H's, 7.25 tiles) reads 4.52 ms
+    forward and 14.55 gradient, 1920 the same, 2048 reads 1.89 and 6.91, and
+    1792 (LFM2's, 7 tiles) 2.45 and 8.55 (my chip run, PR 39). Widths of
+    whole tiles pass as they are, and so do widths under one tile (the CPU
+    tests' sizes, where the padding would be most of the work)."""
+    width = experts[0].shape[-1]
+    pad = -width % tile
+    if not pad or width < tile:
+        return experts
+    *into, out = experts
+    return (*(jnp.pad(w, ((0, 0), (0, 0), (0, pad))) for w in into),
+            jnp.pad(out, ((0, 0), (0, pad), (0, 0))))
+
+
 class _ExpertWeights(nn.Module):
+    """The held experts' arrays, stacked: ``(w1, w3, w2)`` of a gated
+    feed-forward, ``(w1, w2)`` of a plain one."""
+
     held: int
     hidden: int
     width: int
+    gated: bool = True
 
     @nn.compact
     def __call__(self):
         # over the input axis alone: the leading axis counts experts
         init = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=1, out_axis=2, batch_axis=0)
-        return (self.param("w1", init, (self.held, self.hidden, self.width)),
-                self.param("w3", init, (self.held, self.hidden, self.width)),
-                self.param("w2", init, (self.held, self.width, self.hidden)))
+        into = (self.held, self.hidden, self.width)
+        w1 = self.param("w1", init, into)
+        w3 = (self.param("w3", init, into),) if self.gated else ()
+        return (w1, *w3, self.param("w2", init, (self.held, self.width, self.hidden)))
 
 
 class ExpertFFN(nn.Module):
@@ -770,8 +992,11 @@ class ExpertFFN(nn.Module):
     weights the scores themselves, normalised to sum 1 and scaled) and
     computes the part of the result that the ``held`` experts from ``first``
     on give. What the other experts would add is left out: on the chips
-    that share this layer it is their part of the sum. ``shared_width``
-    not 0: a shared expert that every token takes, a SwiGLU of that width
+    that share this layer it is their part of the sum. ``activation``:
+    "swiglu", every expert the gated SiLU ``(silu(x w1) * x w3) w2``, or
+    "relu2", the plain squared ReLU ``relu(x w1)^2 w2`` (two arrays an
+    expert). ``shared_width`` not 0: a shared expert that every token takes,
+    a feed-forward of that width and the experts' form, with ``shared_gate``
     times the sigmoid of a gate of its own (``x w_s``); every chip that
     shares the layer computes it alike, and it is added once here.
 
@@ -812,6 +1037,8 @@ class ExpertFFN(nn.Module):
     dtype: Any = jnp.bfloat16
     scoring: str = "sigmoid"
     shared_width: int = 0
+    activation: str = "swiglu"
+    shared_gate: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -849,9 +1076,10 @@ class ExpertFFN(nn.Module):
                 group.reshape(-1), jnp.arange(pairs, dtype=jnp.int32))
             inverse = jnp.argsort(order)
 
-            w1, w3, w2 = (w.astype(self.dtype) for w in _ExpertWeights(
-                self.held, self.hidden, self.width, name="experts")())
-            operands = (x.astype(self.dtype), weights, w1, w3, w2, order, inverse, sizes)
+            gated = {"swiglu": True, "relu2": False}[self.activation]
+            experts = _whole_tiles(tuple(w.astype(self.dtype) for w in _ExpertWeights(
+                self.held, self.hidden, self.width, gated, name="experts")()))
+            operands = (x.astype(self.dtype), weights, experts, order, inverse, sizes)
             if prefix < pairs:
                 out = _ranges_ffn(prefix, *operands)
             else:
@@ -859,11 +1087,12 @@ class ExpertFFN(nn.Module):
             out = out.astype(self.dtype)
             if self.shared_width:
                 with jax.named_scope("shared_expert"):
-                    gate = jax.nn.sigmoid(
-                        linear(1, self.dtype, "shared_expert_gate")(operands[0]))
-                    out = out + gate * SwiGLU(
+                    gate = jax.nn.sigmoid(linear(1, self.dtype, "shared_expert_gate")(
+                        operands[0])) if self.shared_gate else None
+                    shared = (SwiGLU if gated else SquaredReLU)(
                         self.hidden, self.shared_width, self.dtype,
                         name="shared_expert")(operands[0])
+                    out = out + (shared if gate is None else gate * shared)
             return out.reshape(shape)
 
 

@@ -95,7 +95,9 @@ def test_the_two_entries_list_the_qwen3_next_cell_alone():
     with open(os.path.join(os.path.dirname(spec.BENCH_DIR), "BENCHMARK.json")) as f:
         benchmark = json.load(f)
     entries = {m["name"]: m for m in benchmark["per_layer"]}
-    assert [m["name"] for m in benchmark["per_layer"][-2:]] == list(NAMES)
+    names = [m["name"] for m in benchmark["per_layer"]]
+    at = names.index(NAMES[0])  # added side by side, in this order; later PRs append after them
+    assert names[at:at + 2] == list(NAMES)
     for name, unit, better in zip(NAMES, ("ms", "%"), ("lower", "higher")):
         assert entries[name] == {
             "name": name, "unit": unit, "better": better, "source": "device_trace",

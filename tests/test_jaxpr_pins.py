@@ -16,7 +16,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from test_lfm2 import BATCH, LENGTH, MODEL, _trainer  # the tiny LFM2 of that file
+import test_lfm2  # the tiny token models of these three files
+import test_nemotron_h
+import test_qwen3_next
 
 from mpi4dl_tpu.config import ParallelConfig
 from mpi4dl_tpu.train import Trainer
@@ -50,11 +52,13 @@ def _image_step(model, remat, spatial=False):
         jax.ShapeDtypeStruct((2,), jnp.int32))
 
 
-def _token_step():
-    trainer = _trainer(MODEL, LENGTH)
+def _token_step(tests=test_lfm2):
+    """``Trainer._train_step`` of the tiny float32 model of a token model's
+    test file, under "cell" remat."""
+    trainer = tests._trainer(tests.MODEL, tests.LENGTH)
     state = jax.eval_shape(lambda: trainer.init(
-        jax.random.PRNGKey(0), (BATCH, LENGTH), jnp.int32))
-    ids = jax.ShapeDtypeStruct((BATCH, LENGTH), jnp.int32)
+        jax.random.PRNGKey(0), (tests.BATCH, tests.LENGTH), jnp.int32))
+    ids = jax.ShapeDtypeStruct((tests.BATCH, tests.LENGTH), jnp.int32)
     return trainer._train_step, (state, ids, ids)
 
 
@@ -153,6 +157,13 @@ TRACED_AT_C0A7BC1 = {
     # 9510765a4ddc7ee2...
     "lfm2-cell": (_token_step,
         "3f717aa8fd99b95449d4dfd7e20790fbfa942cff9fd78c3b74cd57d53344af76"),
+    # added by PR 39: Qwen3-Next's step as the parent 15786f9 traced it (the
+    # hash was taken on that tree before PR 39 touched ``Attention`` and
+    # ``ExpertFFN``, and holds after), and Nemotron-H's, new in PR 39
+    "qwen3_next-cell": (lambda: _token_step(test_qwen3_next),
+        "d1ceef15601fc414be91536c3933966f542e9c7f34ae5f2b21e9b0397c144ec6"),
+    "nemotron_h-cell": (lambda: _token_step(test_nemotron_h),
+        "32369203498bf3f6574c5b2797406e878ffc867b5c6be8030f049a33a4d2c14d"),
     "pipeline-gpipe": (lambda: _pipeline_step("gpipe"),
         "278d206dbf04f5ddd34d0b3bfb01274ea8b7e5d47f0c870c9caa6d9ee90b5b01"),
     "pipeline-1f1b": (lambda: _pipeline_step("1f1b"),

@@ -458,3 +458,62 @@ def test_the_share_of_the_512_expert_layer_keeps_the_width_of_its_prefix(topo, c
     assert products and rows == {"20480", "32"}, rows  # 32: the weight gradients
     assert " while(" in text and not re.search(r"\w+\[163840,(2048|512)\]", text)
     assert gradient.memory_analysis().temp_size_in_bytes < 1.6 * 2**30
+
+
+# -- Nemotron-H's three mixers at full width (PR 39) --------------------------
+
+
+def _nemotron_mixer(kind):
+    from mpi4dl_tpu.ops.sequence import Attention, ExpertFFN, Mamba2
+
+    return {
+        "mamba": lambda: Mamba2(2688, 64, 64, 8, 128, 4, 128, 1e-5),
+        "attention": lambda: Attention(2688, 32, 2, 1e-5, 0.0, block=256, head_dim=128,
+                                       rotary_dim=0, qk_norm=False),
+        "moe": lambda: ExpertFFN(2688, 1856, 128, 8, 0, 6, True, 2.5, shared_width=3712,
+                                 activation="relu2", shared_gate=False),
+    }[kind]()
+
+
+@pytest.mark.parametrize("kind,scopes,temp_gib", [
+    ("mamba", ("mamba2", "ssd_scan"), 4.0),
+    ("attention", ("lfm2_attention",), 5.5),
+    ("moe", ("lfm2_moe", "shared_expert"), 1.2),
+])
+def test_a_nemotron_h_mixer_compiles_at_full_width_under_its_scopes(
+        topo, cache_off, monkeypatch, kind, scopes, temp_gib):
+    """The tower's three mixers (``chipbench/configs/nemotron_twotower_30b_
+    a3b_share16.json``) on two sequences of 8,192 positions, forward and
+    backward, for one described chip, every kernel's gate steered to its TPU
+    branch: none admits these shapes (Mamba-2's scan has no kernel; attention
+    has head dim 128 at 16 heads a group), so no ``mpi4dl_*`` custom call is
+    in either pass and what runs is plain JAX under the scopes the
+    benchmark's readers join the trace with. Mamba-2: the scan takes a
+    sequence at a time (a ``while``) and a chunk's float32 squares are never
+    a buffer of both sequences' (1 GiB). The expert layer's share of 8 of 128
+    at 6 a token: 98,304 sorted pair rows, every grouped product over the
+    prefix of 12,288, two products an expert and pass. Temporaries as
+    compiled here, GiB: 3.42 / 4.95 / 0.87 (attention: the chip's scheduler keeps
+    many blocks of the backward alive while the memory allows, PERF.md section 7)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    dtype = jnp.float32 if kind == "moe" else jnp.bfloat16
+    forward, gradient = _layer_grad(
+        _nemotron_mixer(kind), jax.ShapeDtypeStruct((2, 8192, 2688), dtype), one_chip)
+    for scope in scopes:
+        _assert_scope_in_both_passes(forward, gradient, scope)
+    text = gradient.as_text()
+    for compiled in (forward, gradient):  # a kernel's custom call is named after it
+        assert not re.search(r"%mpi4dl_\w+ = [^\n]*custom-call\(", compiled.as_text())
+    temp = gradient.memory_analysis().temp_size_in_bytes
+    print(kind, "temp GiB", temp / 2**30)
+    assert temp < temp_gib * 2**30
+    if kind == "mamba":
+        assert " while(" in text
+        assert not re.search(r"f32\[2,64,128,128,8,8\]", text)
+    if kind == "moe":
+        products = [line for line in text.splitlines()
+                    if "custom-call(" in line and "%ragged-dot-none" in line.split(" = ")[0]]
+        rows = {re.search(r"= \w+\[(\d+),", line).group(1) for line in products}
+        # 2 forward + 4 backward, in the prefix and again in the loop of further ranges
+        assert len(products) == 12 and rows == {"12288", "8"}, rows  # 8: weight gradients
