@@ -49,8 +49,10 @@ MIXERS = "ME*"  # a layer's letter: Mamba-2, expert layer, attention
 # query rows whose scores are alive at once on attention's plain path: at 32
 # heads and two 8,192-token sequences 256 rows are 0.5 GiB of float32 scores,
 # what the other token models' blocks of 512 hold at their batch and heads.
-# (The chip's scheduler still keeps many blocks of the backward alive while
-# memory allows; the cure is in the shared backward: ROADMAP S18.)
+# (Where the fused kernels dispatch, as at the chip's cell since PR 40, they
+# take their own block from the length; on the plain path the chip's
+# scheduler keeps many blocks of the backward alive while memory allows, and
+# the cure is in the shared backward: ROADMAP S18.)
 ATTENTION_BLOCK = 256
 
 

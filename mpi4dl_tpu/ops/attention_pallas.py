@@ -5,14 +5,21 @@ of 64 over 8 key-value heads) took 144 ms of a 487 ms step as plain JAX
 (``ops/sequence.blocked_causal_attention``): every block's float32 scores
 went to HBM and back, 4.3 GB a layer-pass, three passes a layer (forward,
 the "cell" remat's forward again, the backward's recomputation). Here a
-block's scores, probabilities and ``ds`` live and die in VMEM.
+block's scores, probabilities and ``ds`` live and die in VMEM. Since PR 40
+the same kernels serve the one attention layer of the Qwen3-Next cell (16
+query heads of 256 over 2 key-value heads, two sequences) and of the
+Nemotron-H cell (32 of 128 over 2), 105 and 149 ms a step on the plain path:
+what differs between the three is the plan (``plan_for``), taken from head
+dim, heads a group and length.
 
 The arithmetic is the plain path's and the configuration's: bfloat16
 operands, every product accumulated in float32, float32 maximum / sum /
 log-sum-exp, ``delta = sum(d_out * out)`` and ``dp - delta`` in float32,
 ``p`` and ``ds`` rounded to bfloat16 only as operands of the next product.
-The scale ``D^-0.5`` is a power of two at D = 64, so it is folded into the
-keys (and into ``dk`` at the end) exactly.
+The scale ``D^-0.5`` is a power of two at D = 64 and 256, so it is folded
+into the keys (and into ``dk`` at the end) exactly; at D = 128 it is not
+(2^-3.5), and the float32 scores and ``ds`` take it, as on the plain path
+(``_folds``).
 
 Layout: keys run down the sublanes and queries along the lanes (scores
 ``[keys, queries]``), so a query's running maximum, sum, log-sum-exp and
@@ -23,22 +30,30 @@ that crosses HBM but a key block ``[block, D]`` fill their lanes. XLA
 brings q, k, v and d_out into that blocked, transposed form and the results
 back (0.6 ms of device time a layer's three passes, beside the kernels' 14.2).
 
-* forward, grid (batch, key-value head, block of queries): the head's
-  whole K and V stay in VMEM (fetched once a head); an in-kernel loop with
-  a dynamic trip count runs the key blocks below the diagonal, then the
-  diagonal block masked: blocks above the diagonal are never touched.
-* backward, one kernel, grid (batch, key-value head, block of keys): the
-  query blocks from the diagonal on; scores recomputed once, five products
-  a block; ``dk`` / ``dv`` summed over the query blocks and the group in
-  float32 and written once; ``dq`` of the head's whole sequence accumulates
-  in its float32 output block, which stays in VMEM across the head's key
-  blocks.
-* grouped queries: a grid step takes the group's G query heads side by side
-  against their one key-value head: no repeated K / V anywhere, the group's
-  ``dk`` / ``dv`` sum is the kernel's own, and the G chains are independent
-  work for the scheduler.
+* grouped queries: a grid step takes ``plan.heads`` of a group's query
+  heads side by side against their one key-value head: no repeated K / V
+  anywhere, and the chains are independent work for the scheduler. A group
+  wider than a step is so many sub-groups, each a grid row beside its
+  key-value head's (the row's key-value head is ``row // sub-groups``; a
+  block whose index does not change is not fetched again).
+* forward, grid (batch, sub-group, block of queries): the head's whole K
+  and V stay in VMEM (fetched once a head); an in-kernel loop with a dynamic
+  trip count runs the key blocks below the diagonal, then the diagonal
+  block masked: blocks above the diagonal are never touched.
+* backward, one kernel, grid (batch, sub-group, block of keys): the query
+  blocks from the diagonal on; scores recomputed once, five products a
+  block; ``dk`` / ``dv`` summed over the query blocks and the step's heads
+  in float32 and written once; ``dq`` of the sub-group's whole sequence
+  accumulates in its float32 output block, which stays in VMEM across the
+  key blocks. That residency is what the plan is made for: queries,
+  cotangents (bfloat16) and ``dq`` (float32) of ``heads x S x D``, buffered
+  twice, within half of ``_VMEM_LIMIT``: four heads of 64, two of 128 or one
+  of 256 at 8,192 positions. With one sub-group a key-value head the
+  kernel's sums are ``dk`` / ``dv``; with more they are float32 partial sums
+  a sub-group, which XLA adds up (268 MB written and read at Nemotron-H's
+  shape, 0.6 ms).
 
-Timed at the cell's shape (q [1, 8192, 8, 4, 64], k, v [1, 8192, 8, 64],
+Timed at LFM2's shape (q [1, 8192, 8, 4, 64], k, v [1, 8192, 8, 64],
 bfloat16; TPU v5 lite, jax 0.9.0; jitted on the cell's layout, so each
 figure holds its own layout changes; ms forward / backward / a layer's
 three passes = 2 forwards + backward; my chip runs, PR 34):
@@ -64,16 +79,69 @@ its width in the other three, so 98 is what this formulation can reach.
 Block 512 is kept for every length it divides; 256 and 128 serve shorter
 sequences (and the interpreter's tests).
 
-Dispatch (``dispatchable``): TPU backend, not under ``vmap``, bfloat16,
-head dim 64, a length of whole blocks whose group fits VMEM; everything
-else takes the plain path, which is also the tests' oracle. No switch.
-``tests/test_tpu_compile.py`` compiles the cell's shape for a described
-v5e chip and fails if the kernels are not in the compiled text.
+Timed at the two wide shapes (``scripts/time_attention.py``, the same
+method; my chip runs, PR 40; heads a grid step x block; ms):
+
+    Qwen3-Next, q [2, 8192, 2, 8, 256]        forward   backward   three passes
+    plain JAX, 512 query rows a block          23.26      43.80      90.32
+    the plan: 1 x 512                           9.50      21.62      40.61
+    1 x 256                                    12.64      23.71
+    2 x 512 / 2 x 256 (the backward's
+      residents past VMEM: refused)             9.35 / 12.14
+    4 x 512 / 4 x 256 (the same)                9.31 / 12.18
+    the backward's whole-sequence blocks buffered once
+      (``pipeline_mode=pl.Buffered(1)``), so that twice the heads fit:
+      1 x 512 / 2 x 512 / 2 x 256                         21.81 / 20.80 / 21.29
+
+    Nemotron-H, q [2, 8192, 2, 16, 128]
+    plain JAX, 256 query rows a block          39.36      74.99     153.71
+    the plan: 2 x 512                          11.28      20.64      43.20
+    1 x 512 / 1 x 256                          11.74 / 22.25   21.93 / 24.60
+    2 x 256                                    15.97      21.79
+    4 x 512 / 4 x 256 (backward: refused)      11.13 / 12.84
+    buffered once: 2 x 512 / 4 x 512 / 4 x 256            21.06 / 20.07 / 20.29
+
+So the plan is one rule: block 512 where it divides the length, and as many
+heads a step as fill 256 lanes of head dims (``_STEP_WIDTH``), fewer where
+the group or VMEM says so. What lost: smaller blocks (a block's softmax
+statistics and the accumulator's rescaling are paid twice as often); more
+heads a forward step than a backward step (0.2 ms a pass: not worth a
+second number in the plan); single buffering (0.6-1.0 ms a backward, one a
+step, against a pipeline mode no other kernel here uses). A step's heads
+are never more than four, so the unrolled chains are never more than LFM2's
+and the step's trace and lowering did not grow (``setup_s``).
+
+The kernels alone, as the two cells' steps run them (device events, my
+chip runs, PR 40): forward 7.56 ms and backward 17.29 at Qwen3-Next's shape,
+9.27 and 16.71 at Nemotron-H's; a layer's three passes 32.4 / 35.3 ms
+(``gated_attn_kernel_ms``, ``nemotron_attn_kernel_ms``), the rest of the
+standalone figures being the layout changes, which the step fuses in part.
+The three passes execute nine products over whole blocks, 5.26 TFLOP, at 162
+/ 149 TFLOP/s, where the least work (six products over the causal half,
+3.30 TFLOP) is 16.75 ms at the chip's 197: 51.7% / 47.5% of the roofline.
+What is left above it: the recomputed scores and the remat's second forward
+(a third of the executed work), the blocks above the diagonal's half (6%),
+and the softmax's elementwise work, which the matrix unit does not hide:
+0.4 us a head and block of 512 x 512 scores in the forward at either head
+dim, beside 1.36 us of products at D = 256 and 0.68 at 128, so the forward
+runs at 78% / 64% of the peak over the work it executes and the backward at
+86% / 89%. Around the kernels the steps spend 8 / 5 ms in layout changes
+(``_queries_t`` and back, for q, out, d_out and dq): at head dims of whole
+lanes the kernels could read q and d_out row-major (``dot_general`` takes
+the transposed operand), which LFM2's 64 does not allow.
+
+Dispatch (``dispatchable``): TPU backend, not under ``vmap``, and a plan
+(``plan_for``: bfloat16, head dim 64, 128 or 256, a length of whole blocks,
+a step's heads within VMEM); everything else takes the plain path, which is
+also the tests' oracle. No switch. ``tests/test_tpu_compile.py`` compiles
+the three cells' shapes, and shapes the plan admits that no cell runs, for
+a described v5e chip and fails if the kernels are not in the compiled text.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -86,10 +154,18 @@ from jax.experimental.pallas import tpu as pltpu
 # common start, ``mpi4dl_attention``).
 FWD_NAME = "mpi4dl_attention_fwd"
 BWD_NAME = "mpi4dl_attention_bwd"
-HEAD_DIM = 64
+HEAD_DIMS = (64, 128, 256)
 BLOCKS = (512, 256, 128)
 _VMEM_LIMIT = 64 * 1024 * 1024
+_STEP_WIDTH = 256  # heads a grid step x head dim: four heads of 64, two of 128, one of 256
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
+
+
+class Plan(NamedTuple):
+    """What the kernels take from the shape (``plan_for``)."""
+
+    block: int  # rows of a block of queries and of a block of keys
+    heads: int  # query heads of a group a grid step takes side by side
 
 
 def _causal(block):
@@ -103,18 +179,19 @@ def _dot(a, b):
     return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(qt_ref, ks_ref, vt_ref, ot_ref, lse_ref, *, block):
-    """One (batch, key-value head, block of queries): online softmax over
-    the key blocks up to the diagonal, for the group's query heads side by
-    side: their chains are independent, so one head's softmax arithmetic
-    runs under another's products. Keys run down the sublanes and queries
-    along the lanes, so a query's running maximum, sum and log-sum-exp are
-    lane-dense rows ``[1, block]`` and the reductions over keys are
-    elementwise across vregs.
+def _fwd_kernel(qt_ref, ks_ref, vt_ref, ot_ref, lse_ref, *, block, scale, fold):
+    """One (batch, sub-group of a key-value head's query heads, block of
+    queries): online softmax over the key blocks up to the diagonal, for the
+    sub-group's H query heads side by side: their chains are independent, so
+    one head's softmax arithmetic runs under another's products. Keys run
+    down the sublanes and queries along the lanes, so a query's running
+    maximum, sum and log-sum-exp are lane-dense rows ``[1, block]`` and the
+    reductions over keys are elementwise across vregs.
 
-    qt_ref ``[G, D, block]``; ks_ref ``[blocks, block, D]`` (keys x D^-0.5)
-    and vt_ref ``[blocks, D, block]``: the key-value head's whole sequence;
-    ot_ref ``[G, D, block]``; lse_ref ``[G, 1, block]`` float32."""
+    qt_ref ``[H, D, block]``; ks_ref ``[blocks, block, D]`` (keys x D^-0.5
+    where ``fold``, else the keys, and the float32 scores take ``scale``)
+    and vt_ref ``[blocks, D, block]``: the key-value head's whole
+    sequence; ot_ref ``[H, D, block]``; lse_ref ``[H, 1, block]`` float32."""
     i = pl.program_id(2)
     group, d, _ = qt_ref.shape
     f32 = jnp.float32
@@ -125,6 +202,8 @@ def _fwd_kernel(qt_ref, ks_ref, vt_ref, ot_ref, lse_ref, *, block):
         out = []
         for g, (top, total, acc) in enumerate(carry):
             s = _dot(ks, qt_ref[g])  # [keys, queries]
+            if not fold:
+                s = s * scale
             if diagonal:
                 s = jnp.where(seen, s, -jnp.inf)
             new_top = jnp.maximum(top, jnp.max(s, axis=0, keepdims=True))
@@ -144,18 +223,20 @@ def _fwd_kernel(qt_ref, ks_ref, vt_ref, ot_ref, lse_ref, *, block):
 
 
 def _bwd_kernel(ks_ref, kst_ref, v_ref, qt_ref, dot_ref, lse_ref, delta_ref,
-                dqt_ref, dk_ref, dv_ref, *, block, scale):
-    """One (batch, key-value head, block of keys): the query blocks from the
-    diagonal on, the group's query heads side by side. ``dk`` / ``dv`` of
-    this key block are summed over the query blocks and the group in
-    float32 and written once; ``dq`` of the whole sequence stays in VMEM
-    across the key blocks of a head (zeroed at the first, written back after
-    the last).
+                dqt_ref, dk_ref, dv_ref, *, block, scale, fold):
+    """One (batch, sub-group of a key-value head's query heads, block of
+    keys): the query blocks from the diagonal on, the sub-group's H query
+    heads side by side. ``dk`` / ``dv`` of this key block are summed over the
+    query blocks and the sub-group in float32 and written once; ``dq`` of the
+    whole sequence stays in VMEM across the key blocks of a sub-group (zeroed
+    at the first, written back after the last).
 
-    ks_ref ``[block, D]`` and kst_ref ``[D, block]`` (keys x D^-0.5), v_ref
-    ``[block, D]``; qt_ref, dot_ref ``[G, blocks, D, block]`` and lse_ref,
-    delta_ref ``[G, blocks, 1, block]``: the group's whole sequence; dqt_ref
-    ``[G, blocks, D, block]`` float32; dk_ref, dv_ref ``[block, D]``."""
+    ks_ref ``[block, D]`` and kst_ref ``[D, block]`` (keys x D^-0.5 where
+    ``fold``, else the keys: the float32 scores and ``ds`` take ``scale``),
+    v_ref ``[block, D]``; qt_ref, dot_ref ``[H, blocks, D, block]`` and
+    lse_ref, delta_ref ``[H, blocks, 1, block]``: the sub-group's whole
+    sequence; dqt_ref ``[H, blocks, D, block]`` float32; dk_ref, dv_ref
+    ``[block, D]``."""
     j = pl.program_id(2)
     group, blocks = qt_ref.shape[:2]
     ks, kst, v = ks_ref[...], kst_ref[...], v_ref[...]
@@ -171,12 +252,16 @@ def _bwd_kernel(ks_ref, kst_ref, v_ref, qt_ref, dot_ref, lse_ref, delta_ref,
         for g in range(group):
             qt, dot = qt_ref[g, i], dot_ref[g, i]
             s = _dot(ks, qt)  # [keys, queries]
+            if not fold:
+                s = s * scale
             if diagonal:
                 s = jnp.where(seen, s, -jnp.inf)
             p = jnp.exp(s - lse_ref[g, i])
             dp = _dot(v, dot)
-            # without the D^-0.5: dq takes it from the keys, dk at the end
-            ds = (p * (dp - delta_ref[g, i])).astype(qt.dtype)
+            if fold:  # without the D^-0.5: dq takes it from the keys, dk at the end
+                ds = (p * (dp - delta_ref[g, i])).astype(qt.dtype)
+            else:
+                ds = (p * (dp - delta_ref[g, i]) * scale).astype(qt.dtype)
             dv = dv + lax.dot_general(p.astype(dot.dtype), dot, _NT, preferred_element_type=f32)
             dk = dk + lax.dot_general(ds, qt, _NT, preferred_element_type=f32)
             dqt_ref[g, i] += _dot(kst, ds)
@@ -184,7 +269,7 @@ def _bwd_kernel(ks_ref, kst_ref, v_ref, qt_ref, dot_ref, lse_ref, delta_ref,
 
     carry = step(j, (jnp.zeros(ks.shape, f32), jnp.zeros(ks.shape, f32)), True)
     dk, dv = lax.fori_loop(j + 1, blocks, functools.partial(step, diagonal=False), carry)
-    dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
+    dk_ref[...] = (dk * scale if fold else dk).astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
@@ -218,92 +303,133 @@ def _rows_t(x, block):
     return x.reshape(*x.shape[:3], x.shape[3] // block, 1, block)
 
 
+def _folds(d: int) -> bool:
+    """Whether ``D^-0.5`` is a power of two (D = 64, 256), so that the keys
+    take it exactly in bfloat16; else (D = 128) the float32 scores and
+    ``ds`` take it, as on the plain path."""
+    return (d.bit_length() - 1) % 2 == 0
+
+
 def _scaled(k):
-    """The keys x D^-0.5: a power of two at D = 64, so exact in bfloat16."""
-    return k * jnp.asarray(k.shape[-1] ** -0.5, k.dtype)
+    """The keys x D^-0.5 where that is exact in bfloat16, else the keys."""
+    d = k.shape[-1]
+    return k * jnp.asarray(d ** -0.5, k.dtype) if _folds(d) else k
+
+
+def _sub_groups(x_t, heads):
+    """``[B, KV, G, ...] -> [B, KV x G / heads, heads, ...]``: a key-value
+    head's group as sub-groups of ``heads`` query heads, each a grid row of
+    its own beside its key-value head's."""
+    b, kv, g = x_t.shape[:3]
+    return x_t.reshape(b, kv * g // heads, heads, *x_t.shape[3:])
+
+
+def _key_value_head(subs):
+    """A grid row's key-value head: ``subs`` sub-groups share one (at one a
+    head the row itself, so that such a shape's index maps trace as they did
+    before there were sub-groups)."""
+    return (lambda h: h) if subs == 1 else (lambda h: h // subs)
 
 
 def _params(*semantics):
     return pltpu.CompilerParams(dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def forward(q, k, v, block, interpret=False):
+def forward(q, k, v, plan, interpret=False):
     """``(out [B, S, KV, G, D], log-sum-exp [B, KV, G, S] float32)``."""
     b, s, kv, g, d = q.shape
-    blocks = s // block
+    block, heads = plan
+    blocks, subs = s // block, g // heads
+    of = _key_value_head(subs)
 
-    def group(*tail):  # the group's query heads, one block of queries
-        return pl.BlockSpec((None, None, g, None) + tail, lambda n, h, i: (n, h, 0, i, 0, 0))
+    def group(*tail):  # a sub-group's query heads, one block of queries
+        return pl.BlockSpec((None, None, heads, None) + tail, lambda n, h, i: (n, h, 0, i, 0, 0))
 
     def whole(*tail):  # their key-value head's whole sequence
-        return pl.BlockSpec((None, None, blocks) + tail, lambda n, h, i: (n, h, 0, 0, 0))
+        return pl.BlockSpec((None, None, blocks) + tail, lambda n, h, i: (n, of(h), 0, 0, 0))
 
     out_t, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, block=block),
-        grid=(b, kv, blocks),
+        functools.partial(_fwd_kernel, block=block, scale=d ** -0.5, fold=_folds(d)),
+        grid=(b, kv * subs, blocks),
         in_specs=[group(d, block), whole(block, d), whole(d, block)],
         out_specs=[group(d, block), group(1, block)],
-        out_shape=[jax.ShapeDtypeStruct((b, kv, g, blocks, d, block), q.dtype),
-                   jax.ShapeDtypeStruct((b, kv, g, blocks, 1, block), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((b, kv * subs, heads, blocks, d, block), q.dtype),
+                   jax.ShapeDtypeStruct((b, kv * subs, heads, blocks, 1, block), jnp.float32)],
         compiler_params=_params("parallel", "parallel", "parallel"),
         interpret=interpret,
         name=FWD_NAME,
-    )(_queries_t(q, block), _keys(_scaled(k), block), _keys_t(v, block))
+    )(_sub_groups(_queries_t(q, block), heads), _keys(_scaled(k), block), _keys_t(v, block))
+    out_t = out_t.reshape(b, kv, g, blocks, d, block)
     return _queries(out_t, q.shape), lse.reshape(b, kv, g, s)
 
 
-def backward(q, k, v, out, lse, d_out, block, interpret=False):
+def backward(q, k, v, out, lse, d_out, plan, interpret=False):
     """``(dq, dk, dv)``, shaped and typed as ``q, k, v``."""
     b, s, kv, g, d = q.shape
-    blocks = s // block
+    block, heads = plan
+    blocks, subs = s // block, g // heads
+    of = _key_value_head(subs)
     scaled = _scaled(k)
     # per row, sum(d_out * out): what the softmax's backward subtracts
     delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     delta = jnp.moveaxis(delta, 1, 3)  # [B, S, KV, G] -> [B, KV, G, S]
 
     def keys(*tail):  # the key-value head's block of keys
+        return pl.BlockSpec((None, None, None) + tail, lambda n, h, j: (n, of(h), j, 0, 0))
+
+    def sums(*tail):  # that block's dk / dv, a sub-group's
         return pl.BlockSpec((None, None, None) + tail, lambda n, h, j: (n, h, j, 0, 0))
 
-    def whole(*tail):  # the group's query heads, their whole sequence
-        return pl.BlockSpec((None, None, g, blocks) + tail, lambda n, h, j: (n, h, 0, 0, 0, 0))
+    def whole(*tail):  # a sub-group's query heads, their whole sequence
+        return pl.BlockSpec((None, None, heads, blocks) + tail, lambda n, h, j: (n, h, 0, 0, 0, 0))
+
+    def sub(x_t):
+        return _sub_groups(x_t, heads)
+
+    def summed(like):  # one sub-group a key-value head: its sums are dk / dv;
+        return like.dtype if subs == 1 else jnp.float32  # more: partial sums, added up below
 
     dq_t, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, block=block, scale=d ** -0.5),
-        grid=(b, kv, blocks),
+        functools.partial(_bwd_kernel, block=block, scale=d ** -0.5, fold=_folds(d)),
+        grid=(b, kv * subs, blocks),
         in_specs=[keys(block, d), keys(d, block), keys(block, d),
                   whole(d, block), whole(d, block), whole(1, block), whole(1, block)],
-        out_specs=[whole(d, block), keys(block, d), keys(block, d)],
-        out_shape=[jax.ShapeDtypeStruct((b, kv, g, blocks, d, block), jnp.float32),
-                   jax.ShapeDtypeStruct((b, kv, blocks, block, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, kv, blocks, block, d), v.dtype)],
+        out_specs=[whole(d, block), sums(block, d), sums(block, d)],
+        out_shape=[jax.ShapeDtypeStruct((b, kv * subs, heads, blocks, d, block), jnp.float32),
+                   jax.ShapeDtypeStruct((b, kv * subs, blocks, block, d), summed(k)),
+                   jax.ShapeDtypeStruct((b, kv * subs, blocks, block, d), summed(v))],
         compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
         name=BWD_NAME,
     )(_keys(scaled, block), _keys_t(scaled, block), _keys(v, block),
-      _queries_t(q, block), _queries_t(d_out, block), _rows_t(lse, block),
-      _rows_t(delta, block))
+      sub(_queries_t(q, block)), sub(_queries_t(d_out, block)), sub(_rows_t(lse, block)),
+      sub(_rows_t(delta, block)))
 
-    def keys_back(x):  # [B, KV, blocks, block, D] -> [B, S, KV, D]
-        return x.transpose(0, 2, 3, 1, 4).reshape(k.shape)
+    def keys_back(x, like):  # [B, KV x subs, blocks, block, D] -> [B, S, KV, D]
+        if subs > 1:
+            x = jnp.sum(x.reshape(b, kv, subs, blocks, block, d), axis=2).astype(like.dtype)
+        return x.transpose(0, 2, 3, 1, 4).reshape(like.shape)
 
-    return _queries(dq_t.astype(q.dtype), q.shape), keys_back(dk), keys_back(dv)
+    dq_t = dq_t.reshape(b, kv, g, blocks, d, block)
+    return _queries(dq_t.astype(q.dtype), q.shape), keys_back(dk, k), keys_back(dv, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def attention(q, k, v, block, interpret=False):
+def attention(q, k, v, plan, interpret=False):
     """Causal softmax attention with grouped queries through the kernels:
     ``q [B, S, KV, G, D]``, ``k, v [B, S, KV, D]`` -> ``[B, S, KV, G, D]``;
-    ``S`` a multiple of ``block``, ``D`` 64."""
-    return forward(q, k, v, block, interpret)[0]
+    ``S`` a multiple of ``plan.block``, ``G`` of ``plan.heads``, ``D`` one of
+    ``HEAD_DIMS``."""
+    return forward(q, k, v, plan, interpret)[0]
 
 
-def _attention_fwd(q, k, v, block, interpret):
-    out, lse = forward(q, k, v, block, interpret)
+def _attention_fwd(q, k, v, plan, interpret):
+    out, lse = forward(q, k, v, plan, interpret)
     return out, (q, k, v, out, lse)
 
 
-def _attention_bwd(block, interpret, residuals, d_out):
-    return backward(*residuals, d_out, block, interpret)
+def _attention_bwd(plan, interpret, residuals, d_out):
+    return backward(*residuals, d_out, plan, interpret)
 
 
 attention.defvjp(_attention_fwd, _attention_bwd)
@@ -315,17 +441,31 @@ def block_for(length: int):
     return next((blk for blk in BLOCKS if length % blk == 0), None)
 
 
+def plan_for(q_shape, k_shape, dtype):
+    """The kernels' plan for ``q [B, S, KV, G, D]`` and ``k [B, S, KV, D]``,
+    from head dim, heads a group and length alone; None for shapes the
+    kernels are not written (and compiled, for a described chip) for. They
+    take bfloat16, a head dim of ``HEAD_DIMS`` and a sequence that is whole
+    blocks. A grid step takes as many of a group's query heads as divide the
+    group, stay within ``_STEP_WIDTH`` lanes of head dims (the chains a step
+    unrolls are never more than four) and whose whole-sequence queries,
+    cotangents and float32 ``dq``, each buffered twice, fit half of the
+    kernels' VMEM in the backward; the other half is for a key-value head's
+    whole K and V in the forward and for the scores of a step's blocks."""
+    if len(q_shape) != 5 or len(k_shape) != 4 or dtype != jnp.bfloat16:
+        return None
+    length, group, d = q_shape[1], q_shape[3], q_shape[4]
+    block = block_for(length)
+    if d not in HEAD_DIMS or block is None:
+        return None
+    fits = [h for h in range(1, _STEP_WIDTH // d + 1)
+            if group % h == 0 and 2 * h * length * d * (2 + 2 + 4) <= _VMEM_LIMIT // 2]
+    return Plan(block, fits[-1]) if fits else None
+
+
 def supported(q_shape, k_shape, dtype) -> bool:
-    """The shapes the kernels are written (and compiled, for a described
-    chip) for: bfloat16, head dim 64, a sequence that is whole blocks and
-    whose group of query heads (queries, cotangents and float32 ``dq``, each
-    block buffered twice) fits half of the kernels' VMEM; the other half is
-    for the scores of the group's blocks."""
-    if len(q_shape) != 5 or len(k_shape) != 4:
-        return False
-    length, d = q_shape[1], q_shape[-1]
-    return (dtype == jnp.bfloat16 and d == HEAD_DIM and block_for(length) is not None
-            and 2 * q_shape[3] * length * d * (2 + 2 + 4) <= _VMEM_LIMIT // 2)
+    """Whether the kernels have a plan for these shapes."""
+    return plan_for(q_shape, k_shape, dtype) is not None
 
 
 def dispatchable(q, k) -> bool:

@@ -12,8 +12,10 @@ width of a prefix of the sorted rows that follows from the share held; the
 rows past it run only in a step whose routing overflows it. The
 ``S x S`` scores of a long sequence never exist at once (32 heads x 8192^2
 floats are 8.6 GB): on a TPU, at the shapes ``ops/attention_pallas.py``
-takes, ``causal_attention`` is that module's fused kernels, which keep a
-block's scores in VMEM; everywhere else (the CPU, a vmapped trace, a length
+takes (bfloat16, head dim 64, 128 or 256, a length of whole blocks: all
+three token models' at 8,192 positions), ``causal_attention`` is that
+module's fused kernels, which keep a block's scores in VMEM and take their
+plan from the shape; everywhere else (the CPU, a vmapped trace, a length
 that is not whole blocks, another head dim) it is plain JAX, a block of
 query rows at a time, each block recomputed in the backward pass. The gated
 delta rule goes the same way: on a TPU, at the shapes
@@ -552,13 +554,17 @@ def causal_attention(q, k, v, block: int):
     ``q [B, S, KV, G, D]`` (G query heads share a key-value head),
     ``k, v [B, S, KV, D]`` -> ``[B, S, KV, G, D]``. Where
     ``attention_pallas.dispatchable`` says so (TPU backend, not under
-    ``vmap``, bfloat16, head dim 64, a length of whole kernel blocks) the
-    fused kernels, whose block is chosen from the length; else
-    ``blocked_causal_attention`` at ``block`` query rows. Both are the same
-    arithmetic: bfloat16 operands, every product accumulated in float32,
-    float32 softmax statistics, ``dp - delta`` in float32."""
+    ``vmap``, bfloat16, a head dim and a length of whole kernel blocks that
+    ``attention_pallas.plan_for`` has a plan for) the fused kernels under
+    that plan (block from the length, query heads a grid step from head dim,
+    group and length); else ``blocked_causal_attention`` at ``block`` query
+    rows. Both are the same arithmetic: bfloat16 operands, every product
+    accumulated in float32, ``D^-0.5`` on the float32 scores (or, where it
+    is a power of two, on the bfloat16 keys: the same numbers), float32
+    softmax statistics, ``dp - delta`` in float32."""
     if attention_pallas.dispatchable(q, k):
-        return attention_pallas.attention(q, k, v, attention_pallas.block_for(q.shape[1]))
+        return attention_pallas.attention(
+            q, k, v, attention_pallas.plan_for(q.shape, k.shape, q.dtype))
     return blocked_causal_attention(q, k, v, block)
 
 
