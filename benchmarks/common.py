@@ -33,6 +33,7 @@ def parse_csv_ints(s):
 
 
 def build_config(args, spatial: bool, num_cells: int | None = None):
+    import jax
     import jax.numpy as jnp
 
     from mpi4dl_tpu.config import ParallelConfig
@@ -45,6 +46,12 @@ def build_config(args, spatial: bool, num_cells: int | None = None):
     # supervisor process may not hold (TPU access is per-process exclusive).
     maybe_supervise(args)
     enable_compilation_cache()  # multi-minute XLA compiles amortize across runs
+    # A training step's executable carries the jax name stacks it was compiled
+    # with, and the device trace's readers find the program's
+    # ``jax.named_scope``s there (``Trainer.compiled_step``). JAX's cache key
+    # leaves them out unless told: a step whose scopes alone changed would
+    # load the older executable, with the older names.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     # Join the multi-host world if one is configured (no-op single-process;
     # the reference's dist.init_process_group moment, comm.py:154-159).
     multihost.initialize_distributed()

@@ -34,8 +34,10 @@ from __future__ import annotations
 from typing import Any, Callable, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
+from mpi4dl_tpu.ops.fastconv import conv_scope
 from mpi4dl_tpu.ops.layers import (
     Conv2d,
     HaloExchange,
@@ -290,7 +292,8 @@ class Classify(nn.Module):
     def __call__(self, states):
         x, _ = states
         x = jnp.mean(x, axis=(1, 2))
-        return nn.Dense(self.num_classes, dtype=self.dtype, name="fc")(x)
+        with jax.named_scope(conv_scope(1, 1)):
+            return nn.Dense(self.num_classes, dtype=self.dtype, name="fc")(x)
 
 
 class AmoebaCell(nn.Module):
@@ -446,18 +449,19 @@ class PoolD2(nn.Module):
         h = self.halo_in
         if h < 1:
             raise ValueError("PoolD2 needs halo_in >= 1 (3x3 pad-1 window)")
-        if self.kind == "max":
-            x = fill_boundary_halo(x, h, h, float("-inf"))
-            return max_pool_s1_valid(x, 3, 3)
-        if self.kind != "avg":
-            raise ValueError(f"unknown pool kind {self.kind!r}")
-        x = zero_boundary_halo(x, h, h)
-        if self.count_include_pad:
-            return nn.avg_pool(x, (3, 3), strides=(1, 1), padding="VALID")
-        ones = zero_boundary_halo(jnp.ones_like(x), h, h)
-        num = jlax.reduce_window(x, 0.0, jlax.add, (1, 3, 3, 1), (1, 1, 1, 1), "valid")
-        den = jlax.reduce_window(ones, 0.0, jlax.add, (1, 3, 3, 1), (1, 1, 1, 1), "valid")
-        return num / den
+        with jax.named_scope("mpi4dl_pool"):
+            if self.kind == "max":
+                x = fill_boundary_halo(x, h, h, float("-inf"))
+                return max_pool_s1_valid(x, 3, 3)
+            if self.kind != "avg":
+                raise ValueError(f"unknown pool kind {self.kind!r}")
+            x = zero_boundary_halo(x, h, h)
+            if self.count_include_pad:
+                return nn.avg_pool(x, (3, 3), strides=(1, 1), padding="VALID")
+            ones = zero_boundary_halo(jnp.ones_like(x), h, h)
+            num = jlax.reduce_window(x, 0.0, jlax.add, (1, 3, 3, 1), (1, 1, 1, 1), "valid")
+            den = jlax.reduce_window(ones, 0.0, jlax.add, (1, 3, 3, 1), (1, 1, 1, 1), "valid")
+            return num / den
 
 
 def _pair_(v):

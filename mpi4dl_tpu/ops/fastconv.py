@@ -101,6 +101,16 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def conv_scope(kh: int, kw: int) -> str:
+    """The ``jax.named_scope`` the device trace finds a convolution's class
+    by, from its window: a 1x1 (a product over pixels; a dense layer) or a
+    k x k. ``layers.Conv2d`` opens it around forward, halo and bias; the
+    ``custom_vjp`` backward rules' operators carry the forward's name stack
+    under ``transpose(jvp())`` and so the scope (``tests/test_step_scopes.py``
+    holds both gradients to it)."""
+    return "mpi4dl_conv1x1" if (kh, kw) == (1, 1) else "mpi4dl_convkxk"
+
+
 @functools.lru_cache(maxsize=None)
 def pack_factors(kh: int, kw: int, c_out: int, w_out: int) -> tuple[int, int]:
     """Choose (1, fw) output-block factors for a stride-1 conv; (1, 1)

@@ -35,11 +35,12 @@ import math
 from typing import Any, Callable, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 from jax import lax
 
 from mpi4dl_tpu.config import AXIS_TILE_H, AXIS_TILE_W
-from mpi4dl_tpu.ops.fastconv import FastConv
+from mpi4dl_tpu.ops.fastconv import FastConv, conv_scope
 from mpi4dl_tpu.parallel.halo import halo_exchange, zero_boundary_halo
 
 TILE_AXES = (AXIS_TILE_H, AXIS_TILE_W)
@@ -268,41 +269,42 @@ class TrainBatchNorm(nn.Module):
         c = x.shape[-1]
         scale = self.param("scale", nn.initializers.ones_init(), (c,), jnp.float32)
         bias = self.param("bias", nn.initializers.zeros_init(), (c,), jnp.float32)
-        if current_bn_mode() == "running":
-            mean = self.variable(
-                "batch_stats", "mean", jnp.zeros, (c,), jnp.float32
-            ).value
-            var = self.variable(
-                "batch_stats", "var", jnp.ones, (c,), jnp.float32
-            ).value
+        with jax.named_scope("mpi4dl_batchnorm"):
+            if current_bn_mode() == "running":
+                mean = self.variable(
+                    "batch_stats", "mean", jnp.zeros, (c,), jnp.float32
+                ).value
+                var = self.variable(
+                    "batch_stats", "var", jnp.ones, (c,), jnp.float32
+                ).value
+                w = (lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
+                b = (bias - mean * lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
+                return x * w + b
+            # D2 fused-halo tiles carry `interior` rows/cols of neighbor data;
+            # excluding them from the statistics makes cross-tile (pmean) stats
+            # bit-identical to the plain model's — a correctness refinement over
+            # the reference, which lets halo pixels skew per-tile BN.
+            ih, iw = self.interior
+            stat_src = x
+            if ih:
+                stat_src = stat_src[:, ih:-ih, :, :]
+            if iw:
+                stat_src = stat_src[:, :, iw:-iw, :]
+            # Statistics in f32 with the upcast fused into the reductions and
+            # the squaring AFTER the upcast (E[x^2]-E[x]^2 cancels
+            # catastrophically if x^2 is rounded to bf16 first). The
+            # normalize below stays in the input dtype, which profiling showed
+            # otherwise costs ~12% of a bf16 train step in converts alone.
+            mean, mean_sq = bn_moments(stat_src)
+            if self.reduce_axes:
+                mean = lax.pmean(mean, self.reduce_axes)
+                mean_sq = lax.pmean(mean_sq, self.reduce_axes)
+            if current_bn_mode() == "collect":
+                _accumulate_bn_stats(self, mean, mean_sq)
+            var = mean_sq - jnp.square(mean)
             w = (lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
             b = (bias - mean * lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
             return x * w + b
-        # D2 fused-halo tiles carry `interior` rows/cols of neighbor data;
-        # excluding them from the statistics makes cross-tile (pmean) stats
-        # bit-identical to the plain model's — a correctness refinement over
-        # the reference, which lets halo pixels skew per-tile BN.
-        ih, iw = self.interior
-        stat_src = x
-        if ih:
-            stat_src = stat_src[:, ih:-ih, :, :]
-        if iw:
-            stat_src = stat_src[:, :, iw:-iw, :]
-        # Statistics in f32 with the upcast fused into the reductions and
-        # the squaring AFTER the upcast (E[x^2]-E[x]^2 cancels
-        # catastrophically if x^2 is rounded to bf16 first). The
-        # normalize below stays in the input dtype, which profiling showed
-        # otherwise costs ~12% of a bf16 train step in converts alone.
-        mean, mean_sq = bn_moments(stat_src)
-        if self.reduce_axes:
-            mean = lax.pmean(mean, self.reduce_axes)
-            mean_sq = lax.pmean(mean_sq, self.reduce_axes)
-        if current_bn_mode() == "collect":
-            _accumulate_bn_stats(self, mean, mean_sq)
-        var = mean_sq - jnp.square(mean)
-        w = (lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
-        b = (bias - mean * lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
-        return x * w + b
 
 
 def _accumulate_bn_stats(mod: nn.Module, mean, mean_sq) -> None:
@@ -365,79 +367,80 @@ class Conv2d(nn.Module):
         else:
             ph, pw = _pair(self.padding)
 
-        if self.pack != (1, 1):
-            # Persistently-packed activation layout (ops/packed.py): the
-            # input is [B, H, W/pack_in, pack_in*C]; emit packed too.
-            # Spatial mode halo-exchanges whole packed columns (see
-            # conv2d_packed) — the D1 per-op exchange form only; the D2
-            # shrink form (exchange=False) has no packed variant.
-            if self.spatial and not self.exchange:
-                raise NotImplementedError(
-                    "packed layout has no D2 (pre-fetched halo) conv form"
-                )
-            if self.spatial:
-                _check_window_coverage(kh, kw, sh, sw, ph, pw)
-            # Packed columns fold W into C: the recorded extents cannot be
-            # interpreted as image rows/cols, so geometry consumers refuse.
-            _record_windowed_op("packed", x, kh, kw, sh, sw, ph, pw)
-            from mpi4dl_tpu.ops.packed import PackedConv
+        with jax.named_scope(conv_scope(kh, kw)):
+            if self.pack != (1, 1):
+                # Persistently-packed activation layout (ops/packed.py): the
+                # input is [B, H, W/pack_in, pack_in*C]; emit packed too.
+                # Spatial mode halo-exchanges whole packed columns (see
+                # conv2d_packed) — the D1 per-op exchange form only; the D2
+                # shrink form (exchange=False) has no packed variant.
+                if self.spatial and not self.exchange:
+                    raise NotImplementedError(
+                        "packed layout has no D2 (pre-fetched halo) conv form"
+                    )
+                if self.spatial:
+                    _check_window_coverage(kh, kw, sh, sw, ph, pw)
+                # Packed columns fold W into C: the recorded extents cannot be
+                # interpreted as image rows/cols, so geometry consumers refuse.
+                _record_windowed_op("packed", x, kh, kw, sh, sw, ph, pw)
+                from mpi4dl_tpu.ops.packed import PackedConv
 
-            return PackedConv(
+                return PackedConv(
+                    features=self.features,
+                    kernel_size=(kh, kw),
+                    pack_in=self.pack[0],
+                    pack_out=self.pack[1],
+                    strides=(sh, sw),
+                    padding=((ph, ph), (pw, pw)),
+                    use_bias=self.use_bias,
+                    spatial=self.spatial,
+                    dtype=self.dtype,
+                    name="conv",
+                )(x)
+
+            conv = FastConv(
                 features=self.features,
                 kernel_size=(kh, kw),
-                pack_in=self.pack[0],
-                pack_out=self.pack[1],
                 strides=(sh, sw),
-                padding=((ph, ph), (pw, pw)),
+                padding="VALID" if self.spatial else ((ph, ph), (pw, pw)),
                 use_bias=self.use_bias,
-                spatial=self.spatial,
                 dtype=self.dtype,
                 name="conv",
-            )(x)
-
-        conv = FastConv(
-            features=self.features,
-            kernel_size=(kh, kw),
-            strides=(sh, sw),
-            padding="VALID" if self.spatial else ((ph, ph), (pw, pw)),
-            use_bias=self.use_bias,
-            dtype=self.dtype,
-            name="conv",
-        )
-
-        if not self.spatial:
-            _record_windowed_op("conv", x, kh, kw, sh, sw, ph, pw)
-            return conv(x)
-
-        if self.exchange:
-            _check_window_coverage(kh, kw, sh, sw, ph, pw)
-            h_loc, w_loc = x.shape[1], x.shape[2]
-            xe = halo_exchange(x, ph, pw, AXIS_TILE_H, AXIS_TILE_W)
-            impl = self.overlap if self.overlap is not None else (
-                conv_overlap_impl()
             )
-            if impl not in ("monolithic", "decomposed"):
-                raise ValueError(
-                    f"overlap must be monolithic|decomposed, got {impl!r}"
-                )
-            if impl == "decomposed" and (ph or pw):
-                # Interior conv reads the UN-exchanged tile: no data
-                # dependency on the halo ppermutes, so the scheduler can
-                # overlap them; boundary strips consume xe. Flax binds all
-                # calls to the one "conv" submodule, so the param tree is
-                # identical to the monolithic form.
-                y = overlap_decompose(x, xe, conv, kh, kw, sh, sw, ph, pw)
-                if y is not None:
-                    return y
-            # Trim to this tile's share of the global output grid. The first
-            # VALID output aligns with the global grid because tile sizes are
-            # multiples of the stride (power-of-two asserts, config.validate).
-            return conv(xe)[:, : h_loc // sh, : w_loc // sw, :]
 
-        # D2 shrink conv: input already carries a wide halo; VALID conv eats
-        # (k-1) of it per dim. Strided shrink convs are handled by the D2
-        # builder's halo-size formulas.
-        return conv(x)
+            if not self.spatial:
+                _record_windowed_op("conv", x, kh, kw, sh, sw, ph, pw)
+                return conv(x)
+
+            if self.exchange:
+                _check_window_coverage(kh, kw, sh, sw, ph, pw)
+                h_loc, w_loc = x.shape[1], x.shape[2]
+                xe = halo_exchange(x, ph, pw, AXIS_TILE_H, AXIS_TILE_W)
+                impl = self.overlap if self.overlap is not None else (
+                    conv_overlap_impl()
+                )
+                if impl not in ("monolithic", "decomposed"):
+                    raise ValueError(
+                        f"overlap must be monolithic|decomposed, got {impl!r}"
+                    )
+                if impl == "decomposed" and (ph or pw):
+                    # Interior conv reads the UN-exchanged tile: no data
+                    # dependency on the halo ppermutes, so the scheduler can
+                    # overlap them; boundary strips consume xe. Flax binds all
+                    # calls to the one "conv" submodule, so the param tree is
+                    # identical to the monolithic form.
+                    y = overlap_decompose(x, xe, conv, kh, kw, sh, sw, ph, pw)
+                    if y is not None:
+                        return y
+                # Trim to this tile's share of the global output grid. The first
+                # VALID output aligns with the global grid because tile sizes are
+                # multiples of the stride (power-of-two asserts, config.validate).
+                return conv(xe)[:, : h_loc // sh, : w_loc // sw, :]
+
+            # D2 shrink conv: input already carries a wide halo; VALID conv eats
+            # (k-1) of it per dim. Strided shrink convs are handled by the D2
+            # builder's halo-size formulas.
+            return conv(x)
 
 
 def max_pool_s1_valid(x, kh: int, kw: int):
@@ -503,99 +506,100 @@ class Pool(nn.Module):
         ph, pw = _pair(self.padding)
         h_loc, w_loc = x.shape[1], x.shape[2]
 
-        if self.spatial:
-            # Applies to the padding==0 case too (e.g. kernel 3 stride 2
-            # padding 0 would silently drop cross-boundary windows).
-            _check_window_coverage(kh, kw, sh, sw, ph, pw)
-        if self.spatial and (ph or pw):
-            fill = float("-inf") if self.kind == "max" else 0.0
-            if self.kind == "avg" and not self.count_include_pad:
-                # Monolithic only: the mask-ratio form below couples the
-                # numerator and divisor pools to one exchanged layout; the
-                # overlap decomposition covers the fill-value forms.
-                # Exact distributed count_include_pad=False: average = ratio
-                # of two sum-pools. The divisor pool runs on a validity mask
-                # built LOCALLY from tile position (ones, zeroed on the
-                # outside-image ring of global-boundary tiles) — no second
-                # exchange needed; boundary windows then divide by the true
-                # (unpadded) element count at any tile position.
-                xe = halo_exchange(x, ph, pw, AXIS_TILE_H, AXIS_TILE_W)
-                ones = zero_boundary_halo(
-                    jnp.ones_like(xe), ph, pw, AXIS_TILE_H, AXIS_TILE_W
-                )
-                num = lax.reduce_window(
-                    xe, 0.0, lax.add, (1, kh, kw, 1), (1, sh, sw, 1), "valid"
-                )
-                den = lax.reduce_window(
-                    ones, 0.0, lax.add, (1, kh, kw, 1), (1, sh, sw, 1), "valid"
-                )
-                y = num / den
-                return y[:, : h_loc // sh, : w_loc // sw, :]
-            exchanged = True
-            pad = ((0, 0), (0, 0))
-        else:
-            exchanged = False
-            pad = ((ph, ph), (pw, pw))
+        with jax.named_scope("mpi4dl_pool"):
+            if self.spatial:
+                # Applies to the padding==0 case too (e.g. kernel 3 stride 2
+                # padding 0 would silently drop cross-boundary windows).
+                _check_window_coverage(kh, kw, sh, sw, ph, pw)
+            if self.spatial and (ph or pw):
+                fill = float("-inf") if self.kind == "max" else 0.0
+                if self.kind == "avg" and not self.count_include_pad:
+                    # Monolithic only: the mask-ratio form below couples the
+                    # numerator and divisor pools to one exchanged layout; the
+                    # overlap decomposition covers the fill-value forms.
+                    # Exact distributed count_include_pad=False: average = ratio
+                    # of two sum-pools. The divisor pool runs on a validity mask
+                    # built LOCALLY from tile position (ones, zeroed on the
+                    # outside-image ring of global-boundary tiles) — no second
+                    # exchange needed; boundary windows then divide by the true
+                    # (unpadded) element count at any tile position.
+                    xe = halo_exchange(x, ph, pw, AXIS_TILE_H, AXIS_TILE_W)
+                    ones = zero_boundary_halo(
+                        jnp.ones_like(xe), ph, pw, AXIS_TILE_H, AXIS_TILE_W
+                    )
+                    num = lax.reduce_window(
+                        xe, 0.0, lax.add, (1, kh, kw, 1), (1, sh, sw, 1), "valid"
+                    )
+                    den = lax.reduce_window(
+                        ones, 0.0, lax.add, (1, kh, kw, 1), (1, sh, sw, 1), "valid"
+                    )
+                    y = num / den
+                    return y[:, : h_loc // sh, : w_loc // sw, :]
+                exchanged = True
+                pad = ((0, 0), (0, 0))
+            else:
+                exchanged = False
+                pad = ((ph, ph), (pw, pw))
 
-        def apply_pool(t, pad):
-            if self.kind == "max":
-                if (sh, sw) == (1, 1):
-                    # Stride-1: shifted-maximum decomposition (cheap
-                    # backward; see max_pool_s1_valid). -inf edge pad ==
-                    # torch MaxPool2d. Strided pools deliberately stay on
-                    # reduce_window: slicing the s1 maxima by the stride is
-                    # forward-identical but measured a 22% END-TO-END
-                    # REGRESSION on AmoebaNet@1024 (6.37 -> 4.94 img/s) —
-                    # the full-resolution maximum tree + its full-res
-                    # backward select chain costs far more than the
-                    # select_and_scatter it removes (docs/PERF.md round 3).
-                    if pad != ((0, 0), (0, 0)):
-                        t = lax.pad(
-                            t,
-                            jnp.asarray(float("-inf"), t.dtype),
-                            ((0, 0, 0), (*pad[0], 0), (*pad[1], 0), (0, 0, 0)),
-                        )
-                    return max_pool_s1_valid(t, kh, kw)
-                return nn.max_pool(t, (kh, kw), strides=(sh, sw), padding=pad)
-            if self.kind == "avg":
-                return nn.avg_pool(
-                    t,
-                    (kh, kw),
-                    strides=(sh, sw),
-                    padding=pad,
+            def apply_pool(t, pad):
+                if self.kind == "max":
+                    if (sh, sw) == (1, 1):
+                        # Stride-1: shifted-maximum decomposition (cheap
+                        # backward; see max_pool_s1_valid). -inf edge pad ==
+                        # torch MaxPool2d. Strided pools deliberately stay on
+                        # reduce_window: slicing the s1 maxima by the stride is
+                        # forward-identical but measured a 22% END-TO-END
+                        # REGRESSION on AmoebaNet@1024 (6.37 -> 4.94 img/s) —
+                        # the full-resolution maximum tree + its full-res
+                        # backward select chain costs far more than the
+                        # select_and_scatter it removes (docs/PERF.md round 3).
+                        if pad != ((0, 0), (0, 0)):
+                            t = lax.pad(
+                                t,
+                                jnp.asarray(float("-inf"), t.dtype),
+                                ((0, 0, 0), (*pad[0], 0), (*pad[1], 0), (0, 0, 0)),
+                            )
+                        return max_pool_s1_valid(t, kh, kw)
+                    return nn.max_pool(t, (kh, kw), strides=(sh, sw), padding=pad)
+                if self.kind == "avg":
+                    return nn.avg_pool(
+                        t,
+                        (kh, kw),
+                        strides=(sh, sw),
+                        padding=pad,
+                        count_include_pad=self.count_include_pad,
+                    )
+                raise ValueError(f"unknown pool kind {self.kind!r}")
+
+            if not exchanged:
+                _record_windowed_op(
+                    "pool", x, kh, kw, sh, sw, ph, pw,
+                    pool_kind=self.kind,
                     count_include_pad=self.count_include_pad,
                 )
-            raise ValueError(f"unknown pool kind {self.kind!r}")
+                return apply_pool(x, pad)
 
-        if not exchanged:
-            _record_windowed_op(
-                "pool", x, kh, kw, sh, sw, ph, pw,
-                pool_kind=self.kind,
-                count_include_pad=self.count_include_pad,
+            xe = halo_exchange(x, ph, pw, AXIS_TILE_H, AXIS_TILE_W, fill_value=fill)
+            impl = self.overlap if self.overlap is not None else (
+                conv_overlap_impl()
             )
-            return apply_pool(x, pad)
-
-        xe = halo_exchange(x, ph, pw, AXIS_TILE_H, AXIS_TILE_W, fill_value=fill)
-        impl = self.overlap if self.overlap is not None else (
-            conv_overlap_impl()
-        )
-        if impl not in ("monolithic", "decomposed"):
-            raise ValueError(
-                f"overlap must be monolithic|decomposed, got {impl!r}"
-            )
-        if impl == "decomposed":
-            # Same interior/boundary split as the spatial conv: the
-            # interior pool needs no neighbor data (windows that touch the
-            # halo — fill included — live in the boundary strips, which
-            # slice xe and so see the exact monolithic bytes).
-            y = overlap_decompose(
-                x, xe, lambda t: apply_pool(t, ((0, 0), (0, 0))),
-                kh, kw, sh, sw, ph, pw,
-            )
-            if y is not None:
-                return y
-        y = apply_pool(xe, ((0, 0), (0, 0)))
-        return y[:, : h_loc // sh, : w_loc // sw, :]
+            if impl not in ("monolithic", "decomposed"):
+                raise ValueError(
+                    f"overlap must be monolithic|decomposed, got {impl!r}"
+                )
+            if impl == "decomposed":
+                # Same interior/boundary split as the spatial conv: the
+                # interior pool needs no neighbor data (windows that touch the
+                # halo — fill included — live in the boundary strips, which
+                # slice xe and so see the exact monolithic bytes).
+                y = overlap_decompose(
+                    x, xe, lambda t: apply_pool(t, ((0, 0), (0, 0))),
+                    kh, kw, sh, sw, ph, pw,
+                )
+                if y is not None:
+                    return y
+            y = apply_pool(xe, ((0, 0), (0, 0)))
+            return y[:, : h_loc // sh, : w_loc // sw, :]
 
 
 class HaloExchange(nn.Module):
@@ -629,7 +633,8 @@ class Dense(nn.Module):
     @nn.compact
     def __call__(self, x):
         x = x.reshape((x.shape[0], -1))
-        return nn.Dense(self.features, dtype=self.dtype, name="fc")(x)
+        with jax.named_scope(conv_scope(1, 1)):
+            return nn.Dense(self.features, dtype=self.dtype, name="fc")(x)
 
 
 class Sequential(nn.Module):

@@ -342,32 +342,33 @@ class PackedTrainBatchNorm(nn.Module):
         c = fc // self.pack
         scale = self.param("scale", nn.initializers.ones_init(), (c,), jnp.float32)
         bias = self.param("bias", nn.initializers.zeros_init(), (c,), jnp.float32)
-        if current_bn_mode() == "running":
-            # Frozen calibration stats (mpi4dl_tpu/evaluate.py) — logical
-            # [C], tiled over the subpixel axis like w/b below.
-            mean = self.variable(
-                "batch_stats", "mean", jnp.zeros, (c,), jnp.float32
-            ).value
-            var = self.variable(
-                "batch_stats", "var", jnp.ones, (c,), jnp.float32
-            ).value
+        with jax.named_scope("mpi4dl_batchnorm"):
+            if current_bn_mode() == "running":
+                # Frozen calibration stats (mpi4dl_tpu/evaluate.py) — logical
+                # [C], tiled over the subpixel axis like w/b below.
+                mean = self.variable(
+                    "batch_stats", "mean", jnp.zeros, (c,), jnp.float32
+                ).value
+                var = self.variable(
+                    "batch_stats", "var", jnp.ones, (c,), jnp.float32
+                ).value
+                w = (lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
+                b = (bias - mean * lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
+                return x * jnp.tile(w, self.pack) + jnp.tile(b, self.pack)
+            # Moments over the leading axes per PACKED channel
+            # (layers.bn_moments), then averaged over the pack groups
+            # (equal group sizes: mean of group means == pooled mean).
+            from mpi4dl_tpu.ops.layers import bn_moments
+
+            m_pc, msq_pc = bn_moments(x)
+            mean = m_pc.reshape(self.pack, c).mean(0)
+            mean_sq = msq_pc.reshape(self.pack, c).mean(0)
+            if self.reduce_axes:
+                mean = lax.pmean(mean, self.reduce_axes)
+                mean_sq = lax.pmean(mean_sq, self.reduce_axes)
+            if current_bn_mode() == "collect":
+                _accumulate_bn_stats(self, mean, mean_sq)
+            var = mean_sq - jnp.square(mean)
             w = (lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
             b = (bias - mean * lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
             return x * jnp.tile(w, self.pack) + jnp.tile(b, self.pack)
-        # Moments over the leading axes per PACKED channel
-        # (layers.bn_moments), then averaged over the pack groups
-        # (equal group sizes: mean of group means == pooled mean).
-        from mpi4dl_tpu.ops.layers import bn_moments
-
-        m_pc, msq_pc = bn_moments(x)
-        mean = m_pc.reshape(self.pack, c).mean(0)
-        mean_sq = msq_pc.reshape(self.pack, c).mean(0)
-        if self.reduce_axes:
-            mean = lax.pmean(mean, self.reduce_axes)
-            mean_sq = lax.pmean(mean_sq, self.reduce_axes)
-        if current_bn_mode() == "collect":
-            _accumulate_bn_stats(self, mean, mean_sq)
-        var = mean_sq - jnp.square(mean)
-        w = (lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
-        b = (bias - mean * lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
-        return x * jnp.tile(w, self.pack) + jnp.tile(b, self.pack)

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import contextlib
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -110,10 +111,11 @@ def gather_tiles(x, axis_h: str = "tile_h", axis_w: str = "tile_w"):
     exactly the reference's row-major tile layout (``split_input``,
     ``train_spatial.py:241-290``).
     """
-    if axis_size(axis_h) > 1:
-        x = lax.all_gather(x, axis_h, axis=1, tiled=True)
-    if axis_size(axis_w) > 1:
-        x = lax.all_gather(x, axis_w, axis=2, tiled=True)
+    with jax.named_scope("mpi4dl_halo"):
+        if axis_size(axis_h) > 1:
+            x = lax.all_gather(x, axis_h, axis=1, tiled=True)
+        if axis_size(axis_w) > 1:
+            x = lax.all_gather(x, axis_w, axis=2, tiled=True)
     return x
 
 
@@ -154,24 +156,25 @@ def halo_exchange(
             strip,
         )
 
-    if halo_h > 0:
-        if halo_h > h:
-            raise ValueError(f"halo_h={halo_h} exceeds local tile height {h}")
-        # Neighbor above sends its bottom strip down (+1); neighbor below
-        # sends its top strip up (-1).
-        from_above = _shift(x[:, h - halo_h :, :, :], axis_h, +1)
-        from_below = _shift(x[:, :halo_h, :, :], axis_h, -1)
-        from_above = _edge_fill(from_above, axis_h, 0)
-        from_below = _edge_fill(from_below, axis_h, axis_size(axis_h) - 1)
-        x = jnp.concatenate([from_above, x, from_below], axis=1)
-    if halo_w > 0:
-        if halo_w > w:
-            raise ValueError(f"halo_w={halo_w} exceeds local tile width {w}")
-        from_left = _shift(x[:, :, w - halo_w :, :], axis_w, +1)
-        from_right = _shift(x[:, :, :halo_w, :], axis_w, -1)
-        from_left = _edge_fill(from_left, axis_w, 0)
-        from_right = _edge_fill(from_right, axis_w, axis_size(axis_w) - 1)
-        x = jnp.concatenate([from_left, x, from_right], axis=2)
+    with jax.named_scope("mpi4dl_halo"):
+        if halo_h > 0:
+            if halo_h > h:
+                raise ValueError(f"halo_h={halo_h} exceeds local tile height {h}")
+            # Neighbor above sends its bottom strip down (+1); neighbor below
+            # sends its top strip up (-1).
+            from_above = _shift(x[:, h - halo_h :, :, :], axis_h, +1)
+            from_below = _shift(x[:, :halo_h, :, :], axis_h, -1)
+            from_above = _edge_fill(from_above, axis_h, 0)
+            from_below = _edge_fill(from_below, axis_h, axis_size(axis_h) - 1)
+            x = jnp.concatenate([from_above, x, from_below], axis=1)
+        if halo_w > 0:
+            if halo_w > w:
+                raise ValueError(f"halo_w={halo_w} exceeds local tile width {w}")
+            from_left = _shift(x[:, :, w - halo_w :, :], axis_w, +1)
+            from_right = _shift(x[:, :, :halo_w, :], axis_w, -1)
+            from_left = _edge_fill(from_left, axis_w, 0)
+            from_right = _edge_fill(from_right, axis_w, axis_size(axis_w) - 1)
+            x = jnp.concatenate([from_left, x, from_right], axis=2)
     return x
 
 
@@ -195,22 +198,23 @@ def fill_boundary_halo(
     ``value``: 0 for convs / zero-pad pools, ``-inf`` for max pools.
     """
     b, h, w, c = x.shape
-    if halo_h:
-        idx = lax.axis_index(axis_h)
-        n = axis_size(axis_h)
-        row = jnp.arange(h)
-        outside = ((idx == 0) & (row < halo_h)) | (
-            (idx == n - 1) & (row >= h - halo_h)
-        )
-        x = jnp.where(outside[None, :, None, None], value, x)
-    if halo_w:
-        idx = lax.axis_index(axis_w)
-        n = axis_size(axis_w)
-        col = jnp.arange(w)
-        outside = ((idx == 0) & (col < halo_w)) | (
-            (idx == n - 1) & (col >= w - halo_w)
-        )
-        x = jnp.where(outside[None, None, :, None], value, x)
+    with jax.named_scope("mpi4dl_halo"):
+        if halo_h:
+            idx = lax.axis_index(axis_h)
+            n = axis_size(axis_h)
+            row = jnp.arange(h)
+            outside = ((idx == 0) & (row < halo_h)) | (
+                (idx == n - 1) & (row >= h - halo_h)
+            )
+            x = jnp.where(outside[None, :, None, None], value, x)
+        if halo_w:
+            idx = lax.axis_index(axis_w)
+            n = axis_size(axis_w)
+            col = jnp.arange(w)
+            outside = ((idx == 0) & (col < halo_w)) | (
+                (idx == n - 1) & (col >= w - halo_w)
+            )
+            x = jnp.where(outside[None, None, :, None], value, x)
     return x
 
 

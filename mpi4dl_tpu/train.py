@@ -208,6 +208,44 @@ class TrainState:
     step: jax.Array
 
 
+def cell_scope(first: int, last: "int | None" = None) -> str:
+    """The ``jax.named_scope`` the device trace finds a model cell by: its
+    index in ``Trainer.cells``, two digits; a scanned run of stacked cells,
+    which one compiled body serves, is named by its first and last. The
+    trace's readers match by substring (``chipbench/harness/
+    step_classes.py``), so neither name holds the other."""
+    if last is None:
+        return f"mpi4dl_cell{first:02d}"
+    return f"mpi4dl_cells{first:02d}to{last:02d}"
+
+
+def _argument_shape(a) -> jax.ShapeDtypeStruct:
+    """An argument without its buffer: shape, dtype and sharding."""
+    return jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=getattr(a, "sharding", None))
+
+
+def _same_arguments(kept, asked) -> bool:
+    """Whether two trees of :func:`_argument_shape` describe the same call:
+    the same structure and, leaf by leaf, shape, dtype and an equivalent
+    sharding (``P()`` and ``P(None)`` place an array alike)."""
+    if kept is None:
+        return False
+    kept, kept_tree = jax.tree.flatten(kept)
+    asked, asked_tree = jax.tree.flatten(asked)
+    if kept_tree != asked_tree:
+        return False
+    for a, b in zip(kept, asked):
+        if a.shape != b.shape or a.dtype != b.dtype:
+            return False
+        if (a.sharding is None) != (b.sharding is None):
+            return False
+        if a.sharding is not None and not a.sharding.is_equivalent_to(
+                b.sharding, len(a.shape)):
+            return False
+    return True
+
+
 def apply_cells(cells: Sequence[Any], params: Sequence[Any], x):
     for cell, p in zip(cells, params):
         x = cell.apply(p, x)
@@ -303,6 +341,8 @@ class Trainer:
         # and the step's counters (``ops.sequence.step_counters``).
         self.last_metrics: dict = {}
         self._jit_step = jax.jit(self._train_step, donate_argnums=0)
+        # what compiled_step last made, and the arguments it made it for
+        self._compiled, self._compiled_for = None, None
         # Host-side step counter for XProf step annotation (profiling.
         # annotate_step): reading state.step would force a device sync.
         self._host_steps = 0
@@ -585,63 +625,66 @@ class Trainer:
         for ckpt, run in zip(ckpts, self._scan_plan):
             if len(run) == 1:
                 i = run[0]
-                if i == self.n_spatial and self.n_spatial > 0:
-                    h = jax.tree.map(gather_tiles, h)
-                h = ckpt(self.cells[i].apply)(params[i], h)
+                with jax.named_scope(cell_scope(i)):
+                    if i == self.n_spatial and self.n_spatial > 0:
+                        h = jax.tree.map(gather_tiles, h)
+                    h = ckpt(self.cells[i].apply)(params[i], h)
                 h = optimization_barrier(h)
                 continue
-            if run[0] == self.n_spatial and self.n_spatial > 0:
-                h = jax.tree.map(gather_tiles, h)
-            stacked = jax.tree.map(
-                lambda *leaves: jnp.stack(leaves), *[params[k] for k in run]
-            )
-            cell = self.cells[run[0]]
-            hc, shapes = self._compact(h)
+            # one body runs every cell of the run: the scope names the run
+            with jax.named_scope(cell_scope(run[0], run[-1])):
+                if run[0] == self.n_spatial and self.n_spatial > 0:
+                    h = jax.tree.map(gather_tiles, h)
+                stacked = jax.tree.map(
+                    lambda *leaves: jnp.stack(leaves), *[params[k] for k in run]
+                )
+                cell = self.cells[run[0]]
+                hc, shapes = self._compact(h)
 
-            def apply_compact(p, hc, cell=cell, shapes=shapes):
-                o = cell.apply(p, self._restore(hc, shapes))
-                # Output compact-shapes equal the input's: the planner only
-                # groups fixed-point cells.
-                return self._compact(o)[0]
+                def apply_compact(p, hc, cell=cell, shapes=shapes):
+                    o = cell.apply(p, self._restore(hc, shapes))
+                    # Output compact-shapes equal the input's: the planner only
+                    # groups fixed-point cells.
+                    return self._compact(o)[0]
 
-            def body(hc, p):
-                return ckpt(apply_compact)(p, hc), None
+                def body(hc, p):
+                    return ckpt(apply_compact)(p, hc), None
 
-            # Unrolling amortizes the scan machinery (parameter
-            # dynamic-slices, carry copies, loop overhead) at the cost of a
-            # proportionally bigger program. Measured on one v5e (docs/
-            # PERF.md round 3): unroll=3 takes AmoebaNet-D @1024 bs2 from
-            # 4.92 to 6.37 img/s (+29%) and @2048 bs1 from 1.09 to 1.27
-            # (+16%); ResNet is neutral (its hot path is cell_save, and its
-            # @2048 scan is recompute-bound, 0.495 -> 0.492). unroll=6
-            # matches unroll=3, so 3 is the default — the smallest program
-            # that captures the win. MPI4DL_TPU_SCAN_UNROLL overrides.
-            unroll = scan_unroll()
-            if (
-                self.remat == "scanq"
-                and len(run) >= 3
-                and ckpt is not _no_ckpt
-                and not self._scanq_store_granted(run, params, x)
-            ):
-                # Anchored-quadratic backward: O(1) live boundaries per
-                # run (the >3072px policy — chain_quadratic docstring).
-                # Short runs stay on the plain checkpointed scan: the
-                # masked-sweep machinery only pays past ~2 cells.
-                hc = chain_quadratic(apply_compact, stacked, hc)
-                hc = optimization_barrier(hc)
-            elif (
-                self.remat == "scan2"
-                and len(run) >= 4
-                and ckpt is not _no_ckpt
-            ):
-                # A _nockpt_grants grant overrides the nesting: the whole
-                # point of the no-checkpoint tier is to store residuals and
-                # replay nothing, which the plain scan body below (with
-                # ckpt == _no_ckpt) does.
-                hc = self._scan_nested(hc, stacked, apply_compact)
-            else:
-                hc, _ = lax.scan(body, hc, stacked, unroll=unroll)
-            h = self._restore(hc, shapes)
+                # Unrolling amortizes the scan machinery (parameter
+                # dynamic-slices, carry copies, loop overhead) at the cost of a
+                # proportionally bigger program. Measured on one v5e (docs/
+                # PERF.md round 3): unroll=3 takes AmoebaNet-D @1024 bs2 from
+                # 4.92 to 6.37 img/s (+29%) and @2048 bs1 from 1.09 to 1.27
+                # (+16%); ResNet is neutral (its hot path is cell_save, and its
+                # @2048 scan is recompute-bound, 0.495 -> 0.492). unroll=6
+                # matches unroll=3, so 3 is the default — the smallest program
+                # that captures the win. MPI4DL_TPU_SCAN_UNROLL overrides.
+                unroll = scan_unroll()
+                if (
+                    self.remat == "scanq"
+                    and len(run) >= 3
+                    and ckpt is not _no_ckpt
+                    and not self._scanq_store_granted(run, params, x)
+                ):
+                    # Anchored-quadratic backward: O(1) live boundaries per
+                    # run (the >3072px policy — chain_quadratic docstring).
+                    # Short runs stay on the plain checkpointed scan: the
+                    # masked-sweep machinery only pays past ~2 cells.
+                    hc = chain_quadratic(apply_compact, stacked, hc)
+                    hc = optimization_barrier(hc)
+                elif (
+                    self.remat == "scan2"
+                    and len(run) >= 4
+                    and ckpt is not _no_ckpt
+                ):
+                    # A _nockpt_grants grant overrides the nesting: the whole
+                    # point of the no-checkpoint tier is to store residuals and
+                    # replay nothing, which the plain scan body below (with
+                    # ckpt == _no_ckpt) does.
+                    hc = self._scan_nested(hc, stacked, apply_compact)
+                else:
+                    hc, _ = lax.scan(body, hc, stacked, unroll=unroll)
+                h = self._restore(hc, shapes)
         return h
 
     def _scanq_store_granted(self, run, params, x) -> bool:
@@ -707,9 +750,10 @@ class Trainer:
         """Apply cell ``i`` (inserting the SP→LP tile merge before cell
         ``n_spatial``) — the one definition of the merge point, shared by
         every remat policy."""
-        if i == self.n_spatial and self.n_spatial > 0:
-            h = jax.tree.map(gather_tiles, h)
-        return self.cells[i].apply(p, h)
+        with jax.named_scope(cell_scope(i)):
+            if i == self.n_spatial and self.n_spatial > 0:
+                h = jax.tree.map(gather_tiles, h)
+            return self.cells[i].apply(p, h)
 
     def _apply_cells_scanlog(self, params, x):
         """remat="scanlog": logarithmic recursive checkpointing over the
@@ -903,9 +947,10 @@ class Trainer:
         def run_cell(i, p, h):
             cell = self.cells[i]
             collection = getattr(cell, "counters", None)
-            if collection is None:
-                return cell.apply(p, h), {}
-            y, sown = cell.apply(p, h, mutable=[collection])
+            with jax.named_scope(cell_scope(i)):
+                if collection is None:
+                    return cell.apply(p, h), {}
+                y, sown = cell.apply(p, h, mutable=[collection])
             return y, sown.get(collection, {})
 
         counted: dict = {}
@@ -943,8 +988,10 @@ class Trainer:
         global_labels = y.size * d
         denom = global_labels * replicas
         axes = (AXIS_DATA, AXIS_TILE_H, AXIS_TILE_W)
-        loss = lax.psum(cross_entropy_sum(logits, y) / denom, axes)
-        acc = lax.psum(correct_count(logits, y).astype(jnp.float32) / denom, axes)
+        with jax.named_scope("mpi4dl_loss"):
+            loss = lax.psum(cross_entropy_sum(logits, y) / denom, axes)
+            acc = lax.psum(
+                correct_count(logits, y).astype(jnp.float32) / denom, axes)
         return loss, (acc, counted)
 
     def _sharded_loss(self, params, x, y):
@@ -970,8 +1017,10 @@ class Trainer:
                 loss_fn, has_aux=True)(state.params)
         else:  # the chunks' counters are not kept
             loss, acc, grads = self._accum_grads(state.params, x, y)
-        updates, opt_state = self.tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("mpi4dl_optimizer"):
+            updates, opt_state = self.tx.update(
+                grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         new_state = TrainState(
             params=params, opt_state=opt_state, step=state.step + 1
         )
@@ -1224,8 +1273,13 @@ class Trainer:
         ``jax.ShapeDtypeStruct``s with their shardings); its ``as_text()``
         names every HLO instruction with the jax name stack it came from.
         For a step the process already ran this is one more trace and a
-        cache hit."""
-        return self._jit_step.lower(state, x, y).compile()
+        cache hit; the object is kept, so asking again for arguments of the
+        same shapes and shardings costs nothing."""
+        asked = jax.tree.map(_argument_shape, (state, x, y))
+        if not _same_arguments(self._compiled_for, asked):
+            self._compiled = self._jit_step.lower(state, x, y).compile()
+            self._compiled_for = asked
+        return self._compiled
 
     def record_memory_footprint(
         self, state, x, y, ledger=None, registry=None,
@@ -1233,15 +1287,16 @@ class Trainer:
     ) -> dict:
         """Record the compiled train step's predicted peak into a
         :class:`~mpi4dl_tpu.telemetry.memory.FootprintLedger` (a fresh
-        one when none is given). ``lower().compile()`` is a warm-cache
-        no-op for a step the process already traced, so calling this
-        after training costs no extra compile; before any execution it
-        is the feasibility planner's compile-only prediction."""
+        one when none is given), from :meth:`compiled_step`'s object: one
+        more trace and a warm-cache compile for a step the process already
+        ran, nothing where that object is already kept; before any
+        execution it is the feasibility planner's compile-only
+        prediction."""
         from mpi4dl_tpu.telemetry.memory import FootprintLedger
 
         if ledger is None:
             ledger = FootprintLedger(registry=registry)
-        return ledger.record_lowered(program, self._jit_step, state, x, y)
+        return ledger.record_compiled(program, self.compiled_step(state, x, y))
 
 
 def single_device_step(cells: Sequence[Any], learning_rate=0.001, momentum=0.9, parts=1):

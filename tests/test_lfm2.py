@@ -452,6 +452,10 @@ def test_the_benchmarks_run_passes_the_program_and_fails_the_fp8_control(tmp_pat
         assert carried > 10, scope  # forward, recomputed forward and backward
         assert readers[name](context) == pytest.approx(
             (carried + (name == "moe_ms")) / 2)
+    # PR 41: every cell, the optimiser and the loss under names of their own
+    from step_scope_checks import check_step_scopes
+
+    check_step_scopes(context, op_names, session.trainer)
     context["trainer"] = object()  # a program without ``compiled_step``, as the parent
     del context["_step_op_names"]
     assert readers["attn_ms"](context) is None

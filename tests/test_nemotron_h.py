@@ -506,6 +506,10 @@ def test_the_benchmarks_run_passes_the_program_and_fails_the_fp8_control(tmp_pat
     # a scope inside another is a part of it
     assert read["ssd_scan_ms"] < read["mamba_mixer_ms"]
     assert read["nemotron_shared_expert_ms"] < read["nemotron_moe_ms"]
+    # PR 41: every cell, the optimiser and the loss under names of their own
+    from step_scope_checks import check_step_scopes
+
+    check_step_scopes(context, op_names, session.trainer)
     context["trainer"] = object()  # a program without ``compiled_step``, as the parent
     del context["_step_op_names"]
     assert all(readers[name](context) is None for name in BY_SCOPE)
