@@ -147,12 +147,13 @@ _FORMS = {"nn": ((1,), (0,)), "nt": ((1,), (1,)), "tn": ((0,), (0,))}  # a @ b, 
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _dot(a, b, form="nn"):
+def dot(a, b, form="nn"):
     """``a @ b`` ("nn"), ``a @ b.T`` ("nt") or ``a.T @ b`` ("tn"): operands
     as they are (bfloat16 in the cell), accumulated in float32. The backward
     rounds the cotangent to the operands' dtype first, which is what the
     plain path's products do on the chip (a float32 operand of a product at
-    default precision is taken in bfloat16)."""
+    default precision is taken in bfloat16). ``ssd_scan_pallas``'s chunk is
+    made of the same products."""
     return lax.dot_general(a, b, (_FORMS[form], ((), ())), preferred_element_type=_F32)
 
 
@@ -160,15 +161,15 @@ def _dot_bwd(form, operands, ct):
     a, b = operands
     ct = ct.astype(a.dtype)
     if form == "nn":
-        da, db = _dot(ct, b, "nt"), _dot(a, ct, "tn")
+        da, db = dot(ct, b, "nt"), dot(a, ct, "tn")
     elif form == "nt":
-        da, db = _dot(ct, b, "nn"), _dot(ct, a, "tn")
+        da, db = dot(ct, b, "nn"), dot(ct, a, "tn")
     else:
-        da, db = _dot(b, ct, "nt"), _dot(a, ct, "nn")
+        da, db = dot(b, ct, "nt"), dot(a, ct, "nn")
     return da.astype(a.dtype), db.astype(b.dtype)
 
 
-_dot.defvjp(lambda a, b, form: (_dot(a, b, form), (a, b)), _dot_bwd)
+dot.defvjp(lambda a, b, form: (dot(a, b, form), (a, b)), _dot_bwd)
 
 
 def _exact(a, b, form="nn"):
@@ -248,7 +249,7 @@ def _chunk(q, k, values, rows, states, solves=None, *, dtype):
     diagonal, strictly = at_row == at_col, at_row > at_col
     last = lax.broadcasted_iota(jnp.int32, (1, size), 1) == size - 1
     qd, kd = q.astype(dtype), k.astype(dtype)
-    kk, qk = _dot(kd, kd, "nt"), _dot(qd, kd, "nt")
+    kk, qk = dot(kd, kd, "nt"), dot(qd, kd, "nt")
 
     def column(row):  # [1, C] -> [C, 1]: exact, no transpose
         return jnp.sum(jnp.where(diagonal, row, 0.0), axis=1, keepdims=True)
@@ -279,15 +280,15 @@ def _chunk(q, k, values, rows, states, solves=None, *, dtype):
         v_beta = (values[r] * beta).astype(dtype)
         k_beta = (k * (beta * grown)).astype(dtype)
         solve_d = solve.astype(dtype)
-        u = _dot(solve_d, v_beta)
-        w = _dot(solve_d, k_beta).astype(dtype)
+        u = dot(solve_d, v_beta)
+        w = dot(solve_d, k_beta).astype(dtype)
         k_end = (k * to_end).astype(dtype)
         q_grown = (q * grown).astype(dtype)
         start = states[r].astype(dtype)
-        fresh = (u - _dot(w, start)).astype(dtype)
+        fresh = (u - dot(w, start)).astype(dtype)
         end = jnp.broadcast_to(jnp.exp(whole), (1, states[r].shape[1]))
-        ends.append(states[r] * end + _dot(k_end, fresh, "tn"))
-        outs.append(_dot(q_grown, start) + _dot(within, fresh))
+        ends.append(states[r] * end + dot(k_end, fresh, "tn"))
+        outs.append(dot(q_grown, start) + dot(within, fresh))
         starts.append(start)
         kept.append(solve)
     return (outs, ends), (starts, kept)
@@ -373,8 +374,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, rows_ref, do_ref, starts_ref, solve_ref,
 # -- layouts and calls -----------------------------------------------------------
 
 
-def _by_chunk(x, chunk):
-    """``[B, S, ...] -> [B, S / chunk, chunk, everything else]``: no copy."""
+def by_chunk(x, chunk):
+    """``[B, S, ...] -> [B, S / chunk, chunk, everything else]``: no copy
+    (``ssd_scan_pallas`` lays its ``b`` and ``c`` out the same way)."""
     return x.reshape(x.shape[0], x.shape[1] // chunk, chunk, -1)
 
 
@@ -445,7 +447,7 @@ def forward(q, k, v, total, beta, chunk, keep=False, interpret=False):
     out, *kept = call(
         in_specs=[spec["keys"], spec["keys"], spec["values"], spec["rows"]],
         out_specs=out_specs, out_shape=out_shape,
-    )(_by_chunk(q, chunk), _by_chunk(k, chunk), _by_chunk(v, chunk), _rows(total, beta, chunk))
+    )(by_chunk(q, chunk), by_chunk(k, chunk), by_chunk(v, chunk), _rows(total, beta, chunk))
     return (out.reshape(v.shape), *kept)
 
 
@@ -464,8 +466,8 @@ def backward(q, k, v, total, beta, starts, solves, d_out, chunk, interpret=False
         out_specs=[spec["keys"], spec["keys"], spec["values"], spec["rows"]],
         out_shape=[keys, keys, jax.ShapeDtypeStruct((b, chunks, chunk, h * r * e), v.dtype),
                    jax.ShapeDtypeStruct((b, h, chunks, 2 * r, chunk), _F32)],
-    )(_by_chunk(q, chunk), _by_chunk(k, chunk), _by_chunk(v, chunk), _rows(total, beta, chunk),
-      _by_chunk(d_out, chunk), starts, solves)
+    )(by_chunk(q, chunk), by_chunk(k, chunk), by_chunk(v, chunk), _rows(total, beta, chunk),
+      by_chunk(d_out, chunk), starts, solves)
     d_total, d_beta = _from_rows(drows, beta.shape)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), d_total, d_beta
 

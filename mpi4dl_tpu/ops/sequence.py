@@ -21,8 +21,10 @@ query rows at a time, each block recomputed in the backward pass. The gated
 delta rule goes the same way: on a TPU, at the shapes
 ``ops/delta_rule_pallas.py`` takes, ``gated_delta_rule`` is that module's
 kernels, which keep a chunk's squares and the carried state in VMEM;
-everywhere else it is ``_chunked_rule``, plain JAX. The rest, Mamba-2's
-chunked scan (``ssd_scan``) included, is plain JAX everywhere.
+everywhere else it is ``_chunked_rule``, plain JAX. Mamba-2's chunked scan
+likewise: ``ssd_scan`` is ``ops/ssd_scan_pallas.py``'s kernels at the shapes
+they take and ``_chunked_scan`` everywhere else. The rest is plain JAX
+everywhere.
 
 Activations are ``[batch, positions, features]``. Each module computes in
 its ``dtype`` (bfloat16 on the chip) with float32 parameters, float32
@@ -47,7 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mpi4dl_tpu.ops import attention_pallas, delta_rule_pallas
+from mpi4dl_tpu.ops import attention_pallas, delta_rule_pallas, ssd_scan_pallas
 
 COUNTERS = "counters"  # the flax collection an expert layer sows its counts into
 RULE_CHUNK = 64        # positions the gated delta rule takes as one triangular system
@@ -411,20 +413,33 @@ def ssd_scan(x, g, b, c, chunk: int):
     by a ``lax.scan`` whose step is one multiply-add of the state; the
     state a chunk starts from adds ``exp(a_i) S_{c-1} C_i``.
 
-    Plain JAX on every backend, the sequences of the batch one after the
-    other (nothing here mixes them), each under ``jax.checkpoint``: a
-    layer's backward pass holds the scan's four inputs while the layer's
-    other parts are differentiated, and one sequence's squares and states
-    (at 8,192 positions, 64 heads and chunks of 128 a float32 copy of the
-    squares is 0.27 GB, of the states 0.13) only while that sequence's scan
-    is; JAX's own backward through the ``lax.scan`` keeps one state a chunk.
+    Where ``ssd_scan_pallas.dispatchable`` says so (TPU backend, not under
+    ``vmap``, ``x, b, c`` bfloat16 and ``g`` float32, heads of whole
+    bfloat16 tiles of 16 channels, a state and a chunk of whole lanes, a
+    length of whole chunks: the Nemotron-H cell's 8 heads of 64 a group,
+    state and chunks of 128) that module's kernels compute it, both passes,
+    the positions along the lanes, a chunk's squares and the group's carried
+    state in VMEM, the backward a reverse sweep of its own that keeps every
+    chunk's start state. Everywhere else (the CPU,
+    the tier-1 tests, the tiny cut, ``vmap``, float32) ``_chunked_scan``,
+    plain JAX and the same arithmetic, which is also the kernels' oracle:
+    the sequences of the batch one after the other (nothing here mixes
+    them), each under ``jax.checkpoint``: a layer's backward pass holds the
+    scan's four inputs while the layer's other parts are differentiated, and
+    one sequence's squares and states (at 8,192 positions, 64 heads and
+    chunks of 128 a float32 copy of the squares is 0.27 GB, of the states
+    0.13) only while that sequence's scan is; JAX's own backward through
+    the ``lax.scan`` keeps one state a chunk.
 
     Matrix products take operands in ``x``'s dtype and accumulate in
     float32; ``a``, every decay and the carried state are float32. Every
     exponent is of a difference ``<= 0``: a strong decay underflows to the 0
-    it is, nothing overflows. A length that is not whole chunks is padded at
-    the end (``x, b, g`` zero there change no state) and cut again."""
+    it is, nothing overflows. On the plain path a length that is not whole
+    chunks is padded at the end (``x, b, g`` zero there change no state) and
+    cut again."""
     with jax.named_scope("ssd_scan"):
+        if ssd_scan_pallas.dispatchable(x, g, b, c, chunk):
+            return ssd_scan_pallas.scan(x, g, b, c, chunk)
         return lax.map(lambda row: _chunked_scan(*row, chunk), (x, g, b, c))
 
 
@@ -461,8 +476,10 @@ class Mamba2(nn.Module):
     ``x, B, C = split(silu(conv(xBC) + b))`` with a causal depthwise
     convolution; ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)`` a
     head, float32; the recurrence ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t
-    B_t^T``, ``y_t = S_t C_t + D x_t`` (``ssd_scan``; head ``h`` reads group
-    ``h // (heads / groups)``'s ``B, C``); then ``RMSNorm(y * silu(z)) * w``
+    B_t^T``, ``y_t = S_t C_t + D x_t`` (``ssd_scan``: on a TPU at the cell's
+    shapes the kernels of ``ops/ssd_scan_pallas.py``, else ``_chunked_scan``,
+    their oracle; head ``h`` reads group ``h // (heads / groups)``'s ``B,
+    C``); then ``RMSNorm(y * silu(z)) * w``
     with the statistics over each group's channels (the gate before the
     norm) and ``out_proj``. No projection has a bias. ``dt``'s columns of
     ``W_in`` are multiplied apart from the rest so that they accumulate into

@@ -116,7 +116,7 @@ def main(argv):
                     drp.unit_lower_inverse = lambda a: jnp.where(
                         drp._iota(CHUNK, 0) == drp._iota(CHUNK, 1), 1.0, 0.0) - a
                 elif word == "default_precision":
-                    drp._exact = lambda a, b, form="nn": drp._dot(a, b, form)
+                    drp._exact = lambda a, b, form="nn": drp.dot(a, b, form)
                 elif word != "kernels":
                     raise ValueError(word)
                 jax.clear_caches()  # ``forward`` / ``backward`` are jitted: trace them anew

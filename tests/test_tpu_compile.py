@@ -31,7 +31,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from mpi4dl_tpu.config import ParallelConfig
-from mpi4dl_tpu.ops import pool_pallas
+from mpi4dl_tpu.ops import pool_pallas, ssd_scan_pallas
 from mpi4dl_tpu.train import Trainer, default_remat
 
 MODELS = ("amoebanet", "amoebanet_sp2x2", "resnet110")
@@ -371,16 +371,22 @@ def _assert_scope_in_both_passes(forward, gradient, scope):
     assert sum(scope in name for name in backward) > 3, scope
 
 
-def _rule_kernels(compiled):
-    """``{kernel name: [op_name of each of its custom calls]}`` for the gated
-    delta rule's kernels in a compiled program's text."""
-    from mpi4dl_tpu.ops import delta_rule_pallas
-
+def _kernel_calls(compiled, start, names):
+    """``{kernel name: [op_name of each of its custom calls]}`` for the kernels
+    whose names begin with ``start`` in a compiled program's text."""
     calls = [line for line in compiled.as_text().splitlines()
-             if "custom-call(" in line and "mpi4dl_delta_rule" in line.split(" = ")[0]]
+             if "custom-call(" in line and start in line.split(" = ")[0]]
     return {name: [re.search(r'op_name="([^"]*)"', line).group(1)
                    for line in calls if name in line.split(" = ")[0]]
-            for name in (delta_rule_pallas.FWD_NAME, delta_rule_pallas.BWD_NAME)}
+            for name in names}
+
+
+def _rule_kernels(compiled):
+    """The gated delta rule's kernels' custom calls (``_kernel_calls``)."""
+    from mpi4dl_tpu.ops import delta_rule_pallas
+
+    return _kernel_calls(compiled, "mpi4dl_delta_rule",
+                         (delta_rule_pallas.FWD_NAME, delta_rule_pallas.BWD_NAME))
 
 
 def test_the_gated_delta_layer_compiles_at_full_width_under_its_scopes(
@@ -439,15 +445,11 @@ def test_the_tiny_cuts_gated_delta_layer_takes_the_plain_path(topo, cache_off, m
 
 
 def _attention_kernels(compiled):
-    """``{kernel name: [op_name of each of its custom calls]}`` for the fused
-    attention kernels in a compiled program's text."""
+    """The fused attention kernels' custom calls (``_kernel_calls``)."""
     from mpi4dl_tpu.ops import attention_pallas
 
-    calls = [line for line in compiled.as_text().splitlines()
-             if "custom-call(" in line and "mpi4dl_attention" in line.split(" = ")[0]]
-    return {name: [re.search(r'op_name="([^"]*)"', line).group(1)
-                   for line in calls if name in line.split(" = ")[0]]
-            for name in (attention_pallas.FWD_NAME, attention_pallas.BWD_NAME)}
+    return _kernel_calls(compiled, "mpi4dl_attention",
+                         (attention_pallas.FWD_NAME, attention_pallas.BWD_NAME))
 
 
 def _assert_attention_dispatched(forward, gradient):
@@ -532,8 +534,14 @@ def _nemotron_mixer(kind):
     }[kind]()
 
 
+def _scan_kernels(compiled):
+    """Mamba-2's scan's kernels' custom calls (``_kernel_calls``)."""
+    return _kernel_calls(compiled, "mpi4dl_ssd_scan",
+                         (ssd_scan_pallas.FWD_NAME, ssd_scan_pallas.BWD_NAME))
+
+
 @pytest.mark.parametrize("kind,scopes,temp_gib", [
-    ("mamba", ("mamba2", "ssd_scan"), 4.0),
+    ("mamba", ("mamba2", "ssd_scan"), 3.6),
     ("attention", ("lfm2_attention",), 1.5),
     ("moe", ("lfm2_moe", "shared_expert"), 1.2),
 ])
@@ -544,16 +552,21 @@ def test_a_nemotron_h_mixer_compiles_at_full_width_under_its_scopes(
     backward, for one described chip, every kernel's gate steered to its TPU
     branch. Attention at head dim 128 and 16 heads a group dispatches the
     fused kernels, two query heads a grid step (PR 40): both are in the
-    compiled text under ``lfm2_attention``. Mamba-2's scan has no kernel and
-    the expert layer none either, so no ``mpi4dl_*`` custom call is in
-    either pass of theirs and what runs is plain JAX under the scopes the
-    benchmark's readers join the trace with. Mamba-2: the scan takes a
-    sequence at a time (a ``while``) and a chunk's float32 squares are never
-    a buffer of both sequences' (1 GiB). The expert layer's share of 8 of 128
-    at 6 a token: 98,304 sorted pair rows, every grouped product over the
-    prefix of 12,288, two products an expert and pass. Temporaries as
-    compiled here, GiB: 3.42 / 1.13 / 0.87 (attention 4.95 on the blocked
-    plain path, before the plan)."""
+    compiled text under ``lfm2_attention``. Mamba-2's scan takes the kernels
+    of ``ops/ssd_scan_pallas.py`` (PR 42; 8 groups of 8 heads of 64, 64
+    chunks of 128 a sequence, positions along the lanes, a chunk's float32
+    squares and the group's carried state in VMEM): ``mpi4dl_ssd_scan_fwd`` is in the forward's text
+    once, the gradient's holds it and ``mpi4dl_ssd_scan_bwd``, each under
+    ``ssd_scan`` (the scope the benchmark's ``ssd_scan_ms`` reads), the
+    state's hand-on is no ``while`` any more and no chunk's squares are a
+    buffer. The expert layer has no kernel: no ``mpi4dl_*`` custom call is in
+    either pass of it and what runs is plain JAX under the scopes the
+    benchmark's readers join the trace with; its share of 8 of 128 at 6 a
+    token: 98,304 sorted pair rows, every grouped product over the prefix of
+    12,288, two products an expert and pass. Temporaries as compiled here,
+    GiB: 3.33 / 1.13 / 0.87 (Mamba-2 3.42 on the plain path, which kept a
+    sequence's squares and states; attention 4.95 on the blocked plain path,
+    before the plan)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     one_chip = SingleDeviceSharding(topo.devices[0])
     dtype = jnp.float32 if kind == "moe" else jnp.bfloat16
@@ -564,6 +577,13 @@ def test_a_nemotron_h_mixer_compiles_at_full_width_under_its_scopes(
     text = gradient.as_text()
     if kind == "attention":
         _assert_attention_dispatched(forward, gradient)
+    elif kind == "mamba":
+        fwd, bwd = ssd_scan_pallas.FWD_NAME, ssd_scan_pallas.BWD_NAME
+        assert {k: len(v) for k, v in _scan_kernels(forward).items()} == {fwd: 1, bwd: 0}
+        kernels = _scan_kernels(gradient)
+        assert {k: len(v) for k, v in kernels.items()} == {fwd: 1, bwd: 1}
+        assert all("ssd_scan" in op for ops in kernels.values() for op in ops), kernels
+        assert "transpose(" in kernels[bwd][0]
     else:
         for compiled in (forward, gradient):  # a kernel's custom call is named after it
             assert not re.search(r"%mpi4dl_\w+ = [^\n]*custom-call\(", compiled.as_text())
@@ -571,7 +591,7 @@ def test_a_nemotron_h_mixer_compiles_at_full_width_under_its_scopes(
     print(kind, "temp GiB", temp / 2**30)
     assert temp < temp_gib * 2**30
     if kind == "mamba":
-        assert " while(" in text
+        assert " while(" not in text  # the hand-on is the kernels' own
         assert not re.search(r"f32\[2,64,128,128,8,8\]", text)
     if kind == "moe":
         products = [line for line in text.splitlines()
@@ -579,3 +599,25 @@ def test_a_nemotron_h_mixer_compiles_at_full_width_under_its_scopes(
         rows = {re.search(r"= \w+\[(\d+),", line).group(1) for line in products}
         # 2 forward + 4 backward, in the prefix and again in the loop of further ranges
         assert len(products) == 12 and rows == {"12288", "8"}, rows  # 8: weight gradients
+
+
+def test_the_tiny_cuts_mamba_layer_takes_the_plain_path(topo, cache_off, monkeypatch):
+    """The tiny cut's Mamba-2 mixer (``chipbench/tests/tiny/nemotron_twotower_
+    30b_a3b_share16.json``: hidden 64, 2 groups of 4 heads of 8, a state of
+    16, chunks of 32, 80 positions) with the gate steered to its TPU branch:
+    a head's 8 channels are no whole bfloat16 tile, a state of 16 and a chunk
+    of 32 are not whole lanes and 80 positions are not whole chunks, so no
+    ``mpi4dl_ssd_scan*`` name is in the compiled text of either pass and the
+    plain chunked scan runs (its hand-on a ``while``) under the same scopes."""
+    from mpi4dl_tpu.ops.sequence import Mamba2
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    forward, gradient = _layer_grad(
+        Mamba2(64, 8, 8, 2, 16, 4, 32, 1e-5),
+        jax.ShapeDtypeStruct((2, 80, 64), jnp.bfloat16), one_chip)
+    for scope in ("mamba2", "ssd_scan"):
+        _assert_scope_in_both_passes(forward, gradient, scope)
+    for compiled in (forward, gradient):
+        assert "mpi4dl_ssd_scan" not in compiled.as_text()
+    assert " while(" in gradient.as_text()
