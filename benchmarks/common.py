@@ -148,7 +148,7 @@ def token_model_args(argv, model: "dict | None" = None):
         help="JSON file of the model's published config.json keys; a chip's "
         "share of a deployment counts what it holds and states the "
         "published values under `cut` (mpi4dl_tpu/models/lfm2.py, qwen3_next.py, "
-        "nemotron_h.py)")
+        "nemotron_h.py, sdar.py)")
     parser.add_argument(
         "--sequence-length", type=int, default=8192,
         help="Tokens in a sequence (one document a sequence)")
@@ -195,15 +195,22 @@ def build_nemotron_h(args, cfg, spatial_cells=0):
     return _token_cells(args, nemotron_h, spatial_cells)
 
 
-def _token_trainer(config: dict, batch_size: int, build_model):
+def build_sdar(args, cfg, spatial_cells=0):
+    """(cells, float32 twin) of the SDAR model ``args.model`` describes."""
+    from mpi4dl_tpu.models.sdar import sdar
+
+    return _token_cells(args, sdar, spatial_cells)
+
+
+def _token_trainer(config: dict, batch_size: int, build_model, loss=None):
     """``(trainer, cfg)`` of a benchmark configuration of a token model (its
     file as a dict: the model's keys and ``entry_point.argv``), built as its
-    entry script builds it."""
+    entry script builds it; ``loss``: the model's own, where it brings one."""
     argv = list(config["entry_point"]["argv"]) + ["--batch-size", str(batch_size)]
     args = token_model_args(argv, model=config)
     cfg = build_config(args, spatial=False)
     cells, plain = build_model(args, cfg)
-    trainer, _ = make_trainer(args, cfg, cells, plain)
+    trainer, _ = make_trainer(args, cfg, cells, plain, loss=loss)
     return trainer, cfg
 
 
@@ -225,6 +232,15 @@ def nemotron_h_trainer(config: dict, batch_size: int):
     return _token_trainer(config, batch_size, build_nemotron_h)
 
 
+def sdar_trainer(config: dict, batch_size: int):
+    """As ``benchmarks/layer_parallelism/benchmark_sdar_lp.py`` builds it
+    (``entry_point.build_trainer``): the model's own loss handed to the
+    trainer."""
+    from mpi4dl_tpu.models.sdar import block_diffusion_loss
+
+    return _token_trainer(config, batch_size, build_sdar, loss=block_diffusion_loss)
+
+
 def token_input_stream(cfg, traffic: dict, seed: int):
     """The program's token pipeline under a benchmark's traffic mix, seeded
     by the run (``entry_point.input_stream``)."""
@@ -238,10 +254,33 @@ def token_input_stream(cfg, traffic: dict, seed: int):
 lfm2_input_stream = token_input_stream  # the name the LFM2 configuration gives
 
 
-def make_trainer(args, cfg, cells, plain_cells, gems: bool = False, n_spatial=None):
+def block_diffusion_input_stream(cfg, traffic: dict, seed: int):
+    """The program's block-diffusion pipeline under a benchmark's traffic
+    mix, seeded by the run (``entry_point.input_stream``)."""
+    from mpi4dl_tpu.data import BlockDiffusionTokens
+
+    return BlockDiffusionTokens(
+        int(traffic["batch_size"]), int(traffic["sequence_length"]),
+        cfg.num_classes, t_min=float(traffic["t_min"]),
+        seed=seed, prefetch=bool(traffic["prefetch"]))
+
+
+def block_diffusion_dataset(args, batch_size, num_classes, shard_id=0, num_shards=1):
+    """``data.get_dataset``'s place in ``run_training`` for a model trained by
+    block diffusion: noisy and clean copies, targets and weights."""
+    from mpi4dl_tpu.data import BlockDiffusionTokens
+
+    return BlockDiffusionTokens(
+        batch_size, args.sequence_length, num_classes, seed=shard_id)
+
+
+def make_trainer(args, cfg, cells, plain_cells, gems: bool = False, n_spatial=None,
+                 loss=None):
     """Build the trainer the config asks for. The single-program
     ``Trainer`` runs under the fixed remat rule (``train.default_remat``);
-    the pipeline trainers checkpoint per stage on their own."""
+    the pipeline trainers checkpoint per stage on their own. ``loss``: the
+    model's own (``train.position_cross_entropy``'s signature); the
+    single-program trainer alone takes one."""
     import jax
 
     from mpi4dl_tpu.parallel import multihost
@@ -258,6 +297,11 @@ def make_trainer(args, cfg, cells, plain_cells, gems: bool = False, n_spatial=No
     # DCN-aware placement on multi-slice systems; identical to
     # cfg.make_mesh() on one slice (multihost.make_multihost_mesh docs).
     mesh = multihost.make_multihost_mesh(cfg)
+    single_program = not gems and (cfg.split_size == 1 or cfg.spatial_size == cfg.split_size)
+    if loss is not None and not single_program:
+        raise ValueError(
+            "a model that brings its own loss trains under the single-program "
+            "Trainer; the pipeline trainers keep the per-position cross-entropy")
     override = n_spatial  # None → trainers derive from config stage bounds
     if n_spatial is None:
         n_spatial = (
@@ -284,7 +328,7 @@ def make_trainer(args, cfg, cells, plain_cells, gems: bool = False, n_spatial=No
             ),
             n_spatial,
         )
-    if cfg.split_size == 1 or cfg.spatial_size == cfg.split_size:
+    if single_program:
         remat = default_remat(cfg.image_size)
         size = (f"{cfg.sequence_length} tokens" if cfg.sequence_length
                 else f"{cfg.image_size}px")
@@ -297,6 +341,7 @@ def make_trainer(args, cfg, cells, plain_cells, gems: bool = False, n_spatial=No
                 plain_cells=plain_cells,
                 mesh=mesh,
                 remat=remat,
+                loss=loss,
             ),
             n_spatial,
         )
@@ -309,10 +354,11 @@ def make_trainer(args, cfg, cells, plain_cells, gems: bool = False, n_spatial=No
     )
 
 
-def run_training(args, trainer, tag: str):
+def run_training(args, trainer, tag: str, dataset=None):
     """Epoch loop with per-step wall-clock timing (ref
     ``benchmark_amoebanet_sp.py:315-367``), optional checkpoint/resume and
-    ``jax.profiler`` tracing (TPU-native additions)."""
+    ``jax.profiler`` tracing (TPU-native additions). ``dataset``: what makes
+    the stream, called as ``data.get_dataset`` (the default) is."""
     import jax
     import jax.numpy as jnp
 
@@ -335,7 +381,7 @@ def run_training(args, trainer, tag: str):
         shard_id, num_shards = data_shard(trainer.mesh)
     else:
         host_batch, shard_id, num_shards = global_batch, 0, 1
-    ds = get_dataset(
+    ds = (dataset or get_dataset)(
         args, host_batch, cfg.num_classes, shard_id=shard_id, num_shards=num_shards
     )
 

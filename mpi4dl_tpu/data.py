@@ -142,6 +142,57 @@ class SyntheticTokens:
         return _batches(self._make_batch, len(self), self.prefetch)
 
 
+class BlockDiffusionTokens:
+    """Deterministic stream for block-diffusion training of a token model
+    (the BD3-LM recipe: a noisy copy of every sequence beside the clean one).
+    From ``(seed, batch index)`` each row draws ``sequence_length`` ids ``x0``
+    uniformly over the vocabulary less ``mask_id`` (default: the last id,
+    never drawn as data), a noise level ``t`` uniform over ``[t_min, 1]``
+    (the linear schedule: a position is masked with probability ``t``) and
+    the positions masked; a row that masked none masks one drawn position,
+    so that every sequence carries loss. One document a sequence.
+
+    ``x`` int32 ``[batch, 2 sequence_length]``: ``x0`` with ``mask_id`` at
+    the masked positions, then ``x0`` itself. ``y`` int32 ``[batch,
+    sequence_length, 2]``: at every position the token to predict (``x0``:
+    no shift) and the BITS of its float32 weight, ``1 / t`` where the
+    position is masked and 0 where it is not (``y[..., 1].view(float32)``;
+    labels travel as one integer array and a weight must arrive exactly).
+    Prefetched like :class:`SyntheticImages`."""
+
+    def __init__(self, batch_size, sequence_length, vocab_size, mask_id=None,
+                 t_min=1e-3, length=60000, seed=0, prefetch=True):
+        self.batch_size = batch_size
+        self.sequence_length = sequence_length
+        self.vocab_size = vocab_size
+        self.mask_id = vocab_size - 1 if mask_id is None else mask_id
+        self.t_min = t_min
+        self.length = length
+        self.seed = seed
+        self.prefetch = prefetch
+
+    def __len__(self):
+        return max(self.length // self.batch_size, 1)
+
+    def _make_batch(self, i):
+        rng = np.random.default_rng(np.random.SeedSequence((self.seed, i)))
+        shape = (self.batch_size, self.sequence_length)
+        x0 = rng.integers(0, self.vocab_size - 1, shape, dtype=np.int32)
+        x0 += x0 >= self.mask_id  # every id but the mask's
+        t = self.t_min + (1.0 - self.t_min) * rng.random(self.batch_size)
+        t = t.astype(np.float32)
+        masked = rng.random(shape) < t[:, None]
+        one = rng.integers(0, self.sequence_length, self.batch_size)
+        bare = ~masked.any(axis=1)
+        masked[bare, one[bare]] = True
+        weight = np.where(masked, 1 / t[:, None], 0).astype(np.float32)
+        x = np.concatenate([np.where(masked, np.int32(self.mask_id), x0), x0], axis=1)
+        return x, np.stack([x0, weight.view(np.int32)], axis=-1)
+
+    def __iter__(self):
+        return _batches(self._make_batch, len(self), self.prefetch)
+
+
 class ClassPatternImages:
     """Learnable deterministic dataset: each class has a fixed smooth
     pattern template, each sample = its class template + Gaussian noise.

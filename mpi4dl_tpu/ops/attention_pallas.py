@@ -130,6 +130,19 @@ runs at 78% / 64% of the peak over the work it executes and the backward at
 lanes the kernels could read q and d_out row-major (``dot_general`` takes
 the transposed operand), which LFM2's 64 does not allow.
 
+Under a ``BlockMask`` (PR 43: block-diffusion training, a noisy copy of the
+sequence beside the clean one, ``2 L`` rows) the same step bodies
+(``_fwd_step``, ``_bwd_step``) run in kernels of their own,
+``mpi4dl_blockdiff_attention_fwd`` / ``_bwd``, whose walk is the mask's: a
+block of queries takes its own block of keys first (every row sees itself
+there, so the running maximum is finite from the start), then the clean
+blocks before its place whole, and a noisy block the clean block at its place
+under the strict mask; the backward the mirror image from a block of keys.
+Nothing the mask empties is touched: the clean-to-noisy quadrant, every
+off-diagonal noisy block, everything past the place: the work of two causal
+sequences of ``L``, not of one of ``2 L``. The plan counts ``2 L`` rows of
+residents, so 16,384 rows at head dim 128 run one query head a grid step.
+
 Dispatch (``dispatchable``): TPU backend, not under ``vmap``, and a plan
 (``plan_for``: bfloat16, head dim 64, 128 or 256, a length of whole blocks,
 a step's heads within VMEM); everything else takes the plain path, which is
@@ -154,6 +167,10 @@ from jax.experimental.pallas import tpu as pltpu
 # common start, ``mpi4dl_attention``).
 FWD_NAME = "mpi4dl_attention_fwd"
 BWD_NAME = "mpi4dl_attention_bwd"
+# the same two under the block-diffusion mask (names that do not hold the
+# causal kernels' common start: each reader finds its own)
+BLOCKDIFF_FWD_NAME = "mpi4dl_blockdiff_attention_fwd"
+BLOCKDIFF_BWD_NAME = "mpi4dl_blockdiff_attention_bwd"
 HEAD_DIMS = (64, 128, 256)
 BLOCKS = (512, 256, 128)
 _VMEM_LIMIT = 64 * 1024 * 1024
@@ -168,6 +185,19 @@ class Plan(NamedTuple):
     heads: int  # query heads of a group a grid step takes side by side
 
 
+class BlockMask(NamedTuple):
+    """The mask of block-diffusion training over ``2 x length`` rows: rows
+    ``[0, length)`` are the noisy copy of a sequence, rows ``[length, 2 x
+    length)`` the clean one; row ``r`` is position ``r mod length``, in the
+    diffusion block ``position // block``. A noisy query sees the noisy keys
+    of its own block and the clean keys of earlier blocks; a clean query sees
+    the clean keys of its own block and earlier ones, and never a noisy key.
+    Every row sees itself. ``length`` is whole blocks."""
+
+    length: int  # L: positions of the sequence, rows of each copy
+    block: int   # B: positions of a diffusion block
+
+
 def _causal(block):
     """``[keys, queries]``: the key is not later than the query (both count
     from the same start: a diagonal block)."""
@@ -177,6 +207,44 @@ def _causal(block):
 
 def _dot(a, b):
     return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _fwd_start(qt_ref):
+    """The online softmax before any key: per query head of the step
+    ``(running maximum, running sum, accumulator [D, block])``."""
+    group, d, block = qt_ref.shape
+    f32 = jnp.float32
+    return ((jnp.full((1, block), -jnp.inf, f32), jnp.zeros((1, block), f32),
+             jnp.zeros((d, block), f32)),) * group
+
+
+def _fwd_step(qt_ref, ks, vt, carry, seen, scale, fold):
+    """One block of keys ``ks [block, D]``, ``vt [D, block]`` against the
+    step's query heads: the online softmax's update. ``seen [keys, queries]``:
+    the block's mask, None where every key is seen. A query that sees no key
+    of the block keeps what it has, if it has seen one before (its maximum is
+    then finite): the kernels take first a block in which every query sees
+    itself."""
+    out = []
+    for g, (top, total, acc) in enumerate(carry):
+        s = _dot(ks, qt_ref[g])  # [keys, queries]
+        if not fold:
+            s = s * scale
+        if seen is not None:
+            s = jnp.where(seen, s, -jnp.inf)
+        new_top = jnp.maximum(top, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - new_top)
+        shrink = jnp.exp(top - new_top)
+        total = shrink * total + jnp.sum(p, axis=0, keepdims=True)
+        acc = shrink * acc + _dot(vt, p.astype(vt.dtype))
+        out.append((new_top, total, acc))
+    return tuple(out)
+
+
+def _fwd_end(carry, ot_ref, lse_ref):
+    for g, (top, total, acc) in enumerate(carry):
+        ot_ref[g] = (acc / total).astype(ot_ref.dtype)
+        lse_ref[g] = top + jnp.log(total)
 
 
 def _fwd_kernel(qt_ref, ks_ref, vt_ref, ot_ref, lse_ref, *, block, scale, fold):
@@ -193,33 +261,43 @@ def _fwd_kernel(qt_ref, ks_ref, vt_ref, ot_ref, lse_ref, *, block, scale, fold):
     and vt_ref ``[blocks, D, block]``: the key-value head's whole
     sequence; ot_ref ``[H, D, block]``; lse_ref ``[H, 1, block]`` float32."""
     i = pl.program_id(2)
-    group, d, _ = qt_ref.shape
-    f32 = jnp.float32
 
     def step(j, carry, diagonal):
-        ks, vt = ks_ref[j], vt_ref[j]
-        seen = _causal(block) if diagonal else None
-        out = []
-        for g, (top, total, acc) in enumerate(carry):
-            s = _dot(ks, qt_ref[g])  # [keys, queries]
-            if not fold:
-                s = s * scale
-            if diagonal:
-                s = jnp.where(seen, s, -jnp.inf)
-            new_top = jnp.maximum(top, jnp.max(s, axis=0, keepdims=True))
-            p = jnp.exp(s - new_top)
-            shrink = jnp.exp(top - new_top)
-            total = shrink * total + jnp.sum(p, axis=0, keepdims=True)
-            acc = shrink * acc + _dot(vt, p.astype(vt.dtype))
-            out.append((new_top, total, acc))
-        return tuple(out)
+        return _fwd_step(qt_ref, ks_ref[j], vt_ref[j], carry,
+                         _causal(block) if diagonal else None, scale, fold)
 
-    carry = ((jnp.full((1, block), -jnp.inf, f32), jnp.zeros((1, block), f32),
-              jnp.zeros((d, block), f32)),) * group
-    carry = lax.fori_loop(0, i, functools.partial(step, diagonal=False), carry)
-    for g, (top, total, acc) in enumerate(step(i, carry, True)):
-        ot_ref[g] = (acc / total).astype(ot_ref.dtype)
-        lse_ref[g] = top + jnp.log(total)
+    carry = lax.fori_loop(0, i, functools.partial(step, diagonal=False), _fwd_start(qt_ref))
+    _fwd_end(step(i, carry, True), ot_ref, lse_ref)
+
+
+def _bwd_step(refs, i, carry, seen, scale, fold):
+    """The query block ``i`` of the step's heads against the kernel's block
+    of keys: the scores again, five products a head; ``dk`` / ``dv`` into the
+    carry, ``dq`` into its resident block. ``refs``: the keys ``[block, D]``,
+    their transpose, the values, and the kernel's whole-sequence refs (``q``,
+    ``d_out``, log-sum-exp, ``delta``, ``dq``). ``seen``: as ``_fwd_step``'s
+    (a query that sees no key of the block gets ``p = 0`` from its finite
+    log-sum-exp)."""
+    ks, kst, v, qt_ref, dot_ref, lse_ref, delta_ref, dqt_ref = refs
+    dk, dv = carry
+    f32 = jnp.float32
+    for g in range(qt_ref.shape[0]):
+        qt, dot = qt_ref[g, i], dot_ref[g, i]
+        s = _dot(ks, qt)  # [keys, queries]
+        if not fold:
+            s = s * scale
+        if seen is not None:
+            s = jnp.where(seen, s, -jnp.inf)
+        p = jnp.exp(s - lse_ref[g, i])
+        dp = _dot(v, dot)
+        if fold:  # without the D^-0.5: dq takes it from the keys, dk at the end
+            ds = (p * (dp - delta_ref[g, i])).astype(qt.dtype)
+        else:
+            ds = (p * (dp - delta_ref[g, i]) * scale).astype(qt.dtype)
+        dv = dv + lax.dot_general(p.astype(dot.dtype), dot, _NT, preferred_element_type=f32)
+        dk = dk + lax.dot_general(ds, qt, _NT, preferred_element_type=f32)
+        dqt_ref[g, i] += _dot(kst, ds)
+    return dk, dv
 
 
 def _bwd_kernel(ks_ref, kst_ref, v_ref, qt_ref, dot_ref, lse_ref, delta_ref,
@@ -238,8 +316,9 @@ def _bwd_kernel(ks_ref, kst_ref, v_ref, qt_ref, dot_ref, lse_ref, delta_ref,
     sequence; dqt_ref ``[H, blocks, D, block]`` float32; dk_ref, dv_ref
     ``[block, D]``."""
     j = pl.program_id(2)
-    group, blocks = qt_ref.shape[:2]
-    ks, kst, v = ks_ref[...], kst_ref[...], v_ref[...]
+    blocks = qt_ref.shape[1]
+    ks = ks_ref[...]
+    refs = (ks, kst_ref[...], v_ref[...], qt_ref, dot_ref, lse_ref, delta_ref, dqt_ref)
     f32 = jnp.float32
 
     @pl.when(j == 0)
@@ -247,30 +326,101 @@ def _bwd_kernel(ks_ref, kst_ref, v_ref, qt_ref, dot_ref, lse_ref, delta_ref,
         dqt_ref[...] = jnp.zeros(dqt_ref.shape, f32)
 
     def step(i, carry, diagonal):
-        dk, dv = carry
-        seen = _causal(block) if diagonal else None
-        for g in range(group):
-            qt, dot = qt_ref[g, i], dot_ref[g, i]
-            s = _dot(ks, qt)  # [keys, queries]
-            if not fold:
-                s = s * scale
-            if diagonal:
-                s = jnp.where(seen, s, -jnp.inf)
-            p = jnp.exp(s - lse_ref[g, i])
-            dp = _dot(v, dot)
-            if fold:  # without the D^-0.5: dq takes it from the keys, dk at the end
-                ds = (p * (dp - delta_ref[g, i])).astype(qt.dtype)
-            else:
-                ds = (p * (dp - delta_ref[g, i]) * scale).astype(qt.dtype)
-            dv = dv + lax.dot_general(p.astype(dot.dtype), dot, _NT, preferred_element_type=f32)
-            dk = dk + lax.dot_general(ds, qt, _NT, preferred_element_type=f32)
-            dqt_ref[g, i] += _dot(kst, ds)
-        return dk, dv
+        return _bwd_step(refs, i, carry, _causal(block) if diagonal else None, scale, fold)
 
     carry = step(j, (jnp.zeros(ks.shape, f32), jnp.zeros(ks.shape, f32)), True)
     dk, dv = lax.fori_loop(j + 1, blocks, functools.partial(step, diagonal=False), carry)
     dk_ref[...] = (dk * scale if fold else dk).astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
+
+
+def _diffusion_blocks(block, unit):
+    """``[keys, queries]`` twice: the diffusion block (``unit`` positions)
+    of every key and of every query of two blocks of rows that start at the
+    same position."""
+    shape = (block, block)
+    return (lax.broadcasted_iota(jnp.int32, shape, 0) // unit,
+            lax.broadcasted_iota(jnp.int32, shape, 1) // unit)
+
+
+def _own(key_block, query_block, noisy):
+    """A block of rows against itself: a noisy row (``noisy`` 1, a scalar)
+    sees its diffusion block's rows, a clean one (0) its own and earlier
+    blocks'. The earliest block seen is the row's own times ``noisy``:
+    integers, since the chip's compiler selects no masks by a scalar."""
+    return (key_block >= query_block * noisy) & (key_block <= query_block)
+
+
+def _blockdiff_fwd_kernel(qt_ref, ks_ref, vt_ref, ot_ref, lse_ref, *,
+                          block, half, unit, scale, fold):
+    """``_fwd_kernel`` under a ``BlockMask``: the rows are a noisy copy
+    (blocks of rows ``[0, half)``) beside the clean one (``[half, 2 half)``),
+    diffusion blocks of ``unit`` positions. A block of queries takes first
+    its own block of keys, where every query sees itself (a noisy one the
+    noisy keys of its diffusion block, a clean one the clean keys of its own
+    and earlier ones), then the clean blocks before its place whole, and, a
+    noisy one, the clean block AT its place under the strict mask (earlier
+    diffusion blocks alone): ``place + 1`` key blocks a clean block of
+    queries, ``place + 2`` a noisy one. The clean-to-noisy quadrant, every
+    other noisy block and everything past the place are never touched."""
+    i = pl.program_id(2)
+    noisy = (i < half).astype(jnp.int32)
+    place = i - half * (1 - noisy)
+    key_block, query_block = _diffusion_blocks(block, unit)
+
+    def step(j, carry, seen):
+        return _fwd_step(qt_ref, ks_ref[j], vt_ref[j], carry, seen, scale, fold)
+
+    carry = step(i, _fwd_start(qt_ref), _own(key_block, query_block, noisy))
+    carry = lax.fori_loop(0, place, lambda j, c: step(half + j, c, None), carry)
+    carry = lax.fori_loop(  # one trip for a noisy block of queries, none for a clean one
+        0, noisy, lambda _, c: step(half + place, c, key_block < query_block), carry)
+    _fwd_end(carry, ot_ref, lse_ref)
+
+
+def _blockdiff_bwd_kernel(ks_ref, kst_ref, v_ref, qt_ref, dot_ref, lse_ref, delta_ref,
+                          dqt_ref, dk_ref, dv_ref, *, block, half, unit, scale, fold):
+    """``_bwd_kernel`` under a ``BlockMask``. A noisy block of keys meets the
+    one block of queries that sees it, its own. A clean block of keys meets
+    its own block of queries, the noisy queries at its place under the strict
+    mask, and both copies' later blocks whole. Refs as ``_bwd_kernel``'s, the
+    whole sequence being the ``2 half`` blocks of both copies."""
+    j = pl.program_id(2)
+    noisy = (j < half).astype(jnp.int32)
+    place = j - half * (1 - noisy)
+    ks = ks_ref[...]
+    refs = (ks, kst_ref[...], v_ref[...], qt_ref, dot_ref, lse_ref, delta_ref, dqt_ref)
+    f32 = jnp.float32
+    key_block, query_block = _diffusion_blocks(block, unit)
+
+    @pl.when(j == 0)
+    def _():
+        dqt_ref[...] = jnp.zeros(dqt_ref.shape, f32)
+
+    def step(i, carry, seen):
+        return _bwd_step(refs, i, carry, seen, scale, fold)
+
+    def both_copies(i, carry):
+        return step(half + i, step(i, carry, None), None)
+
+    carry = step(j, (jnp.zeros(ks.shape, f32), jnp.zeros(ks.shape, f32)),
+                 _own(key_block, query_block, noisy))
+    carry = lax.fori_loop(  # one trip for a clean block of keys, none for a noisy one
+        0, 1 - noisy, lambda _, c: step(place, c, key_block < query_block), carry)
+    # both copies' later blocks: none for a noisy block of keys
+    dk, dv = lax.fori_loop(place + 1, half - (half - place - 1) * noisy, both_copies, carry)
+    dk_ref[...] = (dk * scale if fold else dk).astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+
+
+def _kernel(causal, diffusion, mask, block, **kwargs):
+    """The kernel body and its ``pallas_call``'s name: the causal one, or
+    under ``mask`` the block-diffusion one."""
+    if mask is None:
+        return functools.partial(causal[0], block=block, **kwargs), causal[1]
+    return functools.partial(
+        diffusion[0], block=block, half=mask.length // block, unit=mask.block,
+        **kwargs), diffusion[1]
 
 
 def _split(x, block):
@@ -335,12 +485,15 @@ def _params(*semantics):
     return pltpu.CompilerParams(dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def forward(q, k, v, plan, interpret=False):
-    """``(out [B, S, KV, G, D], log-sum-exp [B, KV, G, S] float32)``."""
+def forward(q, k, v, plan, interpret=False, mask=None):
+    """``(out [B, S, KV, G, D], log-sum-exp [B, KV, G, S] float32)``; under
+    ``mask`` (a ``BlockMask``) ``S`` is both copies' rows."""
     b, s, kv, g, d = q.shape
     block, heads = plan
     blocks, subs = s // block, g // heads
     of = _key_value_head(subs)
+    kernel, name = _kernel((_fwd_kernel, FWD_NAME), (_blockdiff_fwd_kernel, BLOCKDIFF_FWD_NAME),
+                           mask, block, scale=d ** -0.5, fold=_folds(d))
 
     def group(*tail):  # a sub-group's query heads, one block of queries
         return pl.BlockSpec((None, None, heads, None) + tail, lambda n, h, i: (n, h, 0, i, 0, 0))
@@ -349,7 +502,7 @@ def forward(q, k, v, plan, interpret=False):
         return pl.BlockSpec((None, None, blocks) + tail, lambda n, h, i: (n, of(h), 0, 0, 0))
 
     out_t, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, block=block, scale=d ** -0.5, fold=_folds(d)),
+        kernel,
         grid=(b, kv * subs, blocks),
         in_specs=[group(d, block), whole(block, d), whole(d, block)],
         out_specs=[group(d, block), group(1, block)],
@@ -357,18 +510,20 @@ def forward(q, k, v, plan, interpret=False):
                    jax.ShapeDtypeStruct((b, kv * subs, heads, blocks, 1, block), jnp.float32)],
         compiler_params=_params("parallel", "parallel", "parallel"),
         interpret=interpret,
-        name=FWD_NAME,
+        name=name,
     )(_sub_groups(_queries_t(q, block), heads), _keys(_scaled(k), block), _keys_t(v, block))
     out_t = out_t.reshape(b, kv, g, blocks, d, block)
     return _queries(out_t, q.shape), lse.reshape(b, kv, g, s)
 
 
-def backward(q, k, v, out, lse, d_out, plan, interpret=False):
+def backward(q, k, v, out, lse, d_out, plan, interpret=False, mask=None):
     """``(dq, dk, dv)``, shaped and typed as ``q, k, v``."""
     b, s, kv, g, d = q.shape
     block, heads = plan
     blocks, subs = s // block, g // heads
     of = _key_value_head(subs)
+    kernel, name = _kernel((_bwd_kernel, BWD_NAME), (_blockdiff_bwd_kernel, BLOCKDIFF_BWD_NAME),
+                           mask, block, scale=d ** -0.5, fold=_folds(d))
     scaled = _scaled(k)
     # per row, sum(d_out * out): what the softmax's backward subtracts
     delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
@@ -390,7 +545,7 @@ def backward(q, k, v, out, lse, d_out, plan, interpret=False):
         return like.dtype if subs == 1 else jnp.float32  # more: partial sums, added up below
 
     dq_t, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, block=block, scale=d ** -0.5, fold=_folds(d)),
+        kernel,
         grid=(b, kv * subs, blocks),
         in_specs=[keys(block, d), keys(d, block), keys(block, d),
                   whole(d, block), whole(d, block), whole(1, block), whole(1, block)],
@@ -400,7 +555,7 @@ def backward(q, k, v, out, lse, d_out, plan, interpret=False):
                    jax.ShapeDtypeStruct((b, kv * subs, blocks, block, d), summed(v))],
         compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
-        name=BWD_NAME,
+        name=name,
     )(_keys(scaled, block), _keys_t(scaled, block), _keys(v, block),
       sub(_queries_t(q, block)), sub(_queries_t(d_out, block)), sub(_rows_t(lse, block)),
       sub(_rows_t(delta, block)))
@@ -414,22 +569,23 @@ def backward(q, k, v, out, lse, d_out, plan, interpret=False):
     return _queries(dq_t.astype(q.dtype), q.shape), keys_back(dk, k), keys_back(dv, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def attention(q, k, v, plan, interpret=False):
-    """Causal softmax attention with grouped queries through the kernels:
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def attention(q, k, v, plan, interpret=False, mask=None):
+    """Softmax attention with grouped queries through the kernels, causal or
+    under ``mask`` (a ``BlockMask``; ``S`` is then both copies' rows):
     ``q [B, S, KV, G, D]``, ``k, v [B, S, KV, D]`` -> ``[B, S, KV, G, D]``;
-    ``S`` a multiple of ``plan.block``, ``G`` of ``plan.heads``, ``D`` one of
-    ``HEAD_DIMS``."""
-    return forward(q, k, v, plan, interpret)[0]
+    ``S`` (a copy's rows under a mask) a multiple of ``plan.block``, ``G`` of
+    ``plan.heads``, ``D`` one of ``HEAD_DIMS``."""
+    return forward(q, k, v, plan, interpret, mask)[0]
 
 
-def _attention_fwd(q, k, v, plan, interpret):
-    out, lse = forward(q, k, v, plan, interpret)
+def _attention_fwd(q, k, v, plan, interpret, mask):
+    out, lse = forward(q, k, v, plan, interpret, mask)
     return out, (q, k, v, out, lse)
 
 
-def _attention_bwd(plan, interpret, residuals, d_out):
-    return backward(*residuals, d_out, plan, interpret)
+def _attention_bwd(plan, interpret, mask, residuals, d_out):
+    return backward(*residuals, d_out, plan, interpret, mask)
 
 
 attention.defvjp(_attention_fwd, _attention_bwd)
@@ -441,21 +597,29 @@ def block_for(length: int):
     return next((blk for blk in BLOCKS if length % blk == 0), None)
 
 
-def plan_for(q_shape, k_shape, dtype):
+def plan_for(q_shape, k_shape, dtype, mask=None):
     """The kernels' plan for ``q [B, S, KV, G, D]`` and ``k [B, S, KV, D]``,
     from head dim, heads a group and length alone; None for shapes the
     kernels are not written (and compiled, for a described chip) for. They
     take bfloat16, a head dim of ``HEAD_DIMS`` and a sequence that is whole
-    blocks. A grid step takes as many of a group's query heads as divide the
-    group, stay within ``_STEP_WIDTH`` lanes of head dims (the chains a step
-    unrolls are never more than four) and whose whole-sequence queries,
-    cotangents and float32 ``dq``, each buffered twice, fit half of the
-    kernels' VMEM in the backward; the other half is for a key-value head's
-    whole K and V in the forward and for the scores of a step's blocks."""
+    blocks; under ``mask`` (a ``BlockMask``) ``S`` is both copies' rows, each
+    copy is whole blocks and a block is whole diffusion blocks. A grid step
+    takes as many of a group's query heads as divide the group, stay within
+    ``_STEP_WIDTH`` lanes of head dims (the chains a step unrolls are never
+    more than four) and whose whole-sequence queries, cotangents and float32
+    ``dq``, each buffered twice, fit half of the kernels' VMEM in the
+    backward; the other half is for a key-value head's whole K and V in the
+    forward and for the scores of a step's blocks."""
     if len(q_shape) != 5 or len(k_shape) != 4 or dtype != jnp.bfloat16:
         return None
     length, group, d = q_shape[1], q_shape[3], q_shape[4]
-    block = block_for(length)
+    if mask is None:
+        block = block_for(length)
+    elif length != 2 * mask.length:
+        return None
+    else:
+        block = next((blk for blk in BLOCKS
+                      if mask.length % blk == 0 and blk % mask.block == 0), None)
     if d not in HEAD_DIMS or block is None:
         return None
     fits = [h for h in range(1, _STEP_WIDTH // d + 1)
@@ -463,12 +627,12 @@ def plan_for(q_shape, k_shape, dtype):
     return Plan(block, fits[-1]) if fits else None
 
 
-def supported(q_shape, k_shape, dtype) -> bool:
+def supported(q_shape, k_shape, dtype, mask=None) -> bool:
     """Whether the kernels have a plan for these shapes."""
-    return plan_for(q_shape, k_shape, dtype) is not None
+    return plan_for(q_shape, k_shape, dtype, mask) is not None
 
 
-def dispatchable(q, k) -> bool:
+def dispatchable(q, k, mask=None) -> bool:
     """TPU backend, shapes the kernels take, and not under a batched
     (vmapped) trace: a batched ``pallas_call`` compiles through an added
     grid dimension only sometimes, and the gate, which plans the un-batched
@@ -477,4 +641,4 @@ def dispatchable(q, k) -> bool:
 
     if jax.default_backend() != "tpu" or _is_batch_tracer(q) or _is_batch_tracer(k):
         return False
-    return supported(tuple(q.shape), tuple(k.shape), q.dtype)
+    return supported(tuple(q.shape), tuple(k.shape), q.dtype, mask)

@@ -42,6 +42,7 @@ their first model gave them: the benchmark's readers find them by name.
 from __future__ import annotations
 
 import functools
+import warnings
 from typing import Any
 
 import flax.linen as nn
@@ -93,15 +94,18 @@ class Embedding(nn.Module):
         return jnp.take(table, ids, axis=0)
 
 
-def rope(x, theta: float, rotary: "int | None" = None):
-    """Rotary embedding of ``x [batch, positions, heads, dim]``, half-split
-    pairing, positions 0..S-1 in every row (one document a sequence);
-    angles and rotation in float32. ``rotary``: the leading dims that turn
-    (a partial rotary factor; the rest pass as they are), None for all."""
+def rope(x, theta: float, rotary: "int | None" = None, positions=None):
+    """Rotary embedding of ``x [batch, rows, heads, dim]``, half-split
+    pairing; angles and rotation in float32. ``positions [rows]``: every
+    row's position; None: 0..S-1, the row's index (one document a sequence).
+    ``rotary``: the leading dims that turn (a partial rotary factor; the
+    rest pass as they are), None for all."""
     rotary = x.shape[-1] if rotary is None else rotary
     half = rotary // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq
+    if positions is None:
+        positions = jnp.arange(x.shape[1], dtype=jnp.float32)
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
     x1, x2 = x[..., :half], x[..., half:rotary]
@@ -534,12 +538,71 @@ class Mamba2(nn.Module):
 # -- attention ---------------------------------------------------------------
 
 
-def _block_scores(q, k, first_row: int):
+BlockMask = attention_pallas.BlockMask
+
+
+def _key_ranges(start: int, end: int, mask):
+    """The runs ``[(lo, hi)]`` of key rows that the query rows ``[start,
+    end)`` can see any of: causal (``mask`` None) the prefix that ends with
+    their last row; under a ``BlockMask`` the noisy rows of their own
+    diffusion blocks and the clean rows of every block up to their last
+    one's (a noisy row's own block among them is masked away again)."""
+    if mask is None:
+        return [(0, end)]
+    length, unit = mask
+    whole = lambda n: -(-n // unit) * unit  # noqa: E731  up to whole blocks
+    runs, reach = [], 0  # ``reach``: the clean rows the last row of each copy sees
+    if start < length:  # noisy rows: their own blocks of the noisy copy, earlier clean ones
+        last = min(end, length)
+        runs.append((start // unit * unit, whole(last)))
+        reach = (last - 1) // unit * unit
+    if end > length:  # clean rows: the clean blocks up to their own
+        reach = max(reach, whole(end - length))
+    if reach:
+        runs.append((length, length + reach))
+    return runs
+
+
+def _rows_of(x, runs):
+    """The runs' rows of ``x [B, rows, ...]``, side by side."""
+    parts = [x[:, lo:hi] for lo, hi in runs]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def _add_rows(total, part, runs):
+    """``total`` with ``part``'s rows added at the runs they were taken from."""
+    if len(runs) == 1:
+        return total.at[:, runs[0][0]:runs[0][1]].add(part)
+    at = 0
+    for lo, hi in runs:
+        total = total.at[:, lo:hi].add(part[:, at:at + hi - lo])
+        at += hi - lo
+    return total
+
+
+def _visible(rows, keys, mask):
+    """``[rows, keys]``: whether query row ``rows[i]`` sees key row
+    ``keys[j]`` under the ``BlockMask``."""
+    length, unit = mask
+    rows, keys = rows[:, None], keys[None, :]
+    row_block, key_block = rows % length // unit, keys % length // unit
+    return jnp.where(
+        rows < length,
+        jnp.where(keys < length, key_block == row_block, key_block < row_block),
+        (keys >= length) & (key_block <= row_block))
+
+
+def _block_scores(q, k, first_row: int, runs=None, mask=None):
     """Masked, scaled scores of query rows ``first_row ...`` against the
-    prefix that ends with their last row, float32: ``q [B, rows, KV, G, D]``,
-    ``k [B, prefix, KV, D]`` -> ``[B, KV, G, rows, prefix]``."""
+    keys they can see any of (``_key_ranges``; causal: the prefix that ends
+    with their last row), float32: ``q [B, rows, KV, G, D]``,
+    ``k [B, keys, KV, D]`` -> ``[B, KV, G, rows, keys]``."""
     scores = jnp.einsum("bqkgd,bnkd->bkgqn", q, k,
                         preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
+    if mask is not None:
+        rows = first_row + jnp.arange(q.shape[1])
+        keys = jnp.concatenate([jnp.arange(lo, hi) for lo, hi in runs])
+        return jnp.where(_visible(rows, keys, mask), scores, -jnp.inf)
     row = first_row + jnp.arange(q.shape[1])[:, None]
     return jnp.where(row >= jnp.arange(k.shape[1])[None, :], scores, -jnp.inf)
 
@@ -548,18 +611,19 @@ def _blocks(length: int, block: int):
     return [(start, min(start + block, length)) for start in range(0, length, block)]
 
 
-def _attention_forward(q, k, v, block):
+def _attention_forward(q, k, v, block, mask=None):
     """``(out, log-sum-exp of every row's scores [B, KV, G, S])``."""
     out, lse = [], []
     for start, end in _blocks(q.shape[1], block):
         rows = q[:, start:end]
         if out:  # one block's scores at a time: start when the last is done
             rows, out[-1] = lax.optimization_barrier((rows, out[-1]))
-        scores = _block_scores(rows, k[:, :end], start)
+        runs = _key_ranges(start, end, mask)
+        scores = _block_scores(rows, _rows_of(k, runs), start, runs, mask)
         top = jnp.max(scores, axis=-1, keepdims=True)
         p = jnp.exp(scores - top)
         total = jnp.sum(p, axis=-1, keepdims=True)
-        o = jnp.einsum("bkgqn,bnkd->bqkgd", p.astype(v.dtype), v[:, :end],
+        o = jnp.einsum("bkgqn,bnkd->bqkgd", p.astype(v.dtype), _rows_of(v, runs),
                        preferred_element_type=jnp.float32)
         out.append((o / jnp.moveaxis(total, 3, 1)).astype(q.dtype))
         lse.append((top + jnp.log(total))[..., 0])
@@ -585,6 +649,26 @@ def causal_attention(q, k, v, block: int):
     return blocked_causal_attention(q, k, v, block)
 
 
+def block_diffusion_attention(q, k, v, block: int, mask: BlockMask):
+    """``causal_attention`` under block-diffusion training's mask: the rows
+    of ``q, k, v`` are a noisy copy of the sequence beside the clean one
+    (``BlockMask``: which row sees which; never a dense ``[2L, 2L]`` array).
+    The fused kernels where they have a plan for the shape under the mask,
+    both passes skipping every block of keys the mask empties; else
+    ``blocked_masked_attention``, and on a TPU that is said aloud: the plain
+    path keeps a block's float32 scores in HBM."""
+    if attention_pallas.dispatchable(q, k, mask):
+        with jax.named_scope("blockdiff_attention_kernels"):
+            return attention_pallas.attention(
+                q, k, v, attention_pallas.plan_for(q.shape, k.shape, q.dtype, mask),
+                False, mask)
+    if jax.default_backend() == "tpu":
+        warnings.warn(
+            f"block-diffusion attention of q {tuple(q.shape)} {q.dtype} under {mask} "
+            "has no kernel plan: the plain blocked path runs")
+    return blocked_masked_attention(q, k, v, block, mask)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def blocked_causal_attention(q, k, v, block: int):
     """``causal_attention`` in plain JAX, one block of query rows' scores
@@ -599,12 +683,12 @@ def blocked_causal_attention(q, k, v, block: int):
     return _attention_forward(q, k, v, block)[0]
 
 
-def _attention_fwd(q, k, v, block):
-    out, lse = _attention_forward(q, k, v, block)
+def _attention_fwd(q, k, v, block, mask=None):
+    out, lse = _attention_forward(q, k, v, block, mask)
     return out, (q, k, v, out, lse)
 
 
-def _attention_bwd(block, residuals, d_out):
+def _attention_bwd(block, residuals, d_out, mask=None):
     q, k, v, out, lse = residuals
     # per row, sum(d_out * out): what the softmax's backward subtracts
     delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
@@ -614,24 +698,40 @@ def _attention_bwd(block, residuals, d_out):
     dv = jnp.zeros(v.shape, jnp.float32)
     for start, end in _blocks(q.shape[1], block):
         rows = slice(start, end)
+        runs = _key_ranges(start, end, mask)
         # one block's scores at a time: start when the last block's sums are in
         do, dk, dv = lax.optimization_barrier((d_out[:, rows], dk, dv))
-        p = jnp.exp(_block_scores(q[:, rows], k[:, :end], start)
+        p = jnp.exp(_block_scores(q[:, rows], _rows_of(k, runs), start, runs, mask)
                     - lse[..., rows, None])
-        dp = jnp.einsum("bqkgd,bnkd->bkgqn", do, v[:, :end],
+        dp = jnp.einsum("bqkgd,bnkd->bkgqn", do, _rows_of(v, runs),
                         preferred_element_type=jnp.float32)
         ds = p * (dp - delta[..., rows, None]) * q.shape[-1] ** -0.5
         ds = ds.astype(q.dtype)
-        dq.append(jnp.einsum("bkgqn,bnkd->bqkgd", ds, k[:, :end]))
-        dk = dk.at[:, :end].add(jnp.einsum(
-            "bkgqn,bqkgd->bnkd", ds, q[:, rows], preferred_element_type=jnp.float32))
-        dv = dv.at[:, :end].add(jnp.einsum(
+        dq.append(jnp.einsum("bkgqn,bnkd->bqkgd", ds, _rows_of(k, runs)))
+        dk = _add_rows(dk, jnp.einsum(
+            "bkgqn,bqkgd->bnkd", ds, q[:, rows], preferred_element_type=jnp.float32), runs)
+        dv = _add_rows(dv, jnp.einsum(
             "bkgqn,bqkgd->bnkd", p.astype(do.dtype), do,
-            preferred_element_type=jnp.float32))
+            preferred_element_type=jnp.float32), runs)
     return jnp.concatenate(dq, axis=1), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 blocked_causal_attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def blocked_masked_attention(q, k, v, block: int, mask: BlockMask):
+    """``blocked_causal_attention`` under a ``BlockMask``: a block of query
+    rows meets the keys it can see any of (its own diffusion blocks' noisy
+    rows, the clean rows up to its last block's) and no others, so the work
+    is that of two causal sequences and not of one of twice the length; the
+    backward pass is the same one."""
+    return _attention_forward(q, k, v, block, mask)[0]
+
+
+blocked_masked_attention.defvjp(
+    _attention_fwd,
+    lambda block, mask, residuals, d_out: _attention_bwd(block, residuals, d_out, mask))
 
 
 class Attention(nn.Module):
@@ -643,7 +743,13 @@ class Attention(nn.Module):
     ``qk_norm`` (False: q and k as projected, no norm and no scale of
     theirs), ``output_gate`` (``q_proj`` is twice as wide, each head's
     second half a gate: the attention's output times its sigmoid, before
-    ``out_proj``) and ``zero_centred_norms`` (``RMSNorm``)."""
+    ``out_proj``) and ``zero_centred_norms`` (``RMSNorm``).
+    ``diffusion_block`` not 0: block-diffusion training, the rows a noisy
+    copy of the sequence beside the clean one (``2 L`` rows, diffusion blocks
+    of that many positions): row ``r`` is at position ``r mod L`` for the
+    rotary embedding, and which row sees which is ``BlockMask(L,
+    diffusion_block)`` (``block_diffusion_attention``), not causal; the
+    layer is then found under the scope ``blockdiff_attention``."""
 
     hidden: int
     heads: int
@@ -657,12 +763,18 @@ class Attention(nn.Module):
     output_gate: bool = False
     zero_centred_norms: bool = False
     qk_norm: bool = True
+    diffusion_block: int = 0
 
     @nn.compact
     def __call__(self, x):
-        with jax.named_scope("lfm2_attention"):
+        scope = "blockdiff_attention" if self.diffusion_block else "lfm2_attention"
+        with jax.named_scope(scope):
             batch, length, _ = x.shape
             d = self.head_dim or self.hidden // self.heads
+            mask = positions = None
+            if self.diffusion_block:
+                mask = BlockMask(length // 2, self.diffusion_block)
+                positions = jnp.arange(length) % mask.length
             x = x.astype(self.dtype)
             q = linear(self.heads * d * (1 + self.output_gate), self.dtype, "q_proj")(x)
             k = linear(self.kv_heads * d, self.dtype, "k_proj")(x)
@@ -678,12 +790,17 @@ class Attention(nn.Module):
             def placed(t, name):  # a head's norm, then its rotation
                 if self.qk_norm:
                     t = RMSNorm(self.eps, self.zero_centred_norms, name=name)(t)
-                return t if self.rotary_dim == 0 else rope(t, self.rope_theta, self.rotary_dim)
+                if self.rotary_dim == 0:
+                    return t
+                return rope(t, self.rope_theta, self.rotary_dim, positions)
 
             q, k = placed(q, "q_layernorm"), placed(k, "k_layernorm")
             q = q.astype(self.dtype).reshape(
                 batch, length, self.kv_heads, self.heads // self.kv_heads, d)
-            out = causal_attention(q, k.astype(self.dtype), v, self.block)
+            if mask is None:
+                out = causal_attention(q, k.astype(self.dtype), v, self.block)
+            else:
+                out = block_diffusion_attention(q, k.astype(self.dtype), v, self.block, mask)
             out = out.reshape(batch, length, self.heads * d)
             if self.output_gate:
                 out = out * jax.nn.sigmoid(gate.reshape(out.shape))

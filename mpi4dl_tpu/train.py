@@ -201,6 +201,22 @@ def correct_count(logits, labels) -> jax.Array:
     return jnp.sum(jnp.argmax(logits, axis=-1) == labels)
 
 
+def position_cross_entropy(logits, y, mean):
+    """The loss a ``Trainer`` takes where its model brings none: softmax
+    cross-entropy against one label an image, or one a position of a token
+    sequence, and the share of them the logits name.
+
+    A model's own loss has this signature. ``mean(total, labels)`` is the
+    trainer's: this shard's sum over ``labels`` labels (a count known when
+    the step is traced) as its part of the mean over the global batch, summed
+    over the mesh. Returns ``(loss, accuracy, counters)``, the last a dict of
+    this shard's counts (device scalars), which the trainer sums over the
+    data axis and hands on with the step's metrics."""
+    loss = mean(cross_entropy_sum(logits, y), y.size)
+    accuracy = mean(correct_count(logits, y).astype(jnp.float32), y.size)
+    return loss, accuracy, {}
+
+
 @struct.dataclass
 class TrainState:
     params: Any
@@ -274,8 +290,12 @@ class Trainer:
         momentum: float = 0.9,
         remat: bool | str = False,
         grad_accum: int = 1,
+        loss=None,
     ):
-        """remat: False = store everything; True/"cell" = ``jax.checkpoint``
+        """loss: the model's own, with :func:`position_cross_entropy`'s
+        signature (None: that one).
+
+        remat: False = store everything; True/"cell" = ``jax.checkpoint``
         per cell; "sqrt" = nested two-level remat (cells grouped into ~√N
         outer checkpoints, each cell checkpointed inside, so live residuals
         are ~2√N boundaries); "scan2" = "scan" with the same two-level
@@ -319,6 +339,7 @@ class Trainer:
             raise ValueError(f"grad_accum must be >= 1, got {grad_accum}")
 
         self.grad_accum = grad_accum
+        self.loss = loss or position_cross_entropy
         self.remat = remat
         self.cells = list(cells)
         self.plain_cells = list(plain_cells) if plain_cells is not None else self.cells
@@ -983,16 +1004,17 @@ class Trainer:
 
         d = axis_size(AXIS_DATA)
         replicas = axis_size(AXIS_TILE_H) * axis_size(AXIS_TILE_W)
-        # labels in the global batch: one an image, one a position of a
-        # token sequence (loss and accuracy are means over them)
-        global_labels = y.size * d
-        denom = global_labels * replicas
         axes = (AXIS_DATA, AXIS_TILE_H, AXIS_TILE_W)
+
+        def mean(total, labels):
+            # ``labels`` in this shard's batch (one an image, one a position
+            # of a token sequence), ``d`` times as many in the global batch
+            return lax.psum(total / (labels * d * replicas), axes)
+
         with jax.named_scope("mpi4dl_loss"):
-            loss = lax.psum(cross_entropy_sum(logits, y) / denom, axes)
-            acc = lax.psum(
-                correct_count(logits, y).astype(jnp.float32) / denom, axes)
-        return loss, (acc, counted)
+            loss, acc, more = self.loss(logits, y, mean)
+            more = jax.tree.map(lambda c: lax.psum(c, AXIS_DATA), more)
+        return loss, (acc, counted, more)
 
     def _sharded_loss(self, params, x, y):
         fn = shard_map(
@@ -1013,10 +1035,11 @@ class Trainer:
             def loss_fn(params):
                 return self._sharded_loss(params, x, y)
 
-            (loss, (acc, counted)), grads = jax.value_and_grad(
+            (loss, (acc, counted, more)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(state.params)
         else:  # the chunks' counters are not kept
             loss, acc, grads = self._accum_grads(state.params, x, y)
+            more = {}
         with jax.named_scope("mpi4dl_optimizer"):
             updates, opt_state = self.tx.update(
                 grads, state.opt_state, state.params)
@@ -1024,7 +1047,7 @@ class Trainer:
         new_state = TrainState(
             params=params, opt_state=opt_state, step=state.step + 1
         )
-        return new_state, {"loss": loss, "accuracy": acc, **step_counters(counted)}
+        return new_state, {"loss": loss, "accuracy": acc, **step_counters(counted), **more}
 
     def _accum_grads(self, params, x, y):
         """Gradient accumulation: the batch runs as ``grad_accum`` equal
@@ -1061,7 +1084,7 @@ class Trainer:
 
         def body(carry, xy):
             gsum, lsum, asum = carry
-            (l, (a, _)), g = jax.value_and_grad(chunk_loss, has_aux=True)(
+            (l, (a, *_)), g = jax.value_and_grad(chunk_loss, has_aux=True)(
                 params, *xy
             )
             carry = (jax.tree.map(jnp.add, gsum, g), lsum + l, asum + a)

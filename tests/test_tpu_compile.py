@@ -341,6 +341,44 @@ def test_what_the_attention_plan_admits_the_chips_compiler_takes(
         assert name in compiled.as_text(), why
 
 
+@pytest.mark.parametrize("why, q_shape, length, unit, plan", [
+    ("the SDAR cell's: a noisy copy beside the clean one, 8,192 positions each, "
+     "8 heads of 128 a group, diffusion blocks of 4", (1, 16384, 4, 8, 128), 8192, 4, (512, 1)),
+    ("a short pair of copies at head dim 64, whole kernel blocks as diffusion blocks",
+     (2, 512, 2, 4, 64), 256, 256, (256, 4)),
+])
+def test_the_attention_kernels_under_the_block_diffusion_mask_compile(
+        topo, cache_off, monkeypatch, why, q_shape, length, unit, plan):
+    """``block_diffusion_attention`` with the gate steered to its TPU branch:
+    the plan under the mask, and the compiled value and gradient hold the
+    forward and the backward kernel under their own names (not the causal
+    ones'), each under the scope the layer's reader joins the trace with."""
+    from mpi4dl_tpu.ops import attention_pallas
+    from mpi4dl_tpu.ops.sequence import BlockMask, block_diffusion_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mask = BlockMask(length, unit)
+    k_shape = q_shape[:3] + q_shape[4:]
+    assert attention_pallas.plan_for(q_shape, k_shape, jnp.bfloat16, mask) == plan, why
+
+    def attend(q, k, v):
+        with jax.named_scope("blockdiff_attention"):
+            return block_diffusion_attention(q, k, v, 512, mask)
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    compiled = _compiled_grad(
+        attend, one_chip,
+        (q_shape, jnp.bfloat16), (k_shape, jnp.bfloat16), (k_shape, jnp.bfloat16))
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in (attention_pallas.BLOCKDIFF_FWD_NAME, attention_pallas.BLOCKDIFF_BWD_NAME):
+        mine = [line for line in calls if name in line.split(" = ")[0]]
+        assert len(mine) == 1, (why, name, [line[:80] for line in calls])
+        assert "blockdiff_attention" in mine[0].split("op_name=")[1].split('"')[1]
+    assert not any(attention_pallas.FWD_NAME in line.split(" = ")[0] for line in calls)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
 # -- Qwen3-Next's layers at full width (PR 37) --------------------------------
 
 
