@@ -44,6 +44,11 @@ AXIS_PIPE = "pipe"
 AXIS_TILE_H = "tile_h"
 AXIS_TILE_W = "tile_w"
 
+# The ``checkpoint_name`` of everything a fused kernel's forward writes
+# (``ops/attention_pallas``, ``delta_rule_pallas``, ``ssd_scan_pallas``):
+# what "cell" remat keeps beside the cell's input (``train._cell_ckpt``).
+KERNEL_RESIDUAL = "kernel_residual"
+
 
 def tile_grid(num_spatial_parts: int, slice_method: str) -> tuple[int, int]:
     """(tile_h, tile_w) grid extents for one SP stage.

@@ -143,6 +143,28 @@ off-diagonal noisy block, everything past the place: the work of two causal
 sequences of ``L``, not of one of ``2 L``. The plan counts ``2 L`` rows of
 residents, so 16,384 rows at head dim 128 run one query head a grid step.
 
+What a step calls (PR 44): one forward and one backward kernel a layer. The
+tables above count a layer's passes as the steps ran them until then, two
+forwards and a backward, because the cell's bare ``jax.checkpoint`` replayed
+the forward call only to get back ``out`` and the log-sum-exp it had already
+written. ``_attention_fwd`` (the rule is shared by the causal kernels and the
+ones under a ``BlockMask``) now gives both the name ``config.KERNEL_RESIDUAL``
+and "cell" remat keeps that name (``train._cell_ckpt``), so the replay has no
+use for the call: ``q``, ``k`` and ``v`` are still recomputed from the cell's
+input, ``out`` (134 MB a layer in the three wide cells, 34 in LFM2's) and the
+log-sum-exp (1-2 MB) are held from the layer's forward to its backward, and a
+layer executes seven products over whole blocks where it executed nine, the
+least being six (so no roofline share can pass 6/7 this way). In the compiled
+steps (my sandbox compiles for a described v5e chip, PR 44): 8 forward and 8
+backward calls under the mask in the SDAR cell where the parent has 16 and 8,
+2 and 2 in LFM2's (4 and 2), 1 and 1 in the Qwen3-Next and Nemotron-H cells
+(2 and 1), with no more bytes of layout copies around them than the parent
+has in any of the four. On the chip (device events of the traced steps, my
+chip runs, PR 44; parent -> change, ms a step and share of the roofline):
+the SDAR cell's kernels 312.9 -> 228.8 (42.8 -> 58.6%), Qwen3-Next's 32.41 ->
+24.75 (51.7 -> 67.7%), Nemotron-H's 35.26 -> 25.99 (47.5 -> 64.4%), LFM2's
+28.35 -> 21.15 (29.5 -> 39.6%); a call takes what it took.
+
 Dispatch (``dispatchable``): TPU backend, not under ``vmap``, and a plan
 (``plan_for``: bfloat16, head dim 64, 128 or 256, a length of whole blocks,
 a step's heads within VMEM); everything else takes the plain path, which is
@@ -159,8 +181,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from mpi4dl_tpu.config import KERNEL_RESIDUAL
 
 # The pallas_calls' names: how the kernels are found in a compiled step's
 # text and in a profiler trace (the benchmark's readers look for their
@@ -580,7 +605,10 @@ def attention(q, k, v, plan, interpret=False, mask=None):
 
 
 def _attention_fwd(q, k, v, plan, interpret, mask):
-    out, lse = forward(q, k, v, plan, interpret, mask)
+    # all the forward call writes, under the name "cell" remat keeps: the
+    # cell's replay then has no use for the call (``train._cell_ckpt``)
+    out, lse = (checkpoint_name(x, KERNEL_RESIDUAL)
+                for x in forward(q, k, v, plan, interpret, mask))
     return out, (q, k, v, out, lse)
 
 

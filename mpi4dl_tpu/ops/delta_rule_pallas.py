@@ -51,8 +51,12 @@ with the blocks it differs by 3e-4 (relative L2), the cotangents by
   the output are read and written in the layout the mixer has them in.
 
 The plain path's two ``jax.checkpoint``s play no part here: under the cell's
-"cell" remat the rule runs forward, forward again (keeping the states and
-inverses), backward.
+"cell" remat the rule runs forward (keeping the states and inverses), then
+backward. ``_rule_fwd`` gives all the forward call writes (the output, the
+start states, the inverses) the name ``config.KERNEL_RESIDUAL``, which the
+cell's checkpoint keeps (``train._cell_ckpt``, PR 44), so the cell's replay
+in the backward pass has no use for a second forward call: 536 MB a layer
+held from the layer's forward to its backward.
 
 Timed alone at the cell's shape (``q, k [2, 8192, 16, 128]``, ``v [2, 8192,
 16, 2, 128]`` bfloat16, ``g, beta [2, 8192, 16, 2]`` float32 as a fresh
@@ -127,8 +131,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from mpi4dl_tpu.config import KERNEL_RESIDUAL
 
 # The pallas_calls' names: how the kernels are found in a compiled step's
 # text and in a profiler trace (the benchmark's readers look for their
@@ -478,7 +485,10 @@ def _rule(q, k, v, total, beta, chunk, interpret):
 
 
 def _rule_fwd(q, k, v, total, beta, chunk, interpret):
-    out, starts, solves = forward(q, k, v, total, beta, chunk, True, interpret)
+    # all the forward call writes, under the name "cell" remat keeps
+    # (``attention_pallas._attention_fwd``)
+    out, starts, solves = (checkpoint_name(x, KERNEL_RESIDUAL)
+                           for x in forward(q, k, v, total, beta, chunk, True, interpret))
     return out, (q, k, v, total, beta, starts, solves)
 
 

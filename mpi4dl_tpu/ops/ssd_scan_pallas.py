@@ -50,7 +50,7 @@ configuration's 128. ``_chunk`` is one chunk of one group on 2-D values.
   lanes). The heads' states ``[8, 64, 128]`` float32 (256 KB) stay in VMEM
   scratch across a sequence's blocks and start from zero at the first.
   Called for a backward pass it also writes every chunk's start states
-  (float32: 268 MB a layer, alive only while that layer's backward runs).
+  (float32: 268 MB a layer, kept from the layer's forward to its backward).
 * backward (``mpi4dl_ssd_scan_bwd``), the same grid from the last block to
   the first: ``dS`` is carried in VMEM as ``S`` was; a chunk's backward is
   ``jax.vjp`` of ``_chunk`` taken inside the kernel body on VMEM values (the
@@ -64,9 +64,12 @@ configuration's 128. ``_chunk`` is one chunk of one group on 2-D values.
   layout as rows ``[batch, group, chunk, 8, 128]`` (4 MB), and the way back.
 
 The plain path's two ``jax.checkpoint``s play no part here: under the cell's
-"cell" remat the scan runs forward, forward again (keeping the states),
-backward. (Both forwards write the start states: the remat's first forward
-is the ``custom_vjp``'s too, its residuals dropped.)
+"cell" remat the scan runs forward (keeping the states), then backward.
+``_scan_fwd`` gives all the forward call writes (the output, the start
+states) the name ``config.KERNEL_RESIDUAL``, which the cell's checkpoint
+keeps (``train._cell_ckpt``, PR 44), so the cell's replay in the backward
+pass has no use for a second forward call: 402 MB a layer held from the
+layer's forward to its backward.
 
 Timed alone at the cell's shape (``x [2, 8192, 8, 8, 64]``, ``b, c [2, 8192, 8,
 128]`` bfloat16, ``g [2, 8192, 8, 8]`` float32 as the benchmark's fresh model
@@ -119,8 +122,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from mpi4dl_tpu.config import KERNEL_RESIDUAL
 
 # ``dot``: a product of operands as they are, accumulated in float32, whose
 # backward rounds the cotangent to the operands' dtype first (what the chip's
@@ -388,7 +394,10 @@ def _scan(xt, total, b, c, heads, chunk, interpret):
 
 
 def _scan_fwd(xt, total, b, c, heads, chunk, interpret):
-    out, starts = forward(xt, total, b, c, heads, chunk, True, interpret)
+    # all the forward call writes, under the name "cell" remat keeps
+    # (``attention_pallas._attention_fwd``)
+    out, starts = (checkpoint_name(x, KERNEL_RESIDUAL)
+                   for x in forward(xt, total, b, c, heads, chunk, True, interpret))
     return out, (xt, total, b, c, starts)
 
 

@@ -43,6 +43,7 @@ from mpi4dl_tpu.config import (
     AXIS_PIPE,
     AXIS_TILE_H,
     AXIS_TILE_W,
+    KERNEL_RESIDUAL,
     ParallelConfig,
 )
 from mpi4dl_tpu.parallel.halo import gather_tiles
@@ -55,6 +56,18 @@ def _conv_save_ckpt():
     return functools.partial(
         jax.checkpoint,
         policy=jax.checkpoint_policies.save_only_these_names("conv_out"),
+    )
+
+
+def _cell_ckpt():
+    """jax.checkpoint of one cell under remat "cell": the cell's input is
+    kept and the cell replayed, but for what a fused kernel's forward wrote
+    (``KERNEL_RESIDUAL``: a kernel's output and what its backward reads),
+    which is kept by name, so the replay runs no kernel forward again. A
+    cell without such a value (every image cell) keeps its input alone."""
+    return functools.partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.save_only_these_names(KERNEL_RESIDUAL),
     )
 
 
@@ -144,7 +157,9 @@ def default_remat(image_size: int) -> "bool | str":
 
     - a model without an image (``image_size`` 0: a token-sequence model,
       ``ParallelConfig.sequence_length``): "cell", every cell's input kept
-      and the cell recomputed in the backward pass. Such a model is sized
+      and the cell recomputed in the backward pass, but for what its fused
+      kernels' forwards wrote, which is kept too (:func:`_cell_ckpt`: a
+      kernel's forward runs once a step). Such a model is sized
       so that parameters, gradients and momentum fill most of the chip
       (LFM2-8B-A1B's share: 11.5 of 16 GB), and one layer's activations at
       8,192 positions are what is left to hold.
@@ -296,7 +311,9 @@ class Trainer:
         signature (None: that one).
 
         remat: False = store everything; True/"cell" = ``jax.checkpoint``
-        per cell; "sqrt" = nested two-level remat (cells grouped into ~√N
+        per cell, which keeps the cell's input and what the cell's fused
+        kernels' forwards wrote (:func:`_cell_ckpt`) and recomputes the
+        rest; "sqrt" = nested two-level remat (cells grouped into ~√N
         outer checkpoints, each cell checkpointed inside, so live residuals
         are ~2√N boundaries); "scan2" = "scan" with the same two-level
         nesting applied INSIDE each scan run (see :meth:`_scan_nested`) —
@@ -905,8 +922,9 @@ class Trainer:
             return self._apply_cells_scan(params, x)
         if self.remat in (True, "cell"):
             h = x
+            cell_ckpt = _cell_ckpt()
             for i in range(len(self.cells)):
-                h = jax.checkpoint(functools.partial(run_cell, i))(params[i], h)
+                h = cell_ckpt(functools.partial(run_cell, i))(params[i], h)
             return h
         if self.remat == "sqrt":
             n = len(self.cells)
@@ -976,9 +994,9 @@ class Trainer:
 
         counted: dict = {}
         h = x
+        ckpt = _cell_ckpt() if self.remat else _no_ckpt
         for i in range(len(self.cells)):
-            run = functools.partial(run_cell, i)
-            h, sown = (jax.checkpoint(run) if self.remat else run)(params[i], h)
+            h, sown = ckpt(functools.partial(run_cell, i))(params[i], h)
             for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
                 name = next(k.key for k in reversed(path) if hasattr(k, "key"))
                 counted.setdefault(name, []).append(leaf)

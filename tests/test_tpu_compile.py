@@ -23,6 +23,8 @@ chip (PR 24), outside the tier-1 budget; it stays a scratch script.
 """
 
 import functools
+import json
+import os
 import re
 
 import jax
@@ -382,13 +384,18 @@ def test_the_attention_kernels_under_the_block_diffusion_mask_compile(
 # -- Qwen3-Next's layers at full width (PR 37) --------------------------------
 
 
+def _layer_shapes(layer, x, one_chip):
+    """``(variables, x)`` as shapes on the described chip."""
+    variables = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype)))
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), (variables, x))
+
+
 def _layer_grad(layer, x, one_chip):
     """``(compiled value, compiled gradient over parameters and input)`` of
     ``sum(layer(x))`` for the described chip, and the shapes compiled on."""
-    variables = jax.eval_shape(
-        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype)))
-    shapes = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), (variables, x))
+    shapes = _layer_shapes(layer, x, one_chip)
 
     def value(v, x_):
         return jnp.sum(layer.apply(v, x_).astype(jnp.float32))
@@ -659,3 +666,55 @@ def test_the_tiny_cuts_mamba_layer_takes_the_plain_path(topo, cache_off, monkeyp
     for compiled in (forward, gradient):
         assert "mpi4dl_ssd_scan" not in compiled.as_text()
     assert " while(" in gradient.as_text()
+
+
+# -- what "cell" remat keeps of a kernel (PR 44) -------------------------------
+
+
+def _sdar_attention_cell():
+    """The SDAR cell's attention cell (``chipbench/configs/sdar_30b_a3b_
+    share8.json``: hidden 2048, 32 query heads of 128 over 4 key-value heads,
+    diffusion blocks of 4) on a noisy copy beside a clean one of 8,192."""
+    from mpi4dl_tpu.models.sdar import SDARAttention, SDARConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", "sdar_30b_a3b_share8.json")) as f:
+        config = SDARConfig.from_dict(json.load(f))
+    return SDARAttention(config), (1, 16384, config.hidden_size)
+
+
+def _gated_delta_layer():
+    """Qwen3-Next's Gated DeltaNet mixer on the cell's two sequences."""
+    from mpi4dl_tpu.ops.sequence import GatedDeltaNet
+
+    return GatedDeltaNet(2048, 16, 32, 128, 128, 4, 1e-6), (2, 8192, 2048)
+
+
+@pytest.mark.parametrize("build, start", [
+    (_sdar_attention_cell, "mpi4dl_blockdiff_attention"),
+    (_gated_delta_layer, "mpi4dl_delta_rule"),
+], ids=["sdar_attention_cell", "gated_delta_layer"])
+def test_a_kernels_forward_runs_once_under_the_cell_checkpoint(
+        topo, cache_off, monkeypatch, build, start):
+    """A cell's value and gradient under ``train._cell_ckpt`` at the cell's
+    width, the gates steered to their TPU branch, for one described chip: the
+    compiled text holds the kernel's forward once and its backward once. What
+    the forward wrote is kept by name (``config.KERNEL_RESIDUAL``), so the
+    cell's replay has no use for a second call; under a bare
+    ``jax.checkpoint`` there are two (``tests/test_kernel_residuals.py``
+    holds that, and the gradients' bits, in the interpreter)."""
+    from mpi4dl_tpu.train import _cell_ckpt
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    layer, x_shape = build()
+    shapes = _layer_shapes(layer, jax.ShapeDtypeStruct(x_shape, jnp.bfloat16), one_chip)
+
+    def value(v, x_):
+        return jnp.sum(_cell_ckpt()(layer.apply)(v, x_).astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(value, argnums=(0, 1))).lower(*shapes).compile()
+    found = _kernel_calls(compiled, start, (start + "_fwd", start + "_bwd"))
+    assert {name: len(calls) for name, calls in found.items()} == {
+        start + "_fwd": 1, start + "_bwd": 1}, found
+    assert "checkpoint" in found[start + "_bwd"][0]  # the replay holds the backward alone
