@@ -26,6 +26,7 @@ import dataclasses
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from mpi4dl_tpu.ops.sequence import (
@@ -102,7 +103,9 @@ class LFM2Embed(nn.Module):
     @nn.compact
     def __call__(self, ids):
         c = self.config
-        return Embedding(c.vocab_size, c.hidden_size, name="embed_tokens")(ids).astype(self.dtype)
+        with jax.named_scope("mpi4dl_part_block"):
+            return Embedding(
+                c.vocab_size, c.hidden_size, name="embed_tokens")(ids).astype(self.dtype)
 
 
 class LFM2Layer(nn.Module):
@@ -120,7 +123,8 @@ class LFM2Layer(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.config
-        normed = RMSNorm(c.norm_eps, name="operator_norm")(x)
+        with jax.named_scope("mpi4dl_part_block"):
+            normed = RMSNorm(c.norm_eps, name="operator_norm")(x)
         if self.operator == "conv":
             op = ShortConv(c.hidden_size, c.conv_L_cache, self.dtype, name="conv")
         elif self.operator == "full_attention":
@@ -129,8 +133,10 @@ class LFM2Layer(nn.Module):
                 c.norm_eps, c.rope_theta, dtype=self.dtype, name="self_attn")
         else:
             raise ValueError(f"unknown layer type {self.operator!r}")
-        h = x + op(normed)
-        normed = RMSNorm(c.norm_eps, name="ffn_norm")(h)
+        mixed = op(normed)
+        with jax.named_scope("mpi4dl_part_block"):
+            h = x + mixed
+            normed = RMSNorm(c.norm_eps, name="ffn_norm")(h)
         if self.experts:
             ffn = ExpertFFN(
                 c.hidden_size, c.moe_intermediate_size, c.router_experts,
@@ -140,7 +146,9 @@ class LFM2Layer(nn.Module):
         else:
             ffn = SwiGLU(c.hidden_size, c.intermediate_size, self.dtype,
                          name="feed_forward")
-        return h + ffn(normed)
+        fed = ffn(normed)
+        with jax.named_scope("mpi4dl_part_block"):
+            return h + fed
 
 
 class LFM2Head(nn.Module):

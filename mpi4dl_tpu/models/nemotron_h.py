@@ -33,6 +33,7 @@ import dataclasses
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from mpi4dl_tpu.ops.sequence import (
@@ -139,7 +140,9 @@ class NemotronHEmbed(nn.Module):
     @nn.compact
     def __call__(self, ids):
         c = self.config
-        return Embedding(c.vocab_size, c.hidden_size, name="embed_tokens")(ids).astype(self.dtype)
+        with jax.named_scope("mpi4dl_part_block"):
+            return Embedding(
+                c.vocab_size, c.hidden_size, name="embed_tokens")(ids).astype(self.dtype)
 
 
 class NemotronHLayer(nn.Module):
@@ -158,7 +161,8 @@ class NemotronHLayer(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.config
-        normed = RMSNorm(c.layer_norm_epsilon, name="norm")(x)
+        with jax.named_scope("mpi4dl_part_block"):
+            normed = RMSNorm(c.layer_norm_epsilon, name="norm")(x)
         if self.kind == "M":
             mixer = Mamba2(
                 c.hidden_size, c.mamba_num_heads, c.mamba_head_dim, c.n_groups,
@@ -178,7 +182,9 @@ class NemotronHLayer(nn.Module):
                 rotary_dim=0, qk_norm=False, block=ATTENTION_BLOCK, name="mixer")
         else:
             raise ValueError(f"unknown layer kind {self.kind!r}")
-        return x + mixer(normed)
+        mixed = mixer(normed)
+        with jax.named_scope("mpi4dl_part_block"):
+            return x + mixed
 
 
 class NemotronHHead(nn.Module):

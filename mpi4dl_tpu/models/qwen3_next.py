@@ -30,6 +30,7 @@ import dataclasses
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from mpi4dl_tpu.ops.sequence import (
@@ -118,7 +119,9 @@ class Qwen3NextEmbed(nn.Module):
     @nn.compact
     def __call__(self, ids):
         c = self.config
-        return Embedding(c.vocab_size, c.hidden_size, name="embed_tokens")(ids).astype(self.dtype)
+        with jax.named_scope("mpi4dl_part_block"):
+            return Embedding(
+                c.vocab_size, c.hidden_size, name="embed_tokens")(ids).astype(self.dtype)
 
 
 class Qwen3NextLayer(nn.Module):
@@ -134,7 +137,8 @@ class Qwen3NextLayer(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.config
-        normed = RMSNorm(c.rms_norm_eps, True, name="input_layernorm")(x)
+        with jax.named_scope("mpi4dl_part_block"):
+            normed = RMSNorm(c.rms_norm_eps, True, name="input_layernorm")(x)
         if self.mixer == "linear_attention":
             mix = GatedDeltaNet(
                 c.hidden_size, c.linear_num_key_heads, c.linear_num_value_heads,
@@ -150,15 +154,19 @@ class Qwen3NextLayer(nn.Module):
                 output_gate=True, zero_centred_norms=True, name="self_attn")
         else:
             raise ValueError(f"unknown mixer {self.mixer!r}")
-        h = x + mix(normed)
-        normed = RMSNorm(c.rms_norm_eps, True, name="post_attention_layernorm")(h)
+        mixed = mix(normed)
+        with jax.named_scope("mpi4dl_part_block"):
+            h = x + mixed
+            normed = RMSNorm(c.rms_norm_eps, True, name="post_attention_layernorm")(h)
         moe = ExpertFFN(
             c.hidden_size, c.moe_intermediate_size, c.router_experts,
             c.num_experts, c.first_expert, c.num_experts_per_tok,
             c.norm_topk_prob, expert_bias=False, dtype=self.dtype,
             scoring="softmax", shared_width=c.shared_expert_intermediate_size,
             name="mlp")
-        return h + moe(normed)
+        fed = moe(normed)
+        with jax.named_scope("mpi4dl_part_block"):
+            return h + fed
 
 
 class Qwen3NextHead(nn.Module):

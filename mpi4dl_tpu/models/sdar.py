@@ -112,7 +112,9 @@ class SDAREmbed(nn.Module):
     @nn.compact
     def __call__(self, ids):
         c = self.config
-        return Embedding(c.vocab_size, c.hidden_size, name="embed_tokens")(ids).astype(self.dtype)
+        with jax.named_scope("mpi4dl_part_block"):
+            return Embedding(
+                c.vocab_size, c.hidden_size, name="embed_tokens")(ids).astype(self.dtype)
 
 
 class SDARAttention(nn.Module):
@@ -128,11 +130,14 @@ class SDARAttention(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.config
-        normed = RMSNorm(c.rms_norm_eps, name="input_layernorm")(x)
-        return x + Attention(
+        with jax.named_scope("mpi4dl_part_block"):
+            normed = RMSNorm(c.rms_norm_eps, name="input_layernorm")(x)
+        mixed = Attention(
             c.hidden_size, c.num_attention_heads, c.num_key_value_heads,
             c.rms_norm_eps, c.rope_theta, dtype=self.dtype, head_dim=c.head_dim,
             diffusion_block=c.block_length, name="self_attn")(normed)
+        with jax.named_scope("mpi4dl_part_block"):
+            return x + mixed
 
 
 class SDARExperts(nn.Module):
@@ -147,13 +152,16 @@ class SDARExperts(nn.Module):
     @nn.compact
     def __call__(self, h):
         c = self.config
-        normed = RMSNorm(c.rms_norm_eps, name="post_attention_layernorm")(h)
+        with jax.named_scope("mpi4dl_part_block"):
+            normed = RMSNorm(c.rms_norm_eps, name="post_attention_layernorm")(h)
         with jax.named_scope("sdar_moe"):
-            return h + ExpertFFN(
+            fed = ExpertFFN(
                 c.hidden_size, c.moe_intermediate_size, c.router_experts,
                 c.num_experts, c.first_expert, c.num_experts_per_tok,
                 c.norm_topk_prob, expert_bias=False, dtype=self.dtype,
                 scoring="softmax", name="mlp")(normed)
+            with jax.named_scope("mpi4dl_part_block"):
+                return h + fed
 
 
 class SDARHead(nn.Module):

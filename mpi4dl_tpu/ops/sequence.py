@@ -30,13 +30,37 @@ Activations are ``[batch, positions, features]``. Each module computes in
 its ``dtype`` (bfloat16 on the chip) with float32 parameters, float32
 normalisation statistics and a float32 router.
 
-The device trace finds the mechanisms by ``jax.named_scope``:
-``lfm2_moe`` (router, top-k, sort, grouped products, combine; inside it
-``shared_expert`` where the layer has one), ``lfm2_attention``,
-``lfm2_shortconv``, ``gated_delta`` (inside it ``gated_delta_rule``, the
-chunked rule without the projections) and ``mamba2`` (inside it ``ssd_scan``,
-the chunked scan alone). The shared modules keep the names
-their first model gave them: the benchmark's readers find them by name.
+The device trace finds the mechanisms by ``jax.named_scope``, at two levels.
+**A mixer**: ``lfm2_moe`` (an expert layer; inside it ``shared_expert`` where
+the layer has one; ``models/sdar.py`` opens ``sdar_moe`` around it),
+``lfm2_attention`` (under block diffusion ``blockdiff_attention``, its kernels
+under ``blockdiff_attention_kernels``), ``lfm2_shortconv``, ``gated_delta``
+and ``mamba2``. The shared modules keep the names their first model gave
+them: the benchmark's readers find them by name. **A part** of a mixer, so
+that every line of a mixer's ``__call__`` falls under one:
+``mpi4dl_part_proj`` (the dense projections into and out of a mixer with
+their weights' casts, and the dense feed-forwards ``SwiGLU`` / ``SquaredReLU``,
+a shared expert's among them), ``mpi4dl_part_conv``
+(``causal_depthwise_conv1d`` with its bias, SiLU and, in ``ShortConv``, its
+two gates), ``mpi4dl_part_gates_norms`` (``beta``, ``g``, ``dt``'s softplus and
+the decays, the L2 norms of q and k, ``RMSNorm(o) * silu(z)``,
+``_gated_group_norm`` with ``D x``, attention's output gate), the recurrences
+under the names they had (``ssd_scan``, ``gated_delta_rule``),
+``mpi4dl_part_qk_prep`` (the q/k head norms, the rotary embedding, the
+reshapes and casts into the kernels' operands), ``mpi4dl_part_attn_core``
+(``causal_attention`` / ``block_diffusion_attention``, kernels and plain
+path), ``mpi4dl_part_router`` (the router's product, scores, bias, ``top_k``,
+weights), ``mpi4dl_part_dispatch`` (``group``, ``sizes``, the sorts, the
+ranges' gathers and sums by token, the two-range conditionals) and, opened
+inside it by ``_grouped_ffn``, ``mpi4dl_part_expert_products`` (the grouped
+products with the activation between them; ``_whole_tiles`` and the expert
+arrays' casts); the models' files open ``mpi4dl_part_block`` around a layer's
+pre-norms, its residual adds and the embedding. A part is the innermost part
+scope of a name stack. The backward rules this file writes
+(``_token_sums_bwd``, ``_ranges_bwd``, ``_attention_bwd``, ``_inverse_bwd``)
+open none: their operators carry the stack of the call they belong to
+(``tests/test_token_scopes.py`` holds them to it). Scopes are metadata: no
+traced or compiled instruction depends on one.
 """
 
 from __future__ import annotations
@@ -135,12 +159,16 @@ class ShortConv(nn.Module):
     @nn.compact
     def __call__(self, x):
         with jax.named_scope("lfm2_shortconv"):
-            x = x.astype(self.dtype)
-            gate_in, gate_out, u = jnp.split(
-                linear(3 * self.hidden, self.dtype, "in_proj")(x), 3, axis=-1)
-            kernel = _Kernel((self.taps, self.hidden), name="conv")()
-            v = causal_depthwise_conv1d(gate_in * u, kernel.astype(self.dtype))
-            return linear(self.hidden, self.dtype, "out_proj")(gate_out * v)
+            with jax.named_scope("mpi4dl_part_proj"):
+                x = x.astype(self.dtype)
+                gate_in, gate_out, u = jnp.split(
+                    linear(3 * self.hidden, self.dtype, "in_proj")(x), 3, axis=-1)
+            with jax.named_scope("mpi4dl_part_conv"):  # with the two gates around it
+                kernel = _Kernel((self.taps, self.hidden), name="conv")()
+                v = gate_out * causal_depthwise_conv1d(
+                    gate_in * u, kernel.astype(self.dtype))
+            with jax.named_scope("mpi4dl_part_proj"):
+                return linear(self.hidden, self.dtype, "out_proj")(v)
 
 
 class _Kernel(nn.Module):
@@ -327,36 +355,45 @@ class GatedDeltaNet(nn.Module):
             batch, length, _ = x.shape
             heads, per_key = self.key_heads, self.value_heads // self.key_heads
             keys, values = heads * self.key_dim, self.value_heads * self.value_dim
-            x = x.astype(self.dtype)
-            qkv, z = jnp.split(
-                linear(2 * keys + 2 * values, self.dtype, "in_proj_qkvz")(x),
-                [2 * keys + values], axis=-1)
-            w_ba = _Kernel((self.hidden, 2 * self.value_heads), name="in_proj_ba")()
-            b, a = jnp.split(jnp.matmul(
-                x, w_ba.astype(self.dtype), preferred_element_type=jnp.float32), 2, axis=-1)
-            kernel = _Kernel((self.taps, 2 * keys + values), name="conv")()
-            q, k, v = jnp.split(
-                nn.silu(causal_depthwise_conv1d(qkv, kernel.astype(self.dtype))),
-                [keys, 2 * keys], axis=-1)
+            with jax.named_scope("mpi4dl_part_proj"):
+                x = x.astype(self.dtype)
+                qkv, z = jnp.split(
+                    linear(2 * keys + 2 * values, self.dtype, "in_proj_qkvz")(x),
+                    [2 * keys + values], axis=-1)
+                w_ba = _Kernel((self.hidden, 2 * self.value_heads), name="in_proj_ba")()
+                b, a = jnp.split(jnp.matmul(
+                    x, w_ba.astype(self.dtype), preferred_element_type=jnp.float32),
+                    2, axis=-1)
+            with jax.named_scope("mpi4dl_part_conv"):
+                kernel = _Kernel((self.taps, 2 * keys + values), name="conv")()
+                q, k, v = jnp.split(
+                    nn.silu(causal_depthwise_conv1d(qkv, kernel.astype(self.dtype))),
+                    [keys, 2 * keys], axis=-1)
 
-            a_log = self.param("A_log", nn.initializers.normal(2.0), (self.value_heads,))
-            dt_bias = self.param("dt_bias", nn.initializers.ones, (self.value_heads,))
-            g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
-            beta = jax.nn.sigmoid(b)
+            with jax.named_scope("mpi4dl_part_gates_norms"):
+                a_log = self.param(
+                    "A_log", nn.initializers.normal(2.0), (self.value_heads,))
+                dt_bias = self.param("dt_bias", nn.initializers.ones, (self.value_heads,))
+                g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
+                beta = jax.nn.sigmoid(b)
 
-            def unit(t):  # a head's dims to length 1, in float32
-                t = t.reshape(batch, length, heads, self.key_dim).astype(jnp.float32)
-                return t * lax.rsqrt(jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+                def unit(t):  # a head's dims to length 1, in float32
+                    t = t.reshape(batch, length, heads, self.key_dim).astype(jnp.float32)
+                    return t * lax.rsqrt(
+                        jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
 
-            q = (unit(q) * self.key_dim ** -0.5).astype(self.dtype)
-            by_head = (batch, length, heads, per_key)
-            out = gated_delta_rule(
-                q, unit(k).astype(self.dtype), v.reshape(*by_head, self.value_dim),
-                g.reshape(by_head), beta.reshape(by_head))
-            out = RMSNorm(self.eps, name="norm")(out) * nn.silu(
-                z.reshape(out.shape).astype(jnp.float32))
-            return linear(self.hidden, self.dtype, "out_proj")(
-                out.astype(self.dtype).reshape(batch, length, values))
+                q = (unit(q) * self.key_dim ** -0.5).astype(self.dtype)
+                k = unit(k).astype(self.dtype)
+                by_head = (batch, length, heads, per_key)
+                v, g, beta = (v.reshape(*by_head, self.value_dim),
+                              g.reshape(by_head), beta.reshape(by_head))
+            out = gated_delta_rule(q, k, v, g, beta)
+            with jax.named_scope("mpi4dl_part_gates_norms"):
+                out = RMSNorm(self.eps, name="norm")(out) * nn.silu(
+                    z.reshape(out.shape).astype(jnp.float32))
+            with jax.named_scope("mpi4dl_part_proj"):
+                return linear(self.hidden, self.dtype, "out_proj")(
+                    out.astype(self.dtype).reshape(batch, length, values))
 
 
 # -- Mamba-2 -----------------------------------------------------------------
@@ -506,33 +543,43 @@ class Mamba2(nn.Module):
             batch, length, _ = x.shape
             inner, per = self.heads * self.head_dim, self.heads // self.groups
             mixed = inner + 2 * self.groups * self.state
-            x = x.astype(self.dtype)
-            w_in = _Kernel((self.hidden, inner + mixed + self.heads), name="in_proj")()
-            w_in = w_in.astype(self.dtype)
-            z, xbc = jnp.split(jnp.matmul(x, w_in[:, :inner + mixed]), [inner], axis=-1)
-            dt = jnp.matmul(x, w_in[:, inner + mixed:], preferred_element_type=jnp.float32)
-            kernel = _Kernel((self.taps, mixed), name="conv")()
-            bias = self.param("conv_bias", nn.initializers.zeros, (mixed,))
-            xbc = nn.silu(causal_depthwise_conv1d(xbc, kernel.astype(self.dtype))
-                          + bias.astype(self.dtype))
-            u, b, c = jnp.split(xbc, [inner, inner + self.groups * self.state], axis=-1)
+            with jax.named_scope("mpi4dl_part_proj"):
+                x = x.astype(self.dtype)
+                w_in = _Kernel(
+                    (self.hidden, inner + mixed + self.heads), name="in_proj")()
+                w_in = w_in.astype(self.dtype)
+                z, xbc = jnp.split(
+                    jnp.matmul(x, w_in[:, :inner + mixed]), [inner], axis=-1)
+                dt = jnp.matmul(
+                    x, w_in[:, inner + mixed:], preferred_element_type=jnp.float32)
+            with jax.named_scope("mpi4dl_part_conv"):
+                kernel = _Kernel((self.taps, mixed), name="conv")()
+                bias = self.param("conv_bias", nn.initializers.zeros, (mixed,))
+                xbc = nn.silu(causal_depthwise_conv1d(xbc, kernel.astype(self.dtype))
+                              + bias.astype(self.dtype))
+                u, b, c = jnp.split(
+                    xbc, [inner, inner + self.groups * self.state], axis=-1)
 
-            by_head = (batch, length, self.groups, per)
-            a_log = self.param(
-                "A_log", lambda key, shape: jnp.log(
-                    jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)), (self.heads,))
-            dt_bias = self.param(
-                "dt_bias", _log_uniform_inverse_softplus(*self.dt_range), (self.heads,))
-            skip = self.param("D", nn.initializers.ones, (self.heads,))
-            scale = self.param("norm_scale", nn.initializers.ones, (inner,))
-            dt = jax.nn.softplus(dt + dt_bias).reshape(by_head)
-            g = dt * -jnp.exp(a_log).reshape(self.groups, per)
-            u = u.reshape(*by_head, self.head_dim)
-            by_group = (batch, length, self.groups, self.state)
-            y = ssd_scan((u * dt[..., None]).astype(self.dtype), g,
-                         b.reshape(by_group), c.reshape(by_group), self.chunk)
-            y = _gated_group_norm(y, u, z, skip, scale, self.eps)
-            return linear(self.hidden, self.dtype, "out_proj")(y)
+            with jax.named_scope("mpi4dl_part_gates_norms"):
+                by_head = (batch, length, self.groups, per)
+                a_log = self.param(
+                    "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                        key, shape, jnp.float32, 1.0, 16.0)), (self.heads,))
+                dt_bias = self.param(
+                    "dt_bias", _log_uniform_inverse_softplus(*self.dt_range), (self.heads,))
+                skip = self.param("D", nn.initializers.ones, (self.heads,))
+                scale = self.param("norm_scale", nn.initializers.ones, (inner,))
+                dt = jax.nn.softplus(dt + dt_bias).reshape(by_head)
+                g = dt * -jnp.exp(a_log).reshape(self.groups, per)
+                u = u.reshape(*by_head, self.head_dim)
+                by_group = (batch, length, self.groups, self.state)
+                moved = (u * dt[..., None]).astype(self.dtype)
+                b, c = b.reshape(by_group), c.reshape(by_group)
+            y = ssd_scan(moved, g, b, c, self.chunk)
+            with jax.named_scope("mpi4dl_part_gates_norms"):
+                y = _gated_group_norm(y, u, z, skip, scale, self.eps)
+            with jax.named_scope("mpi4dl_part_proj"):
+                return linear(self.hidden, self.dtype, "out_proj")(y)
 
 
 # -- attention ---------------------------------------------------------------
@@ -774,37 +821,44 @@ class Attention(nn.Module):
             mask = positions = None
             if self.diffusion_block:
                 mask = BlockMask(length // 2, self.diffusion_block)
-                positions = jnp.arange(length) % mask.length
-            x = x.astype(self.dtype)
-            q = linear(self.heads * d * (1 + self.output_gate), self.dtype, "q_proj")(x)
-            k = linear(self.kv_heads * d, self.dtype, "k_proj")(x)
-            v = linear(self.kv_heads * d, self.dtype, "v_proj")(x)
-            if self.output_gate:
-                q, gate = jnp.split(
-                    q.reshape(batch, length, self.heads, 2 * d), 2, axis=-1)
-            else:
-                q = q.reshape(batch, length, self.heads, d)
-            k = k.reshape(batch, length, self.kv_heads, d)
-            v = v.reshape(batch, length, self.kv_heads, d)
+                with jax.named_scope("mpi4dl_part_qk_prep"):
+                    positions = jnp.arange(length) % mask.length
+            with jax.named_scope("mpi4dl_part_proj"):
+                x = x.astype(self.dtype)
+                q = linear(self.heads * d * (1 + self.output_gate), self.dtype, "q_proj")(x)
+                k = linear(self.kv_heads * d, self.dtype, "k_proj")(x)
+                v = linear(self.kv_heads * d, self.dtype, "v_proj")(x)
+            with jax.named_scope("mpi4dl_part_qk_prep"):
+                if self.output_gate:
+                    q, gate = jnp.split(
+                        q.reshape(batch, length, self.heads, 2 * d), 2, axis=-1)
+                else:
+                    q = q.reshape(batch, length, self.heads, d)
+                k = k.reshape(batch, length, self.kv_heads, d)
+                v = v.reshape(batch, length, self.kv_heads, d)
 
-            def placed(t, name):  # a head's norm, then its rotation
-                if self.qk_norm:
-                    t = RMSNorm(self.eps, self.zero_centred_norms, name=name)(t)
-                if self.rotary_dim == 0:
-                    return t
-                return rope(t, self.rope_theta, self.rotary_dim, positions)
+                def placed(t, name):  # a head's norm, then its rotation
+                    if self.qk_norm:
+                        t = RMSNorm(self.eps, self.zero_centred_norms, name=name)(t)
+                    if self.rotary_dim == 0:
+                        return t
+                    return rope(t, self.rope_theta, self.rotary_dim, positions)
 
-            q, k = placed(q, "q_layernorm"), placed(k, "k_layernorm")
-            q = q.astype(self.dtype).reshape(
-                batch, length, self.kv_heads, self.heads // self.kv_heads, d)
-            if mask is None:
-                out = causal_attention(q, k.astype(self.dtype), v, self.block)
-            else:
-                out = block_diffusion_attention(q, k.astype(self.dtype), v, self.block, mask)
-            out = out.reshape(batch, length, self.heads * d)
+                q, k = placed(q, "q_layernorm"), placed(k, "k_layernorm")
+                q = q.astype(self.dtype).reshape(
+                    batch, length, self.kv_heads, self.heads // self.kv_heads, d)
+                k = k.astype(self.dtype)
+            with jax.named_scope("mpi4dl_part_attn_core"):
+                if mask is None:
+                    out = causal_attention(q, k, v, self.block)
+                else:
+                    out = block_diffusion_attention(q, k, v, self.block, mask)
+                out = out.reshape(batch, length, self.heads * d)
             if self.output_gate:
-                out = out * jax.nn.sigmoid(gate.reshape(out.shape))
-            return linear(self.hidden, self.dtype, "out_proj")(out)
+                with jax.named_scope("mpi4dl_part_gates_norms"):
+                    out = out * jax.nn.sigmoid(gate.reshape(out.shape))
+            with jax.named_scope("mpi4dl_part_proj"):
+                return linear(self.hidden, self.dtype, "out_proj")(out)
 
 
 # -- feed-forwards -----------------------------------------------------------
@@ -819,10 +873,11 @@ class SwiGLU(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        x = x.astype(self.dtype)
-        gate = nn.silu(linear(self.width, self.dtype, "w1")(x))
-        return linear(self.hidden, self.dtype, "w2")(
-            gate * linear(self.width, self.dtype, "w3")(x))
+        with jax.named_scope("mpi4dl_part_proj"):
+            x = x.astype(self.dtype)
+            gate = nn.silu(linear(self.width, self.dtype, "w1")(x))
+            return linear(self.hidden, self.dtype, "w2")(
+                gate * linear(self.width, self.dtype, "w3")(x))
 
 
 class SquaredReLU(nn.Module):
@@ -834,8 +889,9 @@ class SquaredReLU(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        up = nn.relu(linear(self.width, self.dtype, "w1")(x.astype(self.dtype)))
-        return linear(self.hidden, self.dtype, "w2")(jnp.square(up))
+        with jax.named_scope("mpi4dl_part_proj"):
+            up = nn.relu(linear(self.width, self.dtype, "w1")(x.astype(self.dtype)))
+            return linear(self.hidden, self.dtype, "w2")(jnp.square(up))
 
 
 def _in_token_order(a, inverse, lo: int):
@@ -952,13 +1008,14 @@ def _grouped_ffn(rows, experts, groups):
     which feed-forward it is: three (``w1, w3, w2``) the gated SiLU,
     ``(silu(x w1) * x w3) w2``; two (``w1, w2``) the plain squared ReLU,
     ``relu(x w1)^2 w2``."""
-    if len(experts) == 3:
-        w1, w3, w2 = experts
-        gate = nn.silu(lax.ragged_dot(rows, w1, groups))
-        return lax.ragged_dot(gate * lax.ragged_dot(rows, w3, groups), w2, groups)
-    w1, w2 = experts
-    return lax.ragged_dot(
-        jnp.square(nn.relu(lax.ragged_dot(rows, w1, groups))), w2, groups)
+    with jax.named_scope("mpi4dl_part_expert_products"):
+        if len(experts) == 3:
+            w1, w3, w2 = experts
+            gate = nn.silu(lax.ragged_dot(rows, w1, groups))
+            return lax.ragged_dot(gate * lax.ragged_dot(rows, w3, groups), w2, groups)
+        w1, w2 = experts
+        return lax.ragged_dot(
+            jnp.square(nn.relu(lax.ragged_dot(rows, w1, groups))), w2, groups)
 
 
 def _range_ffn(bounds, x, weights, experts, order, inverse, sizes):
@@ -1184,56 +1241,64 @@ class ExpertFFN(nn.Module):
     def __call__(self, x):
         """``x``: the normalised input in float32, ``[batch, positions, hidden]``."""
         with jax.named_scope("lfm2_moe"):
-            shape = x.shape
-            x = x.reshape(-1, self.hidden)
-            tokens, k = x.shape[0], self.per_token
-            router = _Kernel((self.hidden, self.experts), name="gate")()
-            score = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[self.scoring]
-            scores = score(
-                jnp.matmul(x, router, precision=lax.Precision.HIGHEST))
-            choose_on = scores
-            if self.expert_bias:
-                choose_on = scores + self.param(
-                    "expert_bias", nn.initializers.zeros, (self.experts,))
-            _, chosen = lax.top_k(choose_on, k)
-            weights = jnp.take_along_axis(scores, chosen, axis=-1)
-            if self.norm_topk:
-                weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-            weights = weights * self.scaling
+            with jax.named_scope("mpi4dl_part_router"):
+                shape = x.shape
+                x = x.reshape(-1, self.hidden)
+                tokens, k = x.shape[0], self.per_token
+                router = _Kernel((self.hidden, self.experts), name="gate")()
+                score = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[self.scoring]
+                scores = score(
+                    jnp.matmul(x, router, precision=lax.Precision.HIGHEST))
+                choose_on = scores
+                if self.expert_bias:
+                    choose_on = scores + self.param(
+                        "expert_bias", nn.initializers.zeros, (self.experts,))
+                _, chosen = lax.top_k(choose_on, k)
+                weights = jnp.take_along_axis(scores, chosen, axis=-1)
+                if self.norm_topk:
+                    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+                weights = weights * self.scaling
 
             # token-expert pairs sorted by expert, the held experts' first
-            local = chosen - self.first
-            group = jnp.where((local >= 0) & (local < self.held), local, self.held)
-            sizes = jnp.sum(
-                group[..., None] == jnp.arange(self.held), axis=(0, 1), dtype=jnp.int32)
-            pairs = tokens * k
-            prefix = _prefix_rows(pairs, self.held, self.experts)
-            if not self.is_initializing():  # ``init`` returns parameters alone
-                self.sow(COUNTERS, "expert_pairs", sizes)
-                self.sow(COUNTERS, "prefix_alone",
-                         (jnp.sum(sizes) <= prefix).astype(jnp.int32))
-            _, order = lax.sort_key_val(
-                group.reshape(-1), jnp.arange(pairs, dtype=jnp.int32))
-            inverse = jnp.argsort(order)
+            with jax.named_scope("mpi4dl_part_dispatch"):
+                local = chosen - self.first
+                group = jnp.where((local >= 0) & (local < self.held), local, self.held)
+                sizes = jnp.sum(
+                    group[..., None] == jnp.arange(self.held), axis=(0, 1), dtype=jnp.int32)
+                pairs = tokens * k
+                prefix = _prefix_rows(pairs, self.held, self.experts)
+                if not self.is_initializing():  # ``init`` returns parameters alone
+                    self.sow(COUNTERS, "expert_pairs", sizes)
+                    self.sow(COUNTERS, "prefix_alone",
+                             (jnp.sum(sizes) <= prefix).astype(jnp.int32))
+                _, order = lax.sort_key_val(
+                    group.reshape(-1), jnp.arange(pairs, dtype=jnp.int32))
+                inverse = jnp.argsort(order)
 
             gated = {"swiglu": True, "relu2": False}[self.activation]
-            experts = _whole_tiles(tuple(w.astype(self.dtype) for w in _ExpertWeights(
-                self.held, self.hidden, self.width, gated, name="experts")()))
-            operands = (x.astype(self.dtype), weights, experts, order, inverse, sizes)
-            if prefix < pairs:
-                out = _ranges_ffn(prefix, *operands)
-            else:
-                out = _range_ffn((0, prefix, 0), *operands)
-            out = out.astype(self.dtype)
+            with jax.named_scope("mpi4dl_part_expert_products"):
+                experts = _whole_tiles(tuple(w.astype(self.dtype) for w in _ExpertWeights(
+                    self.held, self.hidden, self.width, gated, name="experts")()))
+            # the ranges' gathers, sums by token and conditionals; the grouped
+            # products inside them open their own part (``_grouped_ffn``)
+            with jax.named_scope("mpi4dl_part_dispatch"):
+                operands = (x.astype(self.dtype), weights, experts, order, inverse, sizes)
+                if prefix < pairs:
+                    out = _ranges_ffn(prefix, *operands)
+                else:
+                    out = _range_ffn((0, prefix, 0), *operands)
+                out = out.astype(self.dtype)
             if self.shared_width:
-                with jax.named_scope("shared_expert"):
+                # a dense feed-forward (it opens the part again around itself)
+                with jax.named_scope("shared_expert"), jax.named_scope("mpi4dl_part_proj"):
                     gate = jax.nn.sigmoid(linear(1, self.dtype, "shared_expert_gate")(
                         operands[0])) if self.shared_gate else None
                     shared = (SwiGLU if gated else SquaredReLU)(
                         self.hidden, self.shared_width, self.dtype,
                         name="shared_expert")(operands[0])
                     out = out + (shared if gate is None else gate * shared)
-            return out.reshape(shape)
+            with jax.named_scope("mpi4dl_part_dispatch"):  # back in the input's shape
+                return out.reshape(shape)
 
 
 def step_counters(counted: dict) -> dict:

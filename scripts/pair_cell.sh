@@ -11,6 +11,11 @@
 #       on the chip: <traced> (0 or 1) traced pairs, then <pairs> times parent,
 #       change, change, parent (two pairs, each its own seed, the two sides of a
 #       pair sharing it), then <more> runs of the change alone, each its own seed.
+#       <traced> "parts": the traced pair through chipbench/tools/token_table.py
+#       (a token cell), which prints run.py's line and then the step by cell,
+#       mixer and part, and keeps what the tables were made from in
+#       chiprun_out/parts_<cell> (the change) and parts_<cell>.parent; the
+#       printed tables go to chiprun_out/pairs/<cell>.<seed>.<side>.table.txt.
 #       Seeds count up from <seed>. No run starts after <limit_s> seconds
 #       (2700) and none is killed: a run cut while it holds the chip loses it.
 #       Every run's last line goes to chiprun_out/pairs/<cell>.<seed>.jsonl
@@ -41,9 +46,19 @@ one() {  # side seed trace
     now=$(( $(date +%s) - began ))
     if [ "$now" -gt "$limit" ]; then echo "SKIPPED $1 seed=$2 trace=$3 at ${now}s"; return; fi
     start=$(date +%s)
-    (cd ".cache/pair/$1" && python3 chipbench/run.py --workload "$cell" --seed "$2" \
-        --seconds "$seconds" --trace "$3") > "$out/last.out" 2> "$out/last.err"
-    rc=$?
+    if [ "$3" = parts ]; then
+        kept=$root/chiprun_out/parts_$cell; [ "$1" = change ] || kept=$kept.$1
+        (cd ".cache/pair/$1" && python3 chipbench/tools/token_table.py --workload "$cell" \
+            --seed "$2" --seconds "$seconds" --dump "$kept") > "$out/last.out" 2> "$out/last.err"
+        rc=$?
+        # the result line, then the tables: the line last, where the next step reads it
+        grep -v '^{"correct"' "$out/last.out" > "$out/$cell.$seed.$1.table.txt"
+        grep '^{"correct"' "$out/last.out" > "$out/last.line" && cat "$out/last.line" >> "$out/last.out"
+    else
+        (cd ".cache/pair/$1" && python3 chipbench/run.py --workload "$cell" --seed "$2" \
+            --seconds "$seconds" --trace "$3") > "$out/last.out" 2> "$out/last.err"
+        rc=$?
+    fi
     wall=$(( $(date +%s) - start ))
     echo "RAN $1 seed=$2 trace=$3 rc=$rc wall=${wall}s"
     tail -n 1 "$out/last.out"
@@ -57,14 +72,14 @@ try:
 except (IndexError, ValueError):
     result = None
 with open(f"{out}/{name}.jsonl", "a") as log:
-    log.write(json.dumps({"side": side, "seed": int(seed), "trace": int(trace), "rc": int(rc),
+    log.write(json.dumps({"side": side, "seed": int(seed), "trace": int(trace != "0"), "rc": int(rc),
                           "wall_s": int(wall), "result": result}) + "\n")
 EOF
 }
 
-if [ "$traced" = 1 ]; then
-    one parent "$seed" 1
-    one change "$seed" 1
+if [ "$traced" != 0 ]; then
+    one parent "$seed" "$traced"
+    one change "$seed" "$traced"
 fi
 n=0
 while [ "$n" -lt "$pairs" ]; do
