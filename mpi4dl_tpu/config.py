@@ -44,9 +44,12 @@ AXIS_PIPE = "pipe"
 AXIS_TILE_H = "tile_h"
 AXIS_TILE_W = "tile_w"
 
-# The ``checkpoint_name`` of everything a fused kernel's forward writes
-# (``ops/attention_pallas``, ``delta_rule_pallas``, ``ssd_scan_pallas``):
-# what "cell" remat keeps beside the cell's input (``train._cell_ckpt``).
+# The ``checkpoint_name`` of what a cell's forward writes that is dear to
+# compute and cheap to hold: everything a fused kernel's forward writes
+# (``ops/attention_pallas``, ``delta_rule_pallas``, ``ssd_scan_pallas``) and
+# what the expert layer's forward chose, sorted, gathered and multiplied
+# (``ops/sequence._kept``). It is what "cell" remat keeps beside the cell's
+# input (``train._cell_ckpt``); the string is in the pinned token programs.
 KERNEL_RESIDUAL = "kernel_residual"
 
 

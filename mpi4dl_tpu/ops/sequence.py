@@ -9,7 +9,10 @@ experts. The
 grouped matrix products are
 ``jax.lax.ragged_dot`` over the token-expert pairs sorted by expert, at the
 width of a prefix of the sorted rows that follows from the share held; the
-rows past it run only in a step whose routing overflows it. The
+rows past it run only in a step whose routing overflows it; what the
+layer's forward chose, sorted, gathered and multiplied carries the name
+"cell" remat keeps (``_kept``), so a training step runs the router, the
+sorts and every grouped product's forward once. The
 ``S x S`` scores of a long sequence never exist at once (32 heads x 8192^2
 floats are 8.6 GB): on a TPU, at the shapes ``ops/attention_pallas.py``
 takes (bfloat16, head dim 64, 128 or 256, a length of whole blocks: all
@@ -73,7 +76,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
+from mpi4dl_tpu.config import KERNEL_RESIDUAL
 from mpi4dl_tpu.ops import attention_pallas, delta_rule_pallas, ssd_scan_pallas
 
 COUNTERS = "counters"  # the flax collection an expert layer sows its counts into
@@ -864,8 +869,19 @@ class Attention(nn.Module):
 # -- feed-forwards -----------------------------------------------------------
 
 
+def _kept(x):
+    """``x`` under the name "cell" remat keeps (``train._cell_ckpt``): the
+    cell's replay computes nothing that only led to ``x``. It holds only
+    where every reader of the value reads what this returns, so a value is
+    named where it is made, and it is the value an activation is taken *of*,
+    never an activation's own result: ``logistic``'s, ``exp``'s and
+    ``softmax``'s derivatives read the unnamed result inside their own rules
+    and would have the replay make it again, with all that led to it."""
+    return checkpoint_name(x, KERNEL_RESIDUAL)
+
+
 class SwiGLU(nn.Module):
-    """``w2(silu(w1 x) * w3 x)``."""
+    """``w2(silu(w1 x) * w3 x)``; ``w1 x`` and ``w3 x`` are ``_kept``."""
 
     hidden: int
     width: int
@@ -875,13 +891,14 @@ class SwiGLU(nn.Module):
     def __call__(self, x):
         with jax.named_scope("mpi4dl_part_proj"):
             x = x.astype(self.dtype)
-            gate = nn.silu(linear(self.width, self.dtype, "w1")(x))
+            gate = nn.silu(_kept(linear(self.width, self.dtype, "w1")(x)))
             return linear(self.hidden, self.dtype, "w2")(
-                gate * linear(self.width, self.dtype, "w3")(x))
+                gate * _kept(linear(self.width, self.dtype, "w3")(x)))
 
 
 class SquaredReLU(nn.Module):
-    """``w2(relu(w1 x)^2)``: Nemotron-H's feed-forward (``relu2``), no gate."""
+    """``w2(relu(w1 x)^2)``: Nemotron-H's feed-forward (``relu2``), no gate;
+    ``w1 x`` is ``_kept``."""
 
     hidden: int
     width: int
@@ -890,7 +907,7 @@ class SquaredReLU(nn.Module):
     @nn.compact
     def __call__(self, x):
         with jax.named_scope("mpi4dl_part_proj"):
-            up = nn.relu(linear(self.width, self.dtype, "w1")(x.astype(self.dtype)))
+            up = nn.relu(_kept(linear(self.width, self.dtype, "w1")(x.astype(self.dtype))))
             return linear(self.hidden, self.dtype, "w2")(jnp.square(up))
 
 
@@ -933,7 +950,8 @@ def _by_token(pairs, inverse, lo, k: int):
     sorted_pairs, order = lax.sort_key_val(pairs, jnp.arange(width, dtype=jnp.int32))
     at = inverse - lo
     count = jnp.sum(((at >= 0) & (at < width)).reshape(-1, k), axis=1, dtype=jnp.int32)
-    return sorted_pairs // k, order, jnp.cumsum(count) - count, count > 0
+    return tuple(map(_kept, (
+        sorted_pairs // k, order, jnp.cumsum(count) - count, count > 0)))
 
 
 def _sum_by_token(rows, scale, by_token, k: int):
@@ -1007,15 +1025,17 @@ def _grouped_ffn(rows, experts, groups):
     """Every row through its group's expert. The arrays an expert has say
     which feed-forward it is: three (``w1, w3, w2``) the gated SiLU,
     ``(silu(x w1) * x w3) w2``; two (``w1, w2``) the plain squared ReLU,
-    ``relu(x w1)^2 w2``."""
+    ``relu(x w1)^2 w2``. ``x w1`` and ``x w3`` are ``_kept`` as the products
+    give them, at the experts' width: the activation and the gate's product
+    over them are cheap to make again and would double the bytes."""
     with jax.named_scope("mpi4dl_part_expert_products"):
         if len(experts) == 3:
             w1, w3, w2 = experts
-            gate = nn.silu(lax.ragged_dot(rows, w1, groups))
-            return lax.ragged_dot(gate * lax.ragged_dot(rows, w3, groups), w2, groups)
+            gate = nn.silu(_kept(lax.ragged_dot(rows, w1, groups)))
+            return lax.ragged_dot(gate * _kept(lax.ragged_dot(rows, w3, groups)), w2, groups)
         w1, w2 = experts
         return lax.ragged_dot(
-            jnp.square(nn.relu(lax.ragged_dot(rows, w1, groups))), w2, groups)
+            jnp.square(nn.relu(_kept(lax.ragged_dot(rows, w1, groups)))), w2, groups)
 
 
 def _range_ffn(bounds, x, weights, experts, order, inverse, sizes):
@@ -1030,7 +1050,12 @@ def _range_ffn(bounds, x, weights, experts, order, inverse, sizes):
     weight, and every token's sum of its rows. All of it at the width of
     the range: nothing here is as wide as all the pairs but one column of
     weights. ``sizes [held]`` are the groups, so the held pairs are the
-    sorted rows ``[0, sum(sizes))``."""
+    sorted rows ``[0, sum(sizes))``. ``_by_token``'s arrays, the gathered
+    rows and the last product's output (which the weights' gradient reads)
+    are ``_kept``; that holds for the prefix range, whose forward is the
+    cell's own. In the ranges past it the names do nothing: they sit in the
+    conditionals' branches, which the cell's checkpoint takes whole, and
+    ``_ranges_bwd`` runs their forward once more as before."""
     lo, width, own = bounds
     k = weights.shape[1]
     ends = jnp.cumsum(sizes)
@@ -1041,10 +1066,10 @@ def _range_ffn(bounds, x, weights, experts, order, inverse, sizes):
     held = ((at >= own) & (at < ends[-1]))[:, None]
     pairs = lax.dynamic_slice_in_dim(order, lo, width)
     by_token = _by_token(pairs, inverse, lo, k)
-    rows = jnp.where(held, _token_rows(x, pairs, by_token, k), 0)
+    rows = _kept(jnp.where(held, _token_rows(x, pairs, by_token, k), 0))
     y = _grouped_ffn(rows, experts, groups)
     scale = _pair_weights(weights.reshape(-1, 1), pairs, inverse, lo)
-    return _token_sums(jnp.where(held, y, 0), scale, pairs, by_token, k)
+    return _token_sums(_kept(jnp.where(held, y, 0)), scale, pairs, by_token, k)
 
 
 def _over_the_rest(prefix, pairs, held_pairs, step, carry):
@@ -1220,7 +1245,20 @@ class ExpertFFN(nn.Module):
     output; the rows and products are ``dtype``, the weighted sum over a
     token's pairs float32. Sows into the ``counters`` collection
     ``expert_pairs [held]``, the token-expert pairs each held expert
-    computed, and ``prefix_alone``, 1 where ``n <= C``."""
+    computed, and ``prefix_alone``, 1 where ``n <= C``.
+
+    What a training step executes of it under "cell" remat
+    (``train._cell_ckpt``): the router's product three times (forward and
+    two gradients), ``top_k`` and each of the three sorts once, the row
+    gather once, every grouped product three times (forward and two
+    gradients: nine calls a gated layer, six a plain one). Their results
+    are ``_kept`` where they are made (the router's product before the
+    scores are taken of it, the choice as ``top_k`` gives it, the chosen
+    scores, ``sizes``, ``order``, ``inverse``; the rest in ``_range_ffn`` and
+    ``_grouped_ffn``), so the replay of the cell makes again only what is
+    elementwise over them: the scores, the weights' normalisation,
+    ``group``, the activations, a gated shared expert's gate and last
+    product. Under a bare ``jax.checkpoint`` each ran once more a step."""
 
     hidden: int
     width: int
@@ -1247,14 +1285,14 @@ class ExpertFFN(nn.Module):
                 tokens, k = x.shape[0], self.per_token
                 router = _Kernel((self.hidden, self.experts), name="gate")()
                 score = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[self.scoring]
-                scores = score(
-                    jnp.matmul(x, router, precision=lax.Precision.HIGHEST))
+                scores = score(_kept(
+                    jnp.matmul(x, router, precision=lax.Precision.HIGHEST)))
                 choose_on = scores
                 if self.expert_bias:
                     choose_on = scores + self.param(
                         "expert_bias", nn.initializers.zeros, (self.experts,))
-                _, chosen = lax.top_k(choose_on, k)
-                weights = jnp.take_along_axis(scores, chosen, axis=-1)
+                chosen = _kept(lax.top_k(choose_on, k)[1])
+                weights = _kept(jnp.take_along_axis(scores, chosen, axis=-1))
                 if self.norm_topk:
                     weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
                 weights = weights * self.scaling
@@ -1263,17 +1301,17 @@ class ExpertFFN(nn.Module):
             with jax.named_scope("mpi4dl_part_dispatch"):
                 local = chosen - self.first
                 group = jnp.where((local >= 0) & (local < self.held), local, self.held)
-                sizes = jnp.sum(
-                    group[..., None] == jnp.arange(self.held), axis=(0, 1), dtype=jnp.int32)
+                sizes = _kept(jnp.sum(
+                    group[..., None] == jnp.arange(self.held), axis=(0, 1), dtype=jnp.int32))
                 pairs = tokens * k
                 prefix = _prefix_rows(pairs, self.held, self.experts)
                 if not self.is_initializing():  # ``init`` returns parameters alone
                     self.sow(COUNTERS, "expert_pairs", sizes)
                     self.sow(COUNTERS, "prefix_alone",
                              (jnp.sum(sizes) <= prefix).astype(jnp.int32))
-                _, order = lax.sort_key_val(
-                    group.reshape(-1), jnp.arange(pairs, dtype=jnp.int32))
-                inverse = jnp.argsort(order)
+                order = _kept(lax.sort_key_val(
+                    group.reshape(-1), jnp.arange(pairs, dtype=jnp.int32))[1])
+                inverse = _kept(jnp.argsort(order))
 
             gated = {"swiglu": True, "relu2": False}[self.activation]
             with jax.named_scope("mpi4dl_part_expert_products"):

@@ -61,10 +61,14 @@ def _conv_save_ckpt():
 
 def _cell_ckpt():
     """jax.checkpoint of one cell under remat "cell": the cell's input is
-    kept and the cell replayed, but for what a fused kernel's forward wrote
-    (``KERNEL_RESIDUAL``: a kernel's output and what its backward reads),
-    which is kept by name, so the replay runs no kernel forward again. A
-    cell without such a value (every image cell) keeps its input alone."""
+    kept and the cell replayed, but for what the cell's forward wrote that
+    is dear to compute and cheap to hold, which is kept by name
+    (``KERNEL_RESIDUAL``): a fused kernel's output and what its backward
+    reads, and the expert layer's router product, choice, sorts, gathered
+    rows and grouped products (``ops/sequence.ExpertFFN``), so the replay
+    runs no kernel forward, router, sort or grouped product again. The
+    values are named where they are made, one fixed set for every model;
+    a cell without such a value (every image cell) keeps its input alone."""
     return functools.partial(
         jax.checkpoint,
         policy=jax.checkpoint_policies.save_only_these_names(KERNEL_RESIDUAL),
@@ -158,8 +162,9 @@ def default_remat(image_size: int) -> "bool | str":
     - a model without an image (``image_size`` 0: a token-sequence model,
       ``ParallelConfig.sequence_length``): "cell", every cell's input kept
       and the cell recomputed in the backward pass, but for what its fused
-      kernels' forwards wrote, which is kept too (:func:`_cell_ckpt`: a
-      kernel's forward runs once a step). Such a model is sized
+      kernels' and its expert layer's forwards wrote, which is kept too
+      (:func:`_cell_ckpt`: a kernel's forward, the router, the sorts and
+      the grouped products run once a step). Such a model is sized
       so that parameters, gradients and momentum fill most of the chip
       (LFM2-8B-A1B's share: 11.5 of 16 GB), and one layer's activations at
       8,192 positions are what is left to hold.
@@ -312,8 +317,9 @@ class Trainer:
 
         remat: False = store everything; True/"cell" = ``jax.checkpoint``
         per cell, which keeps the cell's input and what the cell's fused
-        kernels' forwards wrote (:func:`_cell_ckpt`) and recomputes the
-        rest; "sqrt" = nested two-level remat (cells grouped into ~√N
+        kernels' and expert layer's forwards wrote under the kept name
+        (:func:`_cell_ckpt`) and recomputes the rest; "sqrt" = nested
+        two-level remat (cells grouped into ~√N
         outer checkpoints, each cell checkpointed inside, so live residuals
         are ~2√N boundaries); "scan2" = "scan" with the same two-level
         nesting applied INSIDE each scan run (see :meth:`_scan_nested`) —

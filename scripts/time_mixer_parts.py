@@ -59,25 +59,39 @@ def model_cells(cell):
     return cells, kinds
 
 
-def programs(layer, index, x):
-    """``(parameters, fwd, grad)`` of one layer cell under its own name."""
+def layer_apply(layer, index=None):
+    """``apply(params, h)`` of one layer cell (under its own name where
+    ``index`` says which), the counts it sows dropped."""
     collection = getattr(layer, "counters", None)
     mutable = [collection] if collection else False
 
     def apply(params, h):
-        with jax.named_scope(cell_scope(index)):
+        if index is None:
             out = layer.apply(params, h, mutable=mutable)
+        else:
+            with jax.named_scope(cell_scope(index)):
+                out = layer.apply(params, h, mutable=mutable)
         return out[0] if mutable else out
 
+    return apply
+
+
+def drawn_params(layer, index, x):
+    """The layer's parameters, normal at deviation 0.02."""
     shapes = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
     leaves, tree = jax.tree.flatten({"params": shapes["params"]})
     keys = jax.random.split(jax.random.PRNGKey(index), len(leaves))
-    params = jax.tree.unflatten(tree, [
+    return jax.tree.unflatten(tree, [
         0.02 * jax.random.normal(k, a.shape, a.dtype) for k, a in zip(keys, leaves)])
+
+
+def programs(layer, index, x):
+    """``(parameters, fwd, grad)`` of one layer cell under its own name."""
+    apply = layer_apply(layer, index)
     fwd = jax.jit(apply)
     grad = jax.jit(jax.grad(
         lambda p, h, ct: jnp.sum(apply(p, h).astype(jnp.float32) * ct), argnums=(0, 1)))
-    return params, fwd, grad
+    return drawn_params(layer, index, x), fwd, grad
 
 
 def traced_parts(fn, args, name):

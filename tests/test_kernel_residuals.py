@@ -1,12 +1,26 @@
-"""What "cell" remat keeps of a fused kernel (``train._cell_ckpt``): every
-array a kernel's forward writes carries ``config.KERNEL_RESIDUAL`` in its
-``custom_vjp`` forward rule, and the cell's checkpoint keeps that name, so a
-step runs each kernel's forward once a cell. Two tiny cells (a projection,
-the kernel in the Pallas interpreter, a projection) under that checkpoint
-and under a bare ``jax.checkpoint``: the gradient's program holds one forward
-and one backward call a cell against the bare checkpoint's two forwards (so
-the test fails if the names or the policy are lost), and the value and every
-gradient are the same bits, since what is kept is what the replay computed.
+"""What "cell" remat keeps by name (``train._cell_ckpt``,
+``config.KERNEL_RESIDUAL``): what a cell's forward wrote that is dear to
+compute and cheap to hold. Two tiny cells under that checkpoint and under a
+bare ``jax.checkpoint``: the gradient's program holds each dear call once a
+cell against the bare checkpoint's twice (so the test fails if a name or the
+policy is lost, or a value is named after something has read it), and the
+value and every gradient are the same bits, since what is kept is what the
+replay computed.
+
+**A fused kernel** (PR 44): every array its forward writes carries the name
+in its ``custom_vjp`` forward rule. A projection, the kernel in the Pallas
+interpreter, a projection: one forward and one backward call a cell, two and
+one under the bare checkpoint.
+
+**The expert layer** (PR 46, ``ops/sequence.ExpertFFN``): the router's
+product, ``top_k``'s choice and the chosen scores, the dispatch's integers
+(``sizes``, both sorts' results, ``_by_token``'s four arrays), the gathered
+rows and the grouped products' outputs carry it where they are made. A cell
+``h + ExpertFFN(h)`` at tiny widths: the whole layer held (``C == P``, gated
+SiLU experts), a share through ``_ranges_ffn``'s ``jit`` and ``custom_vjp``
+(``C < P``, softmax scores; the conditionals' branches hold a forward and a
+replay of their own in both programs, which no name reaches), and squared-
+ReLU experts with a gated shared expert.
 
 What the chip's compiler makes of it at the cells' widths is
 ``tests/test_tpu_compile.py``'s.
@@ -76,12 +90,12 @@ KERNELS = {
 
 
 def _calls(jaxpr):
-    """``{a pallas_call's name: how many}`` over a jaxpr and every jaxpr in
-    its equations' parameters."""
+    """``{a pallas_call's name, any other equation's primitive: how many}``
+    over a jaxpr and every jaxpr in its equations' parameters."""
     counts = collections.Counter()
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            counts[eqn.params["name"]] += 1
+        counts[eqn.params["name"] if eqn.primitive.name == "pallas_call"
+               else eqn.primitive.name] += 1
         for value in eqn.params.values():
             for inner in value if isinstance(value, (tuple, list)) else (value,):
                 inner = getattr(inner, "jaxpr", inner)
@@ -90,8 +104,9 @@ def _calls(jaxpr):
     return counts
 
 
-@pytest.mark.parametrize("case", KERNELS)
-def test_cell_checkpoint_keeps_what_the_kernel_forward_wrote(case):
+def _kernel_cells(case):
+    """``(cell, its parameters a cell, the input, {call: (a cell's under
+    _cell_ckpt, under a bare checkpoint)})`` of a kernel between projections."""
     kernel, length, wide, narrow, (fwd, bwd) = KERNELS[case]
     keys = jax.random.split(jax.random.PRNGKey(0), 2 * CELLS + 1)
     params = [
@@ -105,6 +120,52 @@ def test_cell_checkpoint_keeps_what_the_kernel_forward_wrote(case):
         out = kernel(h @ p[0])
         return h + out.reshape(*h.shape[:2], narrow) @ p[1]
 
+    return cell, params, h, {fwd: (1, 2), bwd: (1, 1)}
+
+
+# an expert layer at tiny widths and a cell's calls: forward + gradients under
+# ``_cell_ckpt``, with the replay's beside them under a bare checkpoint. The
+# router's product is a ``dot_general`` (two gradients), and so are a shared
+# expert's two products and its gate's (whose replay keeps the gate and the
+# product it scales, the way out of the shared expert: two of four)
+EXPERTS = {
+    "experts_whole": (
+        dict(experts=4, held=4, first=0, per_token=2),
+        {"ragged_dot_general": (3 + 6, 3 + 3 + 6), "top_k": (1, 2), "sort": (3, 6),
+         "dot_general": (1 + 2, 1 + 1 + 2)}),
+    # in the two conditionals' branches, in both programs: a forward (3
+    # products, a sort), and its replay with its gradients (3 + 6, a sort)
+    "experts_share_two_ranges": (
+        dict(experts=16, held=4, first=4, per_token=2, scoring="softmax"),
+        {"ragged_dot_general": (9 + 12, 12 + 12), "top_k": (1, 2), "sort": (3 + 2, 6 + 2),
+         "dot_general": (3, 4)}),
+    "experts_relu2_gated_shared": (
+        dict(experts=8, held=2, first=0, per_token=2, activation="relu2",
+             shared_width=24),
+        {"ragged_dot_general": (6 + 8, 8 + 8), "top_k": (1, 2), "sort": (3 + 2, 6 + 2),
+         "dot_general": (3 + 9 + 2, 4 + 9 + 3)}),
+}
+
+
+def _expert_cells(case):
+    """The same of ``h + ExpertFFN(h)``: 32 tokens of width 32, experts of
+    width 16."""
+    fields, calls = EXPERTS[case]
+    layer = sequence.ExpertFFN(hidden=WIDTH, width=16, **fields)
+    keys = jax.random.split(jax.random.PRNGKey(0), CELLS + 1)
+    h = jax.random.normal(keys[-1], (2, 16, WIDTH))
+    return (lambda p, h: h + layer.apply(p, h),
+            [layer.init(key, h) for key in keys[:CELLS]], h, calls)
+
+
+CASES = {**{case: _kernel_cells for case in KERNELS},
+         **{case: _expert_cells for case in EXPERTS}}
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_cell_checkpoint_keeps_what_the_kernel_forward_wrote(case):
+    cell, params, h, calls = CASES[case](case)
+
     def loss(ckpt, params, h):
         for p in params:
             h = ckpt(cell)(p, h)
@@ -113,13 +174,14 @@ def test_cell_checkpoint_keeps_what_the_kernel_forward_wrote(case):
     def step(ckpt):
         return jax.value_and_grad(functools.partial(loss, ckpt), argnums=(0, 1))
 
-    assert _calls(jax.make_jaxpr(step(_cell_ckpt()))(params, h).jaxpr) == {
-        fwd: CELLS, bwd: CELLS}
-    assert _calls(jax.make_jaxpr(step(jax.checkpoint))(params, h).jaxpr) == {
-        fwd: 2 * CELLS, bwd: CELLS}
+    for ckpt, which in ((_cell_ckpt(), 0), (jax.checkpoint, 1)):
+        found = _calls(jax.make_jaxpr(step(ckpt))(params, h).jaxpr)
+        assert {name: found[name] for name in calls} == {
+            name: CELLS * counts[which] for name, counts in calls.items()}
 
     got, want = jax.jit(step(_cell_ckpt()))(params, h), jax.jit(step(jax.checkpoint))(params, h)
     assert np.isfinite(float(got[0])) and float(got[0]) > 0
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        assert np.any(np.asarray(a, np.float32) != 0)
+    for (path, a), b in zip(jax.tree.leaves_with_path(got), jax.tree.leaves(want)):
+        # the choice has no derivative: the bias it is made on gets zeros
+        assert np.any(np.asarray(a, np.float32) != 0) or "expert_bias" in jax.tree_util.keystr(path)
         np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))

@@ -671,15 +671,21 @@ def test_the_tiny_cuts_mamba_layer_takes_the_plain_path(topo, cache_off, monkeyp
 # -- what "cell" remat keeps of a kernel (PR 44) -------------------------------
 
 
+def _sdar_config():
+    from mpi4dl_tpu.models.sdar import SDARConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", "sdar_30b_a3b_share8.json")) as f:
+        return SDARConfig.from_dict(json.load(f))
+
+
 def _sdar_attention_cell():
     """The SDAR cell's attention cell (``chipbench/configs/sdar_30b_a3b_
     share8.json``: hidden 2048, 32 query heads of 128 over 4 key-value heads,
     diffusion blocks of 4) on a noisy copy beside a clean one of 8,192."""
-    from mpi4dl_tpu.models.sdar import SDARAttention, SDARConfig
+    from mpi4dl_tpu.models.sdar import SDARAttention
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "chipbench", "configs", "sdar_30b_a3b_share8.json")) as f:
-        config = SDARConfig.from_dict(json.load(f))
+    config = _sdar_config()
     return SDARAttention(config), (1, 16384, config.hidden_size)
 
 
@@ -718,3 +724,80 @@ def test_a_kernels_forward_runs_once_under_the_cell_checkpoint(
     assert {name: len(calls) for name, calls in found.items()} == {
         start + "_fwd": 1, start + "_bwd": 1}, found
     assert "checkpoint" in found[start + "_bwd"][0]  # the replay holds the backward alone
+
+
+# -- what "cell" remat keeps of the expert layer (PR 46) ----------------------
+
+
+def _sdar_expert_cell():
+    """The SDAR cell's expert cell (16 of 128 experts, 8 a row, widths 2048 /
+    768) on both copies' 16,384 rows: 131,072 sorted pair rows, a prefix of
+    32,768."""
+    from mpi4dl_tpu.models.sdar import SDARExperts
+
+    config = _sdar_config()
+    return SDARExperts(config), (1, 16384, config.hidden_size), jnp.bfloat16
+
+
+def _qwen3_next_expert_layer():
+    """Qwen3-Next's expert layer with its gated shared expert (32 of 512
+    experts, 10 a token, widths 2048 / 512) on the cell's two sequences: a
+    prefix of 20,480 of 163,840 sorted pair rows."""
+    from mpi4dl_tpu.ops.sequence import ExpertFFN
+
+    return (ExpertFFN(2048, 512, 512, 32, 0, 10, True, expert_bias=False,
+                      scoring="softmax", shared_width=512), (2, 8192, 2048), jnp.float32)
+
+
+@pytest.mark.parametrize("build, rows, held, sorts", [
+    (_sdar_expert_cell, 32768, 16, 6), (_qwen3_next_expert_layer, 20480, 32, 7),
+], ids=["sdar_expert_cell", "qwen3_next_expert_layer"])
+def test_the_expert_layers_forward_runs_once_under_the_cell_checkpoint(
+        topo, cache_off, build, rows, held, sorts):
+    """An expert layer's value and gradient at its cell's widths for one
+    described chip under ``train._cell_ckpt``, and SDAR's under a bare
+    ``jax.checkpoint`` beside it (the file is tier-1's longest: one bare
+    compile, not two). Outside the conditionals' branches (the entry
+    computation: the prefix range, the one every measured step runs) the
+    compiled text holds nine grouped products a gated layer under the cell's
+    checkpoint, three forward and six gradients, where the bare one gives
+    twelve, and four sorts fewer in all (``top_k``, which the chip's compiler
+    lowers to a sort, ``sort_key_val``, ``argsort`` and ``_by_token``'s: each
+    once, and ``_by_token``'s in the conditionals' branches, two in SDAR's
+    text and three in Qwen3-Next's, whose further ranges are a loop): what the
+    forward chose, sorted, gathered and multiplied is kept by name
+    (``ops/sequence._kept``), the two hidden-wide arrays among it, so no
+    product is in the replay. Every product is over the prefix's rows. The
+    temporaries as compiled here, GiB (cell / bare): SDAR's cell 1.43 / 1.30,
+    Qwen3-Next's layer 1.42 / 1.18."""
+    from mpi4dl_tpu.train import _cell_ckpt
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    layer, x_shape, dtype = build()
+    shapes = _layer_shapes(layer, jax.ShapeDtypeStruct(x_shape, dtype), one_chip)
+    mutable = [layer.counters] if hasattr(layer, "counters") else False
+
+    def compiled(ckpt):
+        def value(v, x_):
+            out = ckpt(lambda v, x_: layer.apply(v, x_, mutable=mutable))(v, x_)
+            return jnp.sum((out[0] if mutable else out).astype(jnp.float32))
+
+        return jax.jit(jax.value_and_grad(value, argnums=(0, 1))).lower(*shapes).compile()
+
+    def entry_products(text):
+        entry = text[text.index("\nENTRY "):]
+        return [re.search(r"= \w+\[(\d+),", line).group(1)
+                for line in entry[:entry.index("\n}")].splitlines()
+                if "custom-call(" in line and "%ragged-dot-none" in line.split(" = ")[0]]
+
+    kept = compiled(_cell_ckpt())
+    products = entry_products(kept.as_text())
+    # over the prefix's rows; a weight gradient's leading axis counts the held experts
+    assert sorted(products) == sorted(6 * [str(rows)] + 3 * [str(held)]), products
+    assert kept.as_text().count(" sort(") == sorts
+    if build is _sdar_expert_cell:
+        bare = compiled(jax.checkpoint).as_text()
+        assert len(entry_products(bare)) == 12 and bare.count(" sort(") == sorts + 4
+    temp = kept.memory_analysis().temp_size_in_bytes
+    print(build.__name__, "temp GiB", temp / 2**30)
+    assert temp < 1.65 * 2**30
