@@ -26,8 +26,13 @@ delta rule goes the same way: on a TPU, at the shapes
 kernels, which keep a chunk's squares and the carried state in VMEM;
 everywhere else it is ``_chunked_rule``, plain JAX. Mamba-2's chunked scan
 likewise: ``ssd_scan`` is ``ops/ssd_scan_pallas.py``'s kernels at the shapes
-they take and ``_chunked_scan`` everywhere else. The rest is plain JAX
-everywhere.
+they take and ``_chunked_scan`` everywhere else. And the causal depthwise
+convolution with its bias and SiLU, the form Gated DeltaNet and Mamba-2 put
+over their projections: ``causal_conv_silu`` is ``ops/causal_conv_pallas.py``'s
+kernels at the shapes they take (one pass over the array each way) and the
+plain ``causal_depthwise_conv1d`` with ``nn.silu`` everywhere else; those two
+mixers multiply a product of its own for what the convolution reads, so the
+kernels take and give whole arrays. The rest is plain JAX everywhere.
 
 Activations are ``[batch, positions, features]``. Each module computes in
 its ``dtype`` (bfloat16 on the chip) with float32 parameters, float32
@@ -44,9 +49,12 @@ that every line of a mixer's ``__call__`` falls under one:
 ``mpi4dl_part_proj`` (the dense projections into and out of a mixer with
 their weights' casts, and the dense feed-forwards ``SwiGLU`` / ``SquaredReLU``,
 a shared expert's among them), ``mpi4dl_part_conv``
-(``causal_depthwise_conv1d`` with its bias, SiLU and, in ``ShortConv``, its
-two gates), ``mpi4dl_part_gates_norms`` (``beta``, ``g``, ``dt``'s softplus and
-the decays, the L2 norms of q and k, ``RMSNorm(o) * silu(z)``,
+(``causal_conv_silu``: on a TPU at the two cells' shapes the custom calls
+``mpi4dl_causal_conv_fwd`` / ``_bwd`` with the taps' rows and the sums of their
+gradient around them, else ``causal_depthwise_conv1d`` with its bias and
+SiLU; in ``ShortConv`` the plain function with its two gates),
+``mpi4dl_part_gates_norms`` (``beta``, ``g``, ``dt``'s softplus and the
+decays, the L2 norms of q and k, ``RMSNorm(o) * silu(z)``,
 ``_gated_group_norm`` with ``D x``, attention's output gate), the recurrences
 under the names they had (``ssd_scan``, ``gated_delta_rule``),
 ``mpi4dl_part_qk_prep`` (the q/k head norms, the rotary embedding, the
@@ -79,7 +87,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from mpi4dl_tpu.config import KERNEL_RESIDUAL
-from mpi4dl_tpu.ops import attention_pallas, delta_rule_pallas, ssd_scan_pallas
+from mpi4dl_tpu.ops import (
+    attention_pallas, causal_conv_pallas, delta_rule_pallas, ssd_scan_pallas)
 
 COUNTERS = "counters"  # the flax collection an expert layer sows its counts into
 RULE_CHUNK = 64        # positions the gated delta rule takes as one triangular system
@@ -151,6 +160,28 @@ def causal_depthwise_conv1d(x, kernel):
     taps, length = kernel.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     return sum(kernel[j] * padded[:, j:j + length] for j in range(taps))
+
+
+def causal_conv_silu(x, kernel, bias=None):
+    """``silu(causal_depthwise_conv1d(x, kernel) + bias)``, the form Gated
+    DeltaNet (no bias) and Mamba-2 put over their projections: ``x [batch,
+    positions, features]``, ``kernel [L, features]`` and ``bias [features]``
+    (None: none), both cast to ``x``'s dtype. Where
+    ``causal_conv_pallas.dispatchable`` says so (TPU backend, not under
+    ``vmap``, bfloat16, features of whole lanes, a length of whole blocks)
+    that module's kernels: one pass over the array each way, the taps, the
+    bias and the SiLU applied in float32 on values in VMEM and rounded once,
+    the backward building the pre-activation again; else the plain function,
+    its bias and ``nn.silu`` (the CPU, float32, the tiny cuts), which is also
+    the kernels' oracle. ``ShortConv`` (two gates around three taps, no
+    SiLU) does not come here: the compiler fuses its taps and gates into the
+    projections' products."""
+    kernel = kernel.astype(x.dtype)
+    if causal_conv_pallas.dispatchable(x, kernel):
+        return causal_conv_pallas.conv_silu(
+            x, kernel, None if bias is None else bias.astype(x.dtype))
+    y = causal_depthwise_conv1d(x, kernel)
+    return nn.silu(y if bias is None else y + bias.astype(x.dtype))
 
 
 class ShortConv(nn.Module):
@@ -343,7 +374,13 @@ class GatedDeltaNet(nn.Module):
     gated delta rule (``gated_delta_rule``); then per head
     ``RMSNorm(o) * silu(z)`` (one scale of ``value_dim``, from 1) and
     ``out_proj``. The columns of ``W_qkvz`` are ``[q | k | v | z]`` and of
-    ``W_ba`` ``[b | a]``, each in head order."""
+    ``W_ba`` ``[b | a]``, each in head order. ``q``, ``k``, ``v`` and ``z``
+    are a product of ``x`` with their own columns each, and ``q``, ``k``,
+    ``v`` go through the convolution (``causal_conv_silu``, their own columns
+    of its taps) as whole arrays: its channels know nothing of each other,
+    and on a TPU its kernels then read a product's result and write what the
+    norms and the rule read, with no slice or concatenation copied around
+    them in either pass."""
 
     hidden: int
     key_heads: int
@@ -362,18 +399,22 @@ class GatedDeltaNet(nn.Module):
             keys, values = heads * self.key_dim, self.value_heads * self.value_dim
             with jax.named_scope("mpi4dl_part_proj"):
                 x = x.astype(self.dtype)
-                qkv, z = jnp.split(
-                    linear(2 * keys + 2 * values, self.dtype, "in_proj_qkvz")(x),
-                    [2 * keys + values], axis=-1)
+                # a product each for q, k, v and the gate: the channels of a
+                # depthwise convolution know nothing of each other, so each goes
+                # through the convolution as a whole array of its own
+                w_qkvz = _Kernel((self.hidden, 2 * keys + 2 * values), name="in_proj_qkvz")()
+                w_qkvz = w_qkvz.astype(self.dtype)
+                edges = (0, keys, 2 * keys, 2 * keys + values)
+                qkv = [jnp.matmul(x, w_qkvz[:, lo:hi]) for lo, hi in zip(edges, edges[1:])]
+                z = jnp.matmul(x, w_qkvz[:, edges[-1]:])
                 w_ba = _Kernel((self.hidden, 2 * self.value_heads), name="in_proj_ba")()
                 b, a = jnp.split(jnp.matmul(
                     x, w_ba.astype(self.dtype), preferred_element_type=jnp.float32),
                     2, axis=-1)
             with jax.named_scope("mpi4dl_part_conv"):
                 kernel = _Kernel((self.taps, 2 * keys + values), name="conv")()
-                q, k, v = jnp.split(
-                    nn.silu(causal_depthwise_conv1d(qkv, kernel.astype(self.dtype))),
-                    [keys, 2 * keys], axis=-1)
+                q, k, v = (causal_conv_silu(t, kernel[:, lo:hi])
+                           for t, lo, hi in zip(qkv, edges, edges[1:]))
 
             with jax.named_scope("mpi4dl_part_gates_norms"):
                 a_log = self.param(
@@ -529,7 +570,10 @@ class Mamba2(nn.Module):
     with the statistics over each group's channels (the gate before the
     norm) and ``out_proj``. No projection has a bias. ``dt``'s columns of
     ``W_in`` are multiplied apart from the rest so that they accumulate into
-    float32 and the decays never pass through ``dtype``."""
+    float32 and the decays never pass through ``dtype``; ``z``, ``x``, ``B``
+    and ``C`` are a product with their own columns each too, and ``x``,
+    ``B``, ``C`` go through the convolution as whole arrays
+    (``GatedDeltaNet``'s reason)."""
 
     hidden: int
     heads: int
@@ -553,17 +597,17 @@ class Mamba2(nn.Module):
                 w_in = _Kernel(
                     (self.hidden, inner + mixed + self.heads), name="in_proj")()
                 w_in = w_in.astype(self.dtype)
-                z, xbc = jnp.split(
-                    jnp.matmul(x, w_in[:, :inner + mixed]), [inner], axis=-1)
+                z = jnp.matmul(x, w_in[:, :inner])
+                edges = (0, inner, inner + self.groups * self.state, mixed)
+                xbc = [jnp.matmul(x, w_in[:, inner + lo:inner + hi])
+                       for lo, hi in zip(edges, edges[1:])]
                 dt = jnp.matmul(
                     x, w_in[:, inner + mixed:], preferred_element_type=jnp.float32)
             with jax.named_scope("mpi4dl_part_conv"):
                 kernel = _Kernel((self.taps, mixed), name="conv")()
                 bias = self.param("conv_bias", nn.initializers.zeros, (mixed,))
-                xbc = nn.silu(causal_depthwise_conv1d(xbc, kernel.astype(self.dtype))
-                              + bias.astype(self.dtype))
-                u, b, c = jnp.split(
-                    xbc, [inner, inner + self.groups * self.state], axis=-1)
+                u, b, c = (causal_conv_silu(t, kernel[:, lo:hi], bias[lo:hi])
+                           for t, lo, hi in zip(xbc, edges, edges[1:]))
 
             with jax.named_scope("mpi4dl_part_gates_norms"):
                 by_head = (batch, length, self.groups, per)

@@ -145,6 +145,26 @@ def _eval_step(spatial):
 # ``ragged_dot_general`` 36 -> 33, 48 -> 45, 32 -> 30; ``dot_general`` 75 ->
 # 70, 316 -> 304, 146 -> 138). With the names off the three trace to the
 # parent's hashes, which ``WITHOUT_THE_NAMES`` holds them to from now on.
+#
+# "qwen3_next-cell" and "nemotron_h-cell" were replaced by PR 47, which meant
+# to alter one thing in them: ``GatedDeltaNet`` and ``Mamba2`` multiply a
+# product of their input projection's columns for each array the causal
+# convolution reads (q, k, v and the gate z; z, x, B, C and dt) where they
+# split one wide product, and put each of q, k, v / x, B, C through the
+# convolution as a whole array of its own (on a TPU the kernels of
+# ``ops/causal_conv_pallas.py``, which take and give whole arrays; here, on
+# the CPU, the plain function, three times over a third of the channels each).
+# Per program, parent 20f01ba -> PR 47: ``dot_general`` 304 -> 340 and 138 ->
+# 186 (a product a piece, forward, replay and two gradients), ``split`` 22 ->
+# 10 and 16 -> 0, ``concatenate`` 16 -> 10 and 10 -> 2, ``slice`` 164 -> 303
+# and 184 -> 384, ``pad`` 91 -> 163 and 97 -> 197 (the weights' columns, and
+# the plain convolution's pads and taps a piece), ``mul`` 567 -> 669 and 371 ->
+# 499, ``add_any`` 131 -> 192 and 89 -> 177; 15,076 -> 17,046 and 13,079 ->
+# 15,903 lines. Nothing else of the two programs moved: with the two mixers'
+# ``__call__`` as at the parent they trace to the parent's hashes
+# (9d648b03a174d532..., 9cc3536d14a2feeb...), and "lfm2-cell" (``ShortConv``
+# keeps the plain function) is untouched. ``WITHOUT_THE_NAMES`` moves with
+# them for the same reason (5db85c6dd34a26a5..., a381ebc6b3f49670... before).
 TRACED_AT_C0A7BC1 = {
     "amoebanet-False": (lambda: _image_step("amoebanet", False),
         "eb4431aae24c350019f855dfaac178d4cda883b9657eacc6eb69e7a5f24b0cb7"),
@@ -186,9 +206,9 @@ TRACED_AT_C0A7BC1 = {
     # hash was taken on that tree before PR 39 touched ``Attention`` and
     # ``ExpertFFN``, and holds after), and Nemotron-H's, new in PR 39
     "qwen3_next-cell": (lambda: _token_step(test_qwen3_next),
-        "9d648b03a174d53261060dc5cded8711bfe795248e03715fa005fb869cdc2abd"),
+        "9013be8a0da54ff4b560b22111b7233fe4335bf6033ee1b27e28a496db19e6b3"),
     "nemotron_h-cell": (lambda: _token_step(test_nemotron_h),
-        "9cc3536d14a2feeb4a34ff4e25f550979e7baa1c5866b602ceb4c17cc72fe3fd"),
+        "7e60dbb71306d1f1d31a1d5bd788e44f81c8d92d91c1bd3f93bd5c5b1e4ad0df"),
     "pipeline-gpipe": (lambda: _pipeline_step("gpipe"),
         "278d206dbf04f5ddd34d0b3bfb01274ea8b7e5d47f0c870c9caa6d9ee90b5b01"),
     "pipeline-1f1b": (lambda: _pipeline_step("1f1b"),
@@ -204,12 +224,14 @@ TRACED_AT_C0A7BC1 = {
 
 # The three token programs as 3d49de2 (PR 45) pinned them, before the expert
 # layer named anything: what they trace to with ``ops/sequence._kept`` the
-# identity. A name changes which values a replay makes again and no value:
-# whoever names more (or fewer) values leaves these three as they are.
+# identity (Qwen3-Next's and Nemotron-H's with PR 47's products and
+# convolutions a piece, above). A name changes which values a replay makes
+# again and no value: whoever names more (or fewer) values leaves these three
+# as they are.
 WITHOUT_THE_NAMES = {
     "lfm2-cell": "4f7e32c3a0c26bdd030a74218ead9322b2849a21733daa3ee924c373cf7b007b",
-    "qwen3_next-cell": "5db85c6dd34a26a5439f1080bfca8bb8c46d7c61326a4d64af6f98a92b5c008c",
-    "nemotron_h-cell": "a381ebc6b3f496704d3c35f34792deea2259f7ce6d4648d7cfbe94b2bd03eab3",
+    "qwen3_next-cell": "4bf59de71f1b7abf8a53c6d203925ae443eb39f28c41e9b5dd398ebdfa396fec",
+    "nemotron_h-cell": "d67e2a8d40f6f9369efd6db795fc940446e65ab029e17ae5935a19590a00a711",
 }
 
 

@@ -10,7 +10,8 @@ replay computed.
 **A fused kernel** (PR 44): every array its forward writes carries the name
 in its ``custom_vjp`` forward rule. A projection, the kernel in the Pallas
 interpreter, a projection: one forward and one backward call a cell, two and
-one under the bare checkpoint.
+one under the bare checkpoint; the causal convolution's kernels among them
+since PR 47.
 
 **The expert layer** (PR 46, ``ops/sequence.ExpertFFN``): the router's
 product, ``top_k``'s choice and the chosen scores, the dispatch's integers
@@ -34,7 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mpi4dl_tpu.ops import attention_pallas, delta_rule_pallas, sequence, ssd_scan_pallas
+from mpi4dl_tpu.ops import (
+    attention_pallas, causal_conv_pallas, delta_rule_pallas, sequence, ssd_scan_pallas)
 from mpi4dl_tpu.train import _cell_ckpt
 
 CELLS, WIDTH = 2, 32
@@ -74,6 +76,14 @@ def _ssd_scan(z):
         bb.reshape(b, s, 1, 128), c.reshape(b, s, 1, 128), 128, interpret=True)
 
 
+def _causal_conv(z):
+    """``z [B, S, 256]``: four taps (fixed: the cell's parameters are its two
+    projections) and a SiLU over 256 channels."""
+    taps = jax.random.normal(jax.random.PRNGKey(7), (4, z.shape[-1])).astype(z.dtype) * 0.5
+    return causal_conv_pallas.conv_silu(
+        z, taps, plan=causal_conv_pallas.Plan(causal_conv_pallas.ROWS, 128), interpret=True)
+
+
 # the kernel, the positions, the widths the projections go to and come from,
 # and the calls' names
 KERNELS = {
@@ -86,6 +96,8 @@ KERNELS = {
                    (delta_rule_pallas.FWD_NAME, delta_rule_pallas.BWD_NAME)),
     "ssd_scan": (_ssd_scan, 256, 386, 128,
                  (ssd_scan_pallas.FWD_NAME, ssd_scan_pallas.BWD_NAME)),
+    "causal_conv": (_causal_conv, 2 * causal_conv_pallas.ROWS, 256, 256,
+                    (causal_conv_pallas.FWD_NAME, causal_conv_pallas.BWD_NAME)),
 }
 
 

@@ -40,7 +40,7 @@ FAMILIES = {
         head_dim=64, num_attention_heads=2, num_key_value_heads=1), 1),
     "nemotron_h": (test_nemotron_h, dict(
         hybrid_override_pattern="M*E", num_hidden_layers=3, mamba_num_heads=2,
-        mamba_head_dim=16, n_groups=1, ssm_state_size=128, chunk_size=128, head_dim=64,
+        mamba_head_dim=64, n_groups=1, ssm_state_size=128, chunk_size=128, head_dim=64,
         num_attention_heads=2, num_key_value_heads=1), 1),
     "sdar": (test_sdar, dict(
         head_dim=64, num_attention_heads=2, num_key_value_heads=1, num_hidden_layers=1), 2),
@@ -241,7 +241,9 @@ def test_the_fused_kernels_calls_carry_their_part(family, monkeypatch):
     """At shapes the kernels take, with the gates told the backend is a TPU
     (traced, never lowered: the CPU has no such calls): every ``pallas_call``
     of the step, forward and backward, lies in a layer's cell under the
-    attention's core or a recurrence's scope."""
+    attention's core, a recurrence's scope or, the causal convolution's
+    (PR 47: its name has no rule of its own in ``token_parts.NAMED_PARTS``,
+    the scope it stands in says ``conv``), the convolution's part."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     trainer, arguments = _step(family, 128, **FAMILIES[family][1])
     calls = collections.Counter()
@@ -251,18 +253,22 @@ def test_the_fused_kernels_calls_carry_their_part(family, monkeypatch):
         name = stack.rsplit("/", 1)[-1]
         cell, mixer, part = token_parts.scope_of(stack)
         assert cell is not None and len(set(_part_words(stack))) == 1, stack
-        assert part == token_parts.scope_of("", name)[2], stack  # as its own name says
+        assert token_parts.scope_of("", name)[2] in (part, None), stack  # as its own name says
         assert (mixer, part) in (
             ("lfm2_attention", "attn_core"), ("blockdiff_attention", "attn_core"),
-            ("gated_delta", "recurrence"), ("mamba2", "recurrence")), stack
-        calls[mixer, _pass(stack)] += 1
-    mixers = {"lfm2": ["lfm2_attention"], "qwen3_next": ["gated_delta", "lfm2_attention"],
-              "nemotron_h": ["mamba2", "lfm2_attention"], "sdar": ["blockdiff_attention"]}
+            ("gated_delta", "recurrence"), ("mamba2", "recurrence"),
+            ("gated_delta", "conv"), ("mamba2", "conv")), stack
+        calls[mixer, part, _pass(stack)] += 1
+    attention = ("lfm2_attention", "attn_core")
+    mixers = {"lfm2": [attention], "sdar": [("blockdiff_attention", "attn_core")],
+              "qwen3_next": [("gated_delta", "recurrence"), ("gated_delta", "conv"), attention],
+              "nemotron_h": [("mamba2", "recurrence"), ("mamba2", "conv"), attention]}
     # "cell" remat keeps what a kernel's forward wrote: no second forward
     layers = sum(kind == "full_attention" for kind in trainer.cells[1].config.layer_types) \
         if family == "lfm2" else 1
-    assert calls == {(mixer, which): layers for mixer in mixers[family]
-                     for which in ("forward", "backward")}, calls
+    # ... and the convolution's kernels run once each for q, k, v / x, B, C
+    assert calls == {(*mixer, which): layers * (3 if mixer[1] == "conv" else 1)
+                     for mixer in mixers[family] for which in ("forward", "backward")}, calls
 
 
 def _attention(hidden, heads, kv_heads, head_dim, gate=False):

@@ -426,6 +426,32 @@ def _kernel_calls(compiled, start, names):
             for name in names}
 
 
+def _assert_the_convolution_dispatched(forward, gradient):
+    """The causal convolution's kernels (``ops/causal_conv_pallas.py``, PR 47)
+    in a mixer's compiled passes: a forward call for each of the three
+    arrays the convolution takes (q, k, v; x, B, C) in both, a backward call
+    each in the gradient's, every one under ``mpi4dl_part_conv`` (what
+    ``tok_conv_ms`` reads), and what a forward call reads is a product's own
+    result: no slice of a wider array is copied out for it."""
+    from mpi4dl_tpu.ops import causal_conv_pallas as ccp
+
+    names = (ccp.FWD_NAME, ccp.BWD_NAME)
+    found = _kernel_calls(forward, "mpi4dl_causal_conv", names)
+    assert {k: len(v) for k, v in found.items()} == {ccp.FWD_NAME: 3, ccp.BWD_NAME: 0}
+    found = _kernel_calls(gradient, "mpi4dl_causal_conv", names)
+    assert {k: len(v) for k, v in found.items()} == {ccp.FWD_NAME: 3, ccp.BWD_NAME: 3}
+    assert all("mpi4dl_part_conv" in op for ops in found.values() for op in ops), found
+    assert all("transpose(" in op for op in found[ccp.BWD_NAME])
+    for compiled in (forward, gradient):
+        lines = compiled.as_text().splitlines()
+        calls = [line for line in lines
+                 if "custom-call(" in line and ccp.FWD_NAME in line.split(" = ")[0]]
+        for call in calls:
+            operand = re.search(r"custom-call\((%[\w.\-]+)", call).group(1)
+            made = next(line for line in lines if line.strip().startswith(operand + " = "))
+            assert " fusion(" in made and " slice(" not in made, made
+
+
 def _rule_kernels(compiled):
     """The gated delta rule's kernels' custom calls (``_kernel_calls``)."""
     from mpi4dl_tpu.ops import delta_rule_pallas
@@ -464,6 +490,7 @@ def test_the_gated_delta_layer_compiles_at_full_width_under_its_scopes(
     assert len(found["mpi4dl_delta_rule_fwd"]) == 2 and len(found["mpi4dl_delta_rule_bwd"]) == 1
     assert all("gated_delta_rule" in name for names in found.values() for name in names), found
     assert "transpose(" in found["mpi4dl_delta_rule_bwd"][0]
+    _assert_the_convolution_dispatched(forward, gradient)
     assert " while(" not in gradient.as_text()  # the hand-on is the kernels' own
     assert gradient.memory_analysis().temp_size_in_bytes < 3.5 * 2**30
 
@@ -629,6 +656,7 @@ def test_a_nemotron_h_mixer_compiles_at_full_width_under_its_scopes(
         assert {k: len(v) for k, v in kernels.items()} == {fwd: 1, bwd: 1}
         assert all("ssd_scan" in op for ops in kernels.values() for op in ops), kernels
         assert "transpose(" in kernels[bwd][0]
+        _assert_the_convolution_dispatched(forward, gradient)
     else:
         for compiled in (forward, gradient):  # a kernel's custom call is named after it
             assert not re.search(r"%mpi4dl_\w+ = [^\n]*custom-call\(", compiled.as_text())
@@ -696,10 +724,18 @@ def _gated_delta_layer():
     return GatedDeltaNet(2048, 16, 32, 128, 128, 4, 1e-6), (2, 8192, 2048)
 
 
+def _mamba_layer():
+    """Nemotron-H's Mamba-2 mixer on the cell's two sequences."""
+    return _nemotron_mixer("mamba"), (2, 8192, 2688)
+
+
 @pytest.mark.parametrize("build, start", [
     (_sdar_attention_cell, "mpi4dl_blockdiff_attention"),
     (_gated_delta_layer, "mpi4dl_delta_rule"),
-], ids=["sdar_attention_cell", "gated_delta_layer"])
+    (_gated_delta_layer, "mpi4dl_causal_conv"),
+    (_mamba_layer, "mpi4dl_causal_conv"),
+], ids=["sdar_attention_cell", "gated_delta_layer", "gated_delta_layers_convolution",
+        "mamba_layers_convolution"])
 def test_a_kernels_forward_runs_once_under_the_cell_checkpoint(
         topo, cache_off, monkeypatch, build, start):
     """A cell's value and gradient under ``train._cell_ckpt`` at the cell's
@@ -721,8 +757,9 @@ def test_a_kernels_forward_runs_once_under_the_cell_checkpoint(
 
     compiled = jax.jit(jax.value_and_grad(value, argnums=(0, 1))).lower(*shapes).compile()
     found = _kernel_calls(compiled, start, (start + "_fwd", start + "_bwd"))
+    each = 3 if start == "mpi4dl_causal_conv" else 1  # a call each for q, k, v / x, B, C
     assert {name: len(calls) for name, calls in found.items()} == {
-        start + "_fwd": 1, start + "_bwd": 1}, found
+        start + "_fwd": each, start + "_bwd": each}, found
     assert "checkpoint" in found[start + "_bwd"][0]  # the replay holds the backward alone
 
 
